@@ -58,7 +58,7 @@ LEVERS = {
                 "/ two_phase with a capacity plan (docs/exchange.md)",
     "checkpoint": "checkpointing dominates - raise checkpoint_every "
                   "or shrink the state (docs/operations.md)",
-    "retry": "retry/backoff waits dominate - the device/relay is "
+    "retry": "retry/backoff waits dominate - the device is "
              "unhealthy; see the dispatch error spans",
     "compile": "XLA compile dominates - warm the AOT cache "
                "(docs/compile_cache.md); repeat runs should hit",

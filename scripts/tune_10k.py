@@ -7,9 +7,9 @@ wall seconds + derived ms/round per combo and ONE final JSON line
 with the best combo. pop/burst are trace-invariant by contract; a
 combo that diverges anyway is flagged loudly and disqualified.
 outbox_compact is CAPACITY-sensitive: too small fails loudly
-(x_overflow) and is disqualified here, and because the sweep slice
-may not cover steady state, bench.py re-guards it (workload match +
-retry-without on overflow).
+(x_overflow) and is disqualified here. The sweep slice may not cover
+steady state, so a chosen width must be validated on a full run
+before it is set in a config.
 
 When a measured occupancy record (artifacts/OCC_*.json, written by
 bench.py or any capacity_plan run — see device/capacity.py) exists

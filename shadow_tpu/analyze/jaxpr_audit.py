@@ -57,8 +57,11 @@ log = get_logger("analyze")
 #                   scatter-free per the v2 design, and a scatter
 #                   appearing elsewhere still trips SL102 on any NEW
 #                   primitive variant (scatter-mul, scatter-min, ...);
-#   * threefry2x32 rides inside pjit calls (counter-based, stateless);
-#   * optimization_barrier — the prng vmap batching rule.
+#   * threefry2x32 rides inside jit calls (counter-based, stateless);
+#   * optimization_barrier — prng.chain_key's fold barriers;
+#   * stop_gradient — value identity; jax 0.9's vmap of a lax.cond
+#                   with a batched predicate (the ensemble program's
+#                   exchange skip) emits it around the select.
 PRIMITIVE_ALLOWLIST = frozenset({
     "add", "all_gather", "all_to_all", "and", "axis_index",
     "bitcast_convert_type", "broadcast_in_dim", "concatenate",
@@ -66,12 +69,12 @@ PRIMITIVE_ALLOWLIST = frozenset({
     "device_put", "div", "dynamic_slice", "dynamic_update_slice",
     "eq", "gather", "ge", "gt", "iota", "le", "le_to", "lt", "max",
     "min", "mul", "ne", "neg", "not", "optimization_barrier", "or",
-    "pad", "pjit", "population_count", "ppermute", "psum",
+    "jit", "pad", "population_count", "ppermute", "psum",
     "reduce_and", "reduce_max", "reduce_min", "reduce_or",
     "reduce_sum", "rem", "reshape", "scan", "scatter", "scatter-add",
     "select_n", "shard_map", "shift_left", "shift_right_arithmetic",
-    "shift_right_logical", "sign", "slice", "sort", "squeeze", "sub",
-    "threefry2x32", "transpose", "while", "xor",
+    "shift_right_logical", "sign", "slice", "sort", "squeeze",
+    "stop_gradient", "sub", "threefry2x32", "transpose", "while", "xor",
 })
 
 # collective primitives whose axis/shape the registry pins
@@ -115,7 +118,7 @@ def _sub_jaxprs(val):
 
 def walk_jaxpr(closed):
     """Flatten one ClosedJaxpr: returns (consts, eqns) over the whole
-    nested program (while bodies, cond branches, pjit calls,
+    nested program (while bodies, cond branches, jit calls,
     shard_map inner jaxprs, ...)."""
     consts, eqns = list(closed.consts), []
 

@@ -61,9 +61,8 @@ from shadow_tpu.utils.slog import get_logger
 
 log = get_logger("aotcache")
 
-FORMAT = 1
+FORMAT = 2
 ENTRY_SUFFIX = ".aotc"
-DEFAULT_DIR = "~/.cache/shadow_tpu_aot"
 DEFAULT_CAP_MB = 2048
 
 # the engine-side source surface that shapes the traced programs: a
@@ -148,15 +147,10 @@ def _set_tracing_cache(enabled: bool) -> None:
     flipping the flag alone is not enough — reset_cache() drops the
     latch."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-    try:
-        jax.config.update("jax_enable_compilation_cache", enabled)
-        from jax._src import compilation_cache as cc
-
-        cc.reset_cache()
-    except Exception as e:              # noqa: BLE001 — older jax
-        log.info("could not %s jax's tracing cache (%s)",
-                 "enable" if enabled else "disable", e)
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    cc.reset_cache()
 
 
 _serialization_probe: bool | None = None
@@ -165,8 +159,8 @@ _serialization_probe: bool | None = None
 def serialization_supported() -> bool:
     """One cheap per-process probe: can this backend's PJRT client
     round a Compiled through serialize? Runs BEFORE the cache
-    disables jax's tracing cache, so an unsupported backend (e.g. a
-    relay that raises UNIMPLEMENTED) keeps the tracing cache as its
+    disables jax's tracing cache, so an unsupported backend (one whose
+    PJRT client raises UNIMPLEMENTED) keeps the tracing cache as its
     persistence layer for the big engine compiles — not just for
     programs compiled after the first store failure. The probe
     compiles fresh (see _fresh_compile): a tracing-cache-hit
@@ -202,11 +196,7 @@ def _fresh_compile():
     whole process instead."""
     import jax
 
-    try:
-        old = bool(jax.config.jax_enable_compilation_cache)
-    except Exception:                   # noqa: BLE001 — older jax
-        yield
-        return
+    old = bool(jax.config.jax_enable_compilation_cache)
     _set_tracing_cache(False)
     try:
         yield
@@ -253,10 +243,12 @@ def backend_identity(devs) -> dict:
 
 def backend_signature(mesh) -> dict:
     """The backend identity a serialized executable is only valid
-    for, plus the mesh's device ordering (an executable compiled for
-    devices [0..3] must not load onto a differently-ordered mesh)."""
+    for, plus the mesh's device count and ordering (an executable
+    compiled for devices [0..3] must not load onto a 1-device or a
+    differently-ordered mesh; load() places it on exactly these)."""
     devs = list(mesh.devices.flat)
     sig = backend_identity(devs)
+    sig["n_devices"] = len(devs)
     sig["device_ids"] = [int(d.id) for d in devs]
     return sig
 
@@ -463,10 +455,18 @@ class AotCache:
         try:
             if entry is None:
                 entry = self._read_entry(key, path)
+            import jax
             from jax.experimental import serialize_executable as se
 
+            # load onto exactly the devices the executable was
+            # compiled for: left to its default, deserialize assigns
+            # EVERY device of the backend, and a 1-device program
+            # loaded in an 8-device process then expects 8 shards
+            by_id = {d.id: d for d in jax.devices()}
             loaded = se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"])
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=[by_id[i]
+                                   for i in entry["device_ids"]])
         except Exception as e:          # noqa: BLE001 — any bad entry
             log.warning(
                 "compile cache: entry %s is unreadable/stale (%s) — "
@@ -517,7 +517,10 @@ class AotCache:
             return False
         entry = {"format": FORMAT, "key": key, "meta": dict(meta),
                  "payload": payload, "in_tree": in_tree,
-                 "out_tree": out_tree}
+                 "out_tree": out_tree,
+                 "device_ids": [int(d.id) for d in
+                                compiled.runtime_executable()
+                                .local_devices()]}
         path = self.entry_path(key)
         try:
             atomic_write(path, lambda f: pickle.dump(entry, f))
@@ -756,18 +759,24 @@ class AotCache:
         }
 
 
+def default_dir() -> str:
+    """Where ``compile_cache: auto`` keeps the AOT executables."""
+    from shadow_tpu._jax import cache_root
+
+    return os.path.join(cache_root(), "aot")
+
+
 def resolve_cache(experimental) -> AotCache | None:
     """The runners' cache factory, from the validated
     ``experimental.compile_cache`` knob: ``off`` -> None, ``auto`` ->
-    the default directory ($SHADOW_TPU_AOT_DIR, else
-    ~/.cache/shadow_tpu_aot), anything else is the (schema-validated)
-    cache directory path."""
+    the ``aot`` subdirectory of the one compile-cache root
+    (shadow_tpu._jax.cache_root), anything else is the
+    (schema-validated) cache directory path."""
     mode = experimental.compile_cache
     if mode == "off":
         return None
     if mode == "auto":
-        directory = os.environ.get("SHADOW_TPU_AOT_DIR",
-                                   DEFAULT_DIR)
+        directory = default_dir()
     else:
         directory = mode
     cap = int(experimental.compile_cache_cap_mb) * (1 << 20)

@@ -11,25 +11,6 @@ from __future__ import annotations
 
 from shadow_tpu._jax import jax, jnp
 
-# optimization_barrier is identity per operand, but this jax version
-# ships no vmap batching rule for it — the ensemble program (vmapped
-# replicas, device/engine.py) hits the barriers inside chain_key.
-# Register the trivial pass-through batcher: bind the barrier on the
-# batched operands and carry the batch dims unchanged, so the XLA
-# simplifier-loop workaround the barriers exist for holds in the
-# vmapped program too.
-try:
-    from jax.interpreters import batching as _batching
-    from jax._src.lax.lax import optimization_barrier_p as _ob_p
-
-    if _ob_p not in _batching.primitive_batchers:
-        def _ob_batcher(args, dims):
-            return _ob_p.bind(*args), list(dims)
-
-        _batching.primitive_batchers[_ob_p] = _ob_batcher
-except ImportError:        # pragma: no cover - newer jax ships a rule
-    pass
-
 _ROT_A = (13, 15, 26, 6)
 _ROT_B = (17, 29, 16, 24)
 _PARITY = 0x1BD11BDA

@@ -46,15 +46,31 @@ IPC_SIGNAL = 10        # sim->plugin: run handler args[0] for signal
 IPC_SIGNAL_DONE = 11   # plugin->sim: handler returned
 
 
-def load(build_if_missing: bool = True) -> ctypes.CDLL:
+_made: dict = {}
+
+
+def _make(target: str, check: bool = True) -> bool:
+    """Bring one ``native/build`` target up to date, once per process.
+    ``make`` is a no-op when the target is current and rebuilds it
+    when a ``native/`` source changed, so a stale build that came
+    along with the checkout is never used. Returns whether it
+    succeeded (with ``check``, a failure raises)."""
+    if target not in _made:
+        r = subprocess.run(["make", "-C", _NATIVE_DIR, target],
+                           capture_output=True)
+        if check and r.returncode:
+            raise subprocess.CalledProcessError(
+                r.returncode, r.args, r.stdout, r.stderr)
+        _made[target] = r.returncode == 0
+    return _made[target]
+
+
+def load() -> ctypes.CDLL:
     """Load the native library, building it on first use."""
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH) and build_if_missing:
-        subprocess.run(["make", "-C", _NATIVE_DIR,
-                        "build/libshadowtpu_native.so"],
-                       check=True, capture_output=True)
+    _make("build/libshadowtpu_native.so")
     lib = ctypes.CDLL(_LIB_PATH)
     lib.shadowtpu_arena_create.restype = ctypes.c_void_p
     lib.shadowtpu_arena_create.argtypes = [ctypes.c_char_p,
@@ -210,12 +226,9 @@ def cleanup_orphans(prefix: str = "shadowtpu_shm_") -> int:
 _SHIM_PATH = os.path.join(_NATIVE_DIR, "build", "libshadowtpu_shim.so")
 
 
-def shim_path(build_if_missing: bool = True) -> str:
+def shim_path() -> str:
     """Path to the preload shim injected into managed processes."""
-    if not os.path.exists(_SHIM_PATH) and build_if_missing:
-        subprocess.run(["make", "-C", _NATIVE_DIR,
-                        "build/libshadowtpu_shim.so"],
-                       check=True, capture_output=True)
+    _make("build/libshadowtpu_shim.so")
     return _SHIM_PATH
 
 
@@ -223,35 +236,23 @@ _LAUNCHER_PATH = os.path.join(_NATIVE_DIR, "build",
                               "shadowtpu_launcher")
 
 
-def launcher_path(build_if_missing: bool = True) -> str:
+def launcher_path() -> str:
     """Path to the ptrace-backend tracee launcher stub."""
-    if not os.path.exists(_LAUNCHER_PATH) and build_if_missing:
-        subprocess.run(["make", "-C", _NATIVE_DIR,
-                        "build/shadowtpu_launcher"],
-                       check=True, capture_output=True)
+    _make("build/shadowtpu_launcher")
     return _LAUNCHER_PATH
 
 
 _LAUNCHER_STATIC_PATH = os.path.join(_NATIVE_DIR, "build",
                                      "shadowtpu_launcher_static")
-_LAUNCHER_STATIC_RESULT = [False, None]     # [attempted, path|None]
 
 
-def launcher_static_path(build_if_missing: bool = True):
+def launcher_static_path():
     """Path to the STATIC launcher stub (preload backend's --run
     mode: rlimit cap + ASLR off + exec, with LD_PRELOAD inert in the
     stub itself), or None when no static libc exists on this machine
     (callers fall back to a preexec_fn). The build attempt is
     memoized — a machine without static libc must not pay a failing
     make per process spawn."""
-    if os.path.exists(_LAUNCHER_STATIC_PATH):
+    if _make("build/shadowtpu_launcher_static", check=False):
         return _LAUNCHER_STATIC_PATH
-    if not build_if_missing or _LAUNCHER_STATIC_RESULT[0]:
-        return _LAUNCHER_STATIC_RESULT[1]
-    _LAUNCHER_STATIC_RESULT[0] = True
-    r = subprocess.run(["make", "-C", _NATIVE_DIR,
-                        "build/shadowtpu_launcher_static"],
-                       capture_output=True)
-    if r.returncode == 0 and os.path.exists(_LAUNCHER_STATIC_PATH):
-        _LAUNCHER_STATIC_RESULT[1] = _LAUNCHER_STATIC_PATH
-    return _LAUNCHER_STATIC_RESULT[1]
+    return None

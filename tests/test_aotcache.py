@@ -12,7 +12,10 @@ The subsystem's contract, pinned:
   crash;
 * the cache is bounded: LRU eviction under a size cap;
 * two processes racing onto one entry both land complete files
-  (atomic tmp+rename — the loser's replace just lands second).
+  (atomic tmp+rename — the loser's replace just lands second);
+* an entry loads onto exactly the devices it was compiled for;
+* one placement rule: both caches under $JAX_COMPILATION_CACHE_DIR
+  when it is set, else under the checkout's .cache/jax.
 """
 
 import os
@@ -292,6 +295,62 @@ def test_concurrent_writers_never_leave_a_torn_entry(tmp_path):
     # no tmp debris from either writer
     assert not [n for n in os.listdir(cache_dir)
                 if n.endswith(".tmp")]
+
+
+# ---------------------------------------------------------------------------
+# placement: one rule for the XLA cache and the AOT executables
+# ---------------------------------------------------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+PLACEMENT_CHILD = r"""
+import sys
+sys.path.insert(0, {repo!r})
+from shadow_tpu._jax import jax
+from shadow_tpu.device import aotcache
+print(jax.config.jax_compilation_cache_dir)
+print(aotcache.default_dir())
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_cache_placement_rule(tmp_path, env_dir):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
+        root = str(tmp_path / "xla")
+    else:
+        root = os.path.join(REPO, ".cache", "jax")
+    p = subprocess.run(
+        [sys.executable, "-c", PLACEMENT_CHILD.format(repo=REPO)],
+        env=env, cwd=str(tmp_path), capture_output=True, text=True,
+        timeout=120)
+    assert p.returncode == 0, p.stderr
+    xla_dir, aot_dir = p.stdout.split()
+    assert xla_dir == root
+    assert aot_dir == os.path.join(root, "aot")
+    assert "~" not in xla_dir + aot_dir
+
+
+def test_entry_loads_onto_its_compiled_devices(tmp_path):
+    """A 1-device executable stored and loaded in an 8-device process
+    runs on its one device (deserialize's default would spread it
+    over every device and then reject 1-shard arguments)."""
+    import jax
+    import jax.numpy as jnp
+
+    dev = jax.devices()[3]
+    x = jax.device_put(jnp.arange(8.0), dev)
+    compiled = jax.jit(lambda v: v * 2).lower(x).compile()
+    cache = aotcache.AotCache(str(tmp_path / "one"))
+    assert cache.store("k", compiled, {})
+    loaded = cache.load("k")
+    assert loaded is not None
+    out = loaded(x)
+    assert np.array_equal(np.asarray(out), np.arange(8.0) * 2)
+    assert out.devices() == {dev}
 
 
 # ---------------------------------------------------------------------------

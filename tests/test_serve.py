@@ -20,7 +20,12 @@ from shadow_tpu.config import load_config
 from shadow_tpu.core.controller import Controller
 from shadow_tpu.device.chaos import ChaosInjector, events_from_config
 from shadow_tpu.serve import Campaign, Journal
-from shadow_tpu.serve.server import CampaignServer, ServerCrash, submit
+from shadow_tpu.serve.server import (
+    _ROTATION_RE,
+    CampaignServer,
+    ServerCrash,
+    submit,
+)
 
 YAML = """
 general:
@@ -231,9 +236,11 @@ def test_server_crash_recovery_resumes_bit_identical(tmp_path,
 
     def checkpointed():
         # arm the chaos server_crash drill the moment the first
-        # rotation checkpoint lands — the next tick kills the server
+        # rotation checkpoint lands — the next tick kills the server.
+        # Only a finished entry counts: the atomic writer's
+        # in-flight ``ck.npz.t<ns>.<pid>.tmp`` shares the prefix
         if srv.chaos is None and os.path.isdir(cdir) and any(
-                n.startswith("ck.npz.t") for n in os.listdir(cdir)):
+                _ROTATION_RE.match(n) for n in os.listdir(cdir)):
             srv.chaos = ChaosInjector(events_from_config(
                 [{"kind": "server_crash", "tick": 0}]))
         return False
