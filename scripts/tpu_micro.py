@@ -34,8 +34,9 @@ variant 6 — compile/dispatch attribution (IPU-dissection style,
 
 Every variant prints ONE JSON line. Timings use pipelined (async)
 dispatches with one final block so per-call overhead amortizes away —
-the numbers are on-chip costs, not dispatch RTTs (contrast
-scripts/profile_device.py, which syncs per call).
+the numbers are on-chip costs, not dispatch RTTs. The fused round's
+per-stage device time comes from a profiler trace instead: its ops
+carry `engine.*` scopes (docs/observability.md).
 """
 
 from __future__ import annotations

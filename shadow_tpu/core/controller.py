@@ -430,7 +430,7 @@ class Controller:
         # flight recorder (shadow_tpu/obs): ONE per run, attached to
         # whichever executor this config resolves to and published as
         # the module-global current() for call sites with no plumbing
-        # path (aotcache.ensure, capacity record I/O, engine.profile).
+        # path (aotcache.ensure, capacity record I/O).
         # A nested run (the hybrid failover rerun) receives its
         # parent's tracer instead, so the rerun's spans land in the
         # SAME trace under the parent's `failover` span — the parent

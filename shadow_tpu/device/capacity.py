@@ -148,8 +148,8 @@ def measure(engine, state, source: str = "run") -> dict:
     H = engine.config.n_hosts
     occ = {k: np.asarray(jax.device_get(state[k]))
            for k in ("occ_heap", "occ_ob", "occ_in", "occ_x",
-                     "occ_trips", "occ_phases", "overflow",
-                     "x_overflow")}
+                     "occ_trips", "occ_phases", "occ_iters",
+                     "overflow", "x_overflow")}
     eff = dict(engine.effective)
     # the full per-(src shard, dst shard) high-water matrix rides the
     # record (a few ints per shard pair): the exchange planner sizes
@@ -167,6 +167,7 @@ def measure(engine, state, source: str = "run") -> dict:
         "exchange_pairs": [[int(v) for v in row] for row in pairs],
         "pop_trips_max": int(occ["occ_trips"].max(initial=0)),
         "phases": int(occ["occ_phases"].max(initial=0)),
+        "pop_iters": int(occ["occ_iters"].max(initial=0)),
         "overflow": int(occ["overflow"][:H].sum()),
         "x_overflow": int(occ["x_overflow"][:H].sum()),
     }
@@ -440,7 +441,7 @@ def grow_heaps(host_state: dict, new_e: int) -> dict:
 # NIC scalars — are the residual class, shape-checked against the
 # padded width.)
 RESHARD_HOST_ROWS = ("ht", "hk", "hm", "hv", "hw", "app")
-RESHARD_SHARD_ZERO = ("occ_x", "occ_trips", "occ_phases")
+RESHARD_SHARD_ZERO = ("occ_x", "occ_trips", "occ_phases", "occ_iters")
 RESHARD_SHARD_SUM = ("path_cnt",)
 
 

@@ -342,7 +342,7 @@ class DeviceEngine:
         self._heap_builder = None       # jitted lazily by init_state
         # persistent AOT compile cache (device/aotcache.py): the
         # runner attaches one shared AotCache after construction;
-        # run()/run_ensemble()/profile() then dispatch each program
+        # run()/run_ensemble() then dispatch each program
         # through a cached (or freshly AOT-compiled + stored)
         # executable resolved on first use. The executables live in
         # _aot_exec — the _run/_pop_phase/... jit attributes stay
@@ -475,10 +475,12 @@ class DeviceEngine:
             #   occ_x     [S,S] max rows per (src shard, dst shard)
             #   occ_trips [S]  max pop-loop iterations per phase
             #   occ_phases[S]  total flushes executed
+            #   occ_iters [S]  total pop-loop iterations executed
             "occ_x": np.zeros((self.n_shards, self.n_shards),
                               dtype=np.int32),
             "occ_trips": np.zeros(self.n_shards, dtype=np.int32),
             "occ_phases": np.zeros(self.n_shards, dtype=np.int32),
+            "occ_iters": np.zeros(self.n_shards, dtype=np.int32),
         }
         if self.config.audit:
             # invariant-audit leaves (AUD_* bits above):
@@ -941,70 +943,71 @@ class DeviceEngine:
                 else:
                     smask = jnp.full((H_loc, K_eff), -1, jnp.int32)
             else:
-                if C > 1:
-                    ccum = jnp.cumsum(vcnt, axis=-1) - vcnt
-                    pkt_seq = state["packet_seq"][:, None] - \
-                        vcnt.sum(-1).astype(jnp.int32)[:, None] + ccum
-                else:
-                    pkt_seq = state["packet_seq"][:, None] - \
-                        send_valid.sum(-1).astype(jnp.int32)[:, None] \
-                        + vrank
-                srcv = host_vertex[gid][:, None]
-                dstv = host_vertex[jnp.clip(dst, 0, H_pad - 1)]
-                # epoch keyed on the SEND time (lane_t), matching the
-                # CPU model's judge(now=send time) under faults
-                latv = _tbl(lat, lane_t, srcv, dstv,
-                            ept).astype(jnp.int64)               # [H,K]
-                relv = _tbl(rel, lane_t, srcv, dstv, ept)
-            if not HOIST and C > 1:
-                # packet TRAINS: one drop roll per packet, keyed by the
-                # exact (src, pkt_seq0+j) sequence individual sends
-                # would consume — loss statistics are bit-identical to
-                # per-packet sends; survivors become the d2 bitmask
-                js = jnp.arange(C, dtype=jnp.int32)              # [C]
-                if ALL_REL1:
-                    # statically lossless: the roll can never drop
-                    drop3 = jnp.zeros((H_loc, K_eff, C), bool)
-                else:
-                    seqs3 = pkt_seq[..., None] + js              # [H,K,C]
-                    drop3 = packet_drop_mask(
-                        seed_pair, BOOT_END, lane_t[..., None],
-                        gid[:, None, None], seqs3, relv[..., None])
-                win3 = js[None, None, :] < counts[..., None]
-                if out.send_mask is not None:
-                    # forwarding a previous hop's survivors: only LIVE
-                    # lanes are packets (seq consumption + roll keys
-                    # still span all `counts` lanes — twin alignment)
-                    smask = jnp.broadcast_to(
-                        out.send_mask, (H_loc, K_eff)) \
-                        .astype(jnp.uint32)
-                    live3 = win3 & (jnp.right_shift(
-                        smask[..., None],
-                        js.astype(jnp.uint32)[None, None, :])
-                        & jnp.uint32(1)).astype(bool)
-                else:
-                    live3 = win3
-                lost3 = drop3 & live3 & send_valid[..., None]
-                surv = jnp.where(
-                    ~drop3 & live3,
-                    jnp.left_shift(jnp.uint32(1),
-                                   js.astype(jnp.uint32)),
-                    jnp.uint32(0)).sum(-1, dtype=jnp.uint32)     # [H,K]
-                surv = jnp.where(send_valid, surv, 0)
-                dropped = send_valid & (surv == 0)
-                n_lost = lost3.sum((-2, -1)).astype(jnp.int32)
-                livecnt = (live3 & send_valid[..., None]).sum(
-                    -1, dtype=jnp.int32)                         # [H,K]
-            elif not HOIST:
-                dropped = send_valid & (
-                    jnp.zeros((H_loc, K_eff), bool) if ALL_REL1
-                    else packet_drop_mask(
-                        seed_pair, BOOT_END, lane_t, gid[:, None],
-                        pkt_seq, relv))
-                surv = jnp.where(send_valid & ~dropped,
-                                 jnp.uint32(1), jnp.uint32(0))
-                n_lost = dropped.sum(-1).astype(jnp.int32)
-                livecnt = vcnt
+                with jax.named_scope("engine.judge"):
+                    if C > 1:
+                        ccum = jnp.cumsum(vcnt, axis=-1) - vcnt
+                        pkt_seq = state["packet_seq"][:, None] - \
+                            vcnt.sum(-1).astype(jnp.int32)[:, None] + ccum
+                    else:
+                        pkt_seq = state["packet_seq"][:, None] - \
+                            send_valid.sum(-1).astype(jnp.int32)[:, None] \
+                            + vrank
+                    srcv = host_vertex[gid][:, None]
+                    dstv = host_vertex[jnp.clip(dst, 0, H_pad - 1)]
+                    # epoch keyed on the SEND time (lane_t), matching the
+                    # CPU model's judge(now=send time) under faults
+                    latv = _tbl(lat, lane_t, srcv, dstv,
+                                ept).astype(jnp.int64)               # [H,K]
+                    relv = _tbl(rel, lane_t, srcv, dstv, ept)
+                    if C > 1:
+                        # packet TRAINS: one drop roll per packet, keyed by the
+                        # exact (src, pkt_seq0+j) sequence individual sends
+                        # would consume — loss statistics are bit-identical to
+                        # per-packet sends; survivors become the d2 bitmask
+                        js = jnp.arange(C, dtype=jnp.int32)              # [C]
+                        if ALL_REL1:
+                            # statically lossless: the roll can never drop
+                            drop3 = jnp.zeros((H_loc, K_eff, C), bool)
+                        else:
+                            seqs3 = pkt_seq[..., None] + js              # [H,K,C]
+                            drop3 = packet_drop_mask(
+                                seed_pair, BOOT_END, lane_t[..., None],
+                                gid[:, None, None], seqs3, relv[..., None])
+                        win3 = js[None, None, :] < counts[..., None]
+                        if out.send_mask is not None:
+                            # forwarding a previous hop's survivors: only LIVE
+                            # lanes are packets (seq consumption + roll keys
+                            # still span all `counts` lanes — twin alignment)
+                            smask = jnp.broadcast_to(
+                                out.send_mask, (H_loc, K_eff)) \
+                                .astype(jnp.uint32)
+                            live3 = win3 & (jnp.right_shift(
+                                smask[..., None],
+                                js.astype(jnp.uint32)[None, None, :])
+                                & jnp.uint32(1)).astype(bool)
+                        else:
+                            live3 = win3
+                        lost3 = drop3 & live3 & send_valid[..., None]
+                        surv = jnp.where(
+                            ~drop3 & live3,
+                            jnp.left_shift(jnp.uint32(1),
+                                           js.astype(jnp.uint32)),
+                            jnp.uint32(0)).sum(-1, dtype=jnp.uint32)     # [H,K]
+                        surv = jnp.where(send_valid, surv, 0)
+                        dropped = send_valid & (surv == 0)
+                        n_lost = lost3.sum((-2, -1)).astype(jnp.int32)
+                        livecnt = (live3 & send_valid[..., None]).sum(
+                            -1, dtype=jnp.int32)                         # [H,K]
+                    else:
+                        dropped = send_valid & (
+                            jnp.zeros((H_loc, K_eff), bool) if ALL_REL1
+                            else packet_drop_mask(
+                                seed_pair, BOOT_END, lane_t, gid[:, None],
+                                pkt_seq, relv))
+                        surv = jnp.where(send_valid & ~dropped,
+                                         jnp.uint32(1), jnp.uint32(0))
+                        n_lost = dropped.sum(-1).astype(jnp.int32)
+                        livecnt = vcnt
             if MB:
                 # TX fluid bucket (ModelNic.tx_depart): a burst's sends
                 # serialize in slot order; drop-rolled packets still
@@ -1023,27 +1026,26 @@ class DeviceEngine:
             elif not HOIST:
                 depart = lane_t
             if not HOIST:
-                delivered = send_valid & ~dropped
-                state["n_sent"] = state["n_sent"] + \
-                    livecnt.sum(-1).astype(jnp.int32)
-                state["n_drop"] = state["n_drop"] + n_lost
+                with jax.named_scope("engine.judge"):
+                    delivered = send_valid & ~dropped
+                    state["n_sent"] = state["n_sent"] + \
+                        livecnt.sum(-1).astype(jnp.int32)
+                    state["n_drop"] = state["n_drop"] + n_lost
+                    deliver_t = depart + latv
+                    cross = dst != gid[:, None]
+                    # cross-host causality bump (host_single.c:174-220);
+                    # self packets keep their true time — they may run this
+                    # window (the flush + another phase makes them
+                    # poppable)
+                    deliver_t = jnp.where(cross,
+                                          jnp.maximum(deliver_t, win_end),
+                                          deliver_t)
 
             # event seq consumed per SEND (delivered or dropped alike),
             # matching the CPU engines — lets the CPU side defer drop
             # judgment to a batched device call without perturbing seqs
             ev_seq = state["event_seq"][:, None] + vrank
             n_snt = send_valid.sum(-1).astype(jnp.int32)
-
-            if not HOIST:
-                deliver_t = depart + latv
-                cross = dst != gid[:, None]
-                # cross-host causality bump (host_single.c:174-220);
-                # self packets keep their true time — they may run this
-                # window (the flush + another phase makes them
-                # poppable)
-                deliver_t = jnp.where(cross,
-                                      jnp.maximum(deliver_t, win_end),
-                                      deliver_t)
 
             # model-NIC RX stage (ModelNic.rx_deliver twin): the popped
             # KIND_PACKET row passes the download bucket + event-driven
@@ -1695,9 +1697,10 @@ class DeviceEngine:
                 (shard_of != my_shard.astype(jnp.int64))
             state = _lost_to_local(state, lost_mask, skey, my_shard)
             win = _seg_take(perm, rows, starts, counts, CAP)
-            moved = {f: lax.all_to_all(
-                win[f], AXIS, split_axis=0, concat_axis=0)
-                .reshape(n_shards * CAP) for f in XF}
+            with jax.named_scope("engine.exchange"):
+                moved = {f: lax.all_to_all(
+                    win[f], AXIS, split_axis=0, concat_axis=0)
+                    .reshape(n_shards * CAP) for f in XF}
             kmoved = None
             if ship_keys:
                 kidx = jnp.clip(
@@ -1710,9 +1713,10 @@ class DeviceEngine:
                     jnp.take(skey, kidx.reshape(-1)).reshape(
                         n_shards, CAP),
                     IMAX)
-                kmoved = lax.all_to_all(
-                    kwin, AXIS, split_axis=0,
-                    concat_axis=0).reshape(n_shards * CAP)
+                with jax.named_scope("engine.exchange"):
+                    kmoved = lax.all_to_all(
+                        kwin, AXIS, split_axis=0,
+                        concat_axis=0).reshape(n_shards * CAP)
             return state, moved, kmoved
 
         # ---------------- two-phase hierarchical exchange --------------
@@ -1801,7 +1805,9 @@ class DeviceEngine:
             for o in range(1, g):
                 perm_o = [(s, (s // g) * g + ((s % g) + o) % g)
                           for s in range(n_shards)]
-                parts1.append(lax.ppermute(sbuf[:, o], AXIS, perm_o))
+                with jax.named_scope("engine.exchange"):
+                    parts1.append(lax.ppermute(sbuf[:, o], AXIS,
+                                               perm_o))
             C = len(TP_FIELDS)
             recv1 = jnp.stack(parts1, axis=1).reshape(C, g * CAP)
 
@@ -1817,7 +1823,8 @@ class DeviceEngine:
             shard_r, rank2 = _within_shard_rank(rkey_s)
             lost2 = (rkey_s < IMAX) & (shard_r != my64) & \
                 (rank2 >= CAP2)
-            n_lost2 = _axis_sum64(lost2.sum())
+            with jax.named_scope("engine.exchange"):
+                n_lost2 = _axis_sum64(lost2.sum())
 
             def _attr2(_):
                 # the lost rows' senders live on OTHER shards (this
@@ -1828,8 +1835,9 @@ class DeviceEngine:
                 sgs = lax.sort(sg)
                 hbg = jnp.searchsorted(
                     sgs, jnp.arange(H_pad + 1, dtype=jnp.int64))
-                hist = lax.psum(
-                    (hbg[1:] - hbg[:-1]).astype(jnp.int32), AXIS)
+                with jax.named_scope("engine.exchange"):
+                    hist = lax.psum(
+                        (hbg[1:] - hbg[:-1]).astype(jnp.int32), AXIS)
                 return lax.dynamic_slice(
                     hist, (my_shard * H_loc,), (H_loc,))
 
@@ -1849,7 +1857,8 @@ class DeviceEngine:
                     for c, ch in enumerate(TP_FIELDS)])
                 perm_q = [(s, ((s // g + q) % ng) * g + s % g)
                           for s in range(n_shards)]
-                parts2.append(lax.ppermute(buf2, AXIS, perm_q))
+                with jax.named_scope("engine.exchange"):
+                    parts2.append(lax.ppermute(buf2, AXIS, perm_q))
 
             out = jnp.concatenate([recv1] + parts2, axis=1)
             return state, out[0], \
@@ -1919,146 +1928,154 @@ class DeviceEngine:
                 # its own via the [lo, hi) mask inside _ob_rows
                 state, flat = _compact_flat(state, ob)
                 W = flat["t"].shape[0]
-                allf = {f: lax.all_gather(flat[f], AXIS)
-                        .reshape(n_shards * W) for f in XF}
+                with jax.named_scope("engine.exchange"):
+                    allf = {f: lax.all_gather(flat[f], AXIS)
+                            .reshape(n_shards * W) for f in XF}
                 parts = [_ob_rows(allf["t"], allf["k"], allf["m"],
                                   allf["s"], allf["v"], lo, hi)]
             else:
                 state, flat = _compact_flat(state, ob)
                 parts = [_ob_rows(flat["t"], flat["k"], flat["m"],
                                   flat["s"], flat["v"], lo, hi)]
-            return _merge_rows(state, parts)
+            with jax.named_scope("engine.merge"):
+                return _merge_rows(state, parts)
 
         def _exchange(state, ob, gid, my_shard, host_vertex, wrld,
                       win_end):
-            if HOIST:
-                state, ob = _judge_outbox(state, ob, gid, host_vertex,
-                                          wrld, win_end)
-            if CP:
-                state = _count_paths(state, ob, host_vertex)
-            # occupancy: exchangeable outbox rows per host this phase
-            # (post-judge, the population outbox_compact must hold)
-            state["occ_ob"] = jnp.maximum(
-                state["occ_ob"],
-                (ob["t"] < DROP_T).sum(-1).astype(jnp.int32))
-            state["occ_phases"] = state["occ_phases"] + jnp.int32(1)
-            if AUDIT:
-                # conservation ledger: every exchangeable row
-                # (post-judge t < DROP_T — sends, timers, READY
-                # reinserts) must land in some host's heap or be
-                # counted into overflow/x_overflow; _audit_round
-                # balances this ledger against pops + live rows
-                state["aud_tx"] = state["aud_tx"] + \
-                    (ob["t"] < DROP_T).sum(-1).astype(jnp.int64)
-            if MERGE_GLOBAL:
-                return _exchange_global(state, ob, gid, my_shard)
-            state, skey, perm, rows = _flat_sorted(state, ob, gid)
-            G = H_loc * CX
+            with jax.named_scope("engine.flush"):
+                if HOIST:
+                    with jax.named_scope("engine.judge"):
+                        state, ob = _judge_outbox(state, ob, gid,
+                                                  host_vertex, wrld,
+                                                  win_end)
+                if CP:
+                    state = _count_paths(state, ob, host_vertex)
+                # occupancy: exchangeable outbox rows per host this phase
+                # (post-judge, the population outbox_compact must hold)
+                state["occ_ob"] = jnp.maximum(
+                    state["occ_ob"],
+                    (ob["t"] < DROP_T).sum(-1).astype(jnp.int32))
+                state["occ_phases"] = state["occ_phases"] + jnp.int32(1)
+                if AUDIT:
+                    # conservation ledger: every exchangeable row
+                    # (post-judge t < DROP_T — sends, timers, READY
+                    # reinserts) must land in some host's heap or be
+                    # counted into overflow/x_overflow; _audit_round
+                    # balances this ledger against pops + live rows
+                    state["aud_tx"] = state["aud_tx"] + \
+                        (ob["t"] < DROP_T).sum(-1).astype(jnp.int64)
+                if MERGE_GLOBAL:
+                    return _exchange_global(state, ob, gid, my_shard)
+                state, skey, perm, rows = _flat_sorted(state, ob, gid)
+                G = H_loc * CX
 
-            inc2 = None
-            arr2 = jnp.zeros(H_loc, jnp.int32)
-            if n_shards > 1 and cfg.exchange == "all_to_all":
-                # SELF-SHARD rows (timers, model-NIC READY reinserts,
-                # local sends — often half the outbox) never need to
-                # move: they bypass the pack entirely (zero ICI, zero
-                # CAP consumption) and reach the merge as a second
-                # incoming block below. Only genuinely remote rows
-                # pack into [n_shards, CAP] for the all_to_all.
-                # my own range: straight per-host windows (IN each)
-                state, inc2, arr2 = _host_windows(state, skey, perm,
-                                                  rows, my_shard)
+                inc2 = None
+                arr2 = jnp.zeros(H_loc, jnp.int32)
+                if n_shards > 1 and cfg.exchange == "all_to_all":
+                    # SELF-SHARD rows (timers, model-NIC READY reinserts,
+                    # local sends — often half the outbox) never need to
+                    # move: they bypass the pack entirely (zero ICI, zero
+                    # CAP consumption) and reach the merge as a second
+                    # incoming block below. Only genuinely remote rows
+                    # pack into [n_shards, CAP] for the all_to_all.
+                    # my own range: straight per-host windows (IN each)
+                    state, inc2, arr2 = _host_windows(state, skey, perm,
+                                                      rows, my_shard)
 
-                state, moved, kmoved = _pack_remote(
-                    state, skey, perm, rows, my_shard,
-                    ship_keys=True)
-                G = n_shards * CAP
-                skey, perm = lax.sort(
-                    (kmoved, jnp.arange(G, dtype=jnp.int64)),
-                    num_keys=1)
-                rows = moved
-            elif n_shards > 1 and cfg.exchange == "two_phase":
-                # self-shard bypass identical to the direct path;
-                # the two-phase received block still holds relayed
-                # forwards, whose skeys fall outside this shard's
-                # host boundaries — _host_windows never takes them
-                state, inc2, arr2 = _host_windows(state, skey, perm,
-                                                  rows, my_shard)
-                state, kout, rout = _pack_two_phase(
-                    state, skey, perm, rows, my_shard)
-                G = kout.shape[0]
-                skey, perm = lax.sort(
-                    (kout, jnp.arange(G, dtype=jnp.int64)),
-                    num_keys=1)
-                rows = rout
-            elif n_shards > 1:
-                # all_gather fallback: replicate every shard's rows,
-                # then one global key re-sort (debug / hub-heavy)
-                rows = {f: lax.all_gather(rows[f], AXIS)
-                        .reshape(n_shards * G) for f in XF}
-                kg = lax.all_gather(skey, AXIS).reshape(n_shards * G)
-                pg = (lax.all_gather(perm, AXIS)
-                      .reshape(n_shards, G)
-                      + (jnp.arange(n_shards, dtype=jnp.int64)
-                         * G)[:, None]).reshape(n_shards * G)
-                skey, perm = lax.sort(
-                    (kg, pg), num_keys=2)
-                G = n_shards * G
+                    state, moved, kmoved = _pack_remote(
+                        state, skey, perm, rows, my_shard,
+                        ship_keys=True)
+                    G = n_shards * CAP
+                    skey, perm = lax.sort(
+                        (kmoved, jnp.arange(G, dtype=jnp.int64)),
+                        num_keys=1)
+                    rows = moved
+                elif n_shards > 1 and cfg.exchange == "two_phase":
+                    # self-shard bypass identical to the direct path;
+                    # the two-phase received block still holds relayed
+                    # forwards, whose skeys fall outside this shard's
+                    # host boundaries — _host_windows never takes them
+                    state, inc2, arr2 = _host_windows(state, skey, perm,
+                                                      rows, my_shard)
+                    state, kout, rout = _pack_two_phase(
+                        state, skey, perm, rows, my_shard)
+                    G = kout.shape[0]
+                    skey, perm = lax.sort(
+                        (kout, jnp.arange(G, dtype=jnp.int64)),
+                        num_keys=1)
+                    rows = rout
+                elif n_shards > 1:
+                    # all_gather fallback: replicate every shard's rows,
+                    # then one global key re-sort (debug / hub-heavy)
+                    with jax.named_scope("engine.exchange"):
+                        rows = {f: lax.all_gather(rows[f], AXIS)
+                                .reshape(n_shards * G) for f in XF}
+                        kg = lax.all_gather(skey, AXIS).reshape(
+                            n_shards * G)
+                        pg = lax.all_gather(perm, AXIS)
+                    pg = (pg.reshape(n_shards, G)
+                          + (jnp.arange(n_shards, dtype=jnp.int64)
+                             * G)[:, None]).reshape(n_shards * G)
+                    skey, perm = lax.sort(
+                        (kg, pg), num_keys=2)
+                    G = n_shards * G
 
-            # my hosts' contiguous arrival segments -> [H_loc, IN]
-            state, inc, arr = _host_windows(state, skey, perm, rows,
-                                            my_shard)
-            # occupancy: the self-shard bypass and the post-exchange
-            # arrivals are windowed to IN separately, so the
-            # capacity-relevant mark is the per-block max, not the sum
-            state["occ_in"] = jnp.maximum(state["occ_in"],
-                                          jnp.maximum(arr, arr2))
+                # my hosts' contiguous arrival segments -> [H_loc, IN]
+                state, inc, arr = _host_windows(state, skey, perm, rows,
+                                                my_shard)
+                # occupancy: the self-shard bypass and the post-exchange
+                # arrivals are windowed to IN separately, so the
+                # capacity-relevant mark is the per-block max, not the sum
+                state["occ_in"] = jnp.maximum(state["occ_in"],
+                                              jnp.maximum(arr, arr2))
 
-            # merge: one lexicographic row sort of [live heap | inc
-            # (| self-shard inc)] by (time, src<<32|seq) — keys +
-            # column iota only; payload columns follow via
-            # take_along_axis
-            def _inc_cols(b):
-                kindb = lo32(b["m"]) & 0xFF    # strip the train count
-                return (b["t"], b["k"],
-                        pack2(kindb, hi32(b["s"])),
-                        pack2(lo32(b["s"]), lo32(b["v"])),
-                        (b["v"] >> 32) & U32)  # d2 (train survivors)
+                with jax.named_scope("engine.merge"):
+                    # merge: one lexicographic row sort of [live heap | inc
+                    # (| self-shard inc)] by (time, src<<32|seq) — keys +
+                    # column iota only; payload columns follow via
+                    # take_along_axis
+                    def _inc_cols(b):
+                        kindb = lo32(b["m"]) & 0xFF    # strip the train count
+                        return (b["t"], b["k"],
+                                pack2(kindb, hi32(b["s"])),
+                                pack2(lo32(b["s"]), lo32(b["v"])),
+                                (b["v"] >> 32) & U32)  # d2 (train survivors)
 
-            blocks = [_inc_cols(inc)]
-            if inc2 is not None:
-                blocks.append(_inc_cols(inc2))
-            live = jnp.arange(E)[None, :] >= state["head"][:, None]
-            mt = jnp.where(live, state["ht"], INF)
-            mk = jnp.where(live, state["hk"], IMAX)
-            WID = E + IN * len(blocks)
-            ct = jnp.concatenate([mt] + [b[0] for b in blocks], axis=1)
-            ck = jnp.concatenate([mk] + [b[1] for b in blocks], axis=1)
-            ci = jnp.broadcast_to(
-                jnp.arange(WID, dtype=jnp.int32)[None, :],
-                (H_loc, WID))
-            st, sk, si = lax.sort((ct, ck, ci), dimension=1,
-                                  num_keys=2)
-            state["overflow"] = state["overflow"] + \
-                (st[:, E:] < INF).sum(-1).astype(jnp.int32)
-            sie = si[:, :E]
-            cm = jnp.concatenate([state["hm"]] + [b[2] for b in blocks],
-                                 axis=1)
-            cv = jnp.concatenate([state["hv"]] + [b[3] for b in blocks],
-                                 axis=1)
-            cw = jnp.concatenate([state["hw"]] + [b[4] for b in blocks],
-                                 axis=1)
-            state["ht"] = st[:, :E]
-            state["hk"] = sk[:, :E]
-            state["hm"] = jnp.take_along_axis(cm, sie, axis=1)
-            state["hv"] = jnp.take_along_axis(cv, sie, axis=1)
-            state["hw"] = jnp.take_along_axis(cw, sie, axis=1)
-            state["head"] = jnp.zeros_like(state["head"])
-            # occupancy: live heap rows after the merge — the rows
-            # event_capacity must hold
-            state["occ_heap"] = jnp.maximum(
-                state["occ_heap"],
-                (state["ht"] < INF).sum(-1).astype(jnp.int32))
-            return state
+                    blocks = [_inc_cols(inc)]
+                    if inc2 is not None:
+                        blocks.append(_inc_cols(inc2))
+                    live = jnp.arange(E)[None, :] >= state["head"][:, None]
+                    mt = jnp.where(live, state["ht"], INF)
+                    mk = jnp.where(live, state["hk"], IMAX)
+                    WID = E + IN * len(blocks)
+                    ct = jnp.concatenate([mt] + [b[0] for b in blocks], axis=1)
+                    ck = jnp.concatenate([mk] + [b[1] for b in blocks], axis=1)
+                    ci = jnp.broadcast_to(
+                        jnp.arange(WID, dtype=jnp.int32)[None, :],
+                        (H_loc, WID))
+                    st, sk, si = lax.sort((ct, ck, ci), dimension=1,
+                                          num_keys=2)
+                    state["overflow"] = state["overflow"] + \
+                        (st[:, E:] < INF).sum(-1).astype(jnp.int32)
+                    sie = si[:, :E]
+                    cm = jnp.concatenate([state["hm"]] + [b[2] for b in blocks],
+                                         axis=1)
+                    cv = jnp.concatenate([state["hv"]] + [b[3] for b in blocks],
+                                         axis=1)
+                    cw = jnp.concatenate([state["hw"]] + [b[4] for b in blocks],
+                                         axis=1)
+                    state["ht"] = st[:, :E]
+                    state["hk"] = sk[:, :E]
+                    state["hm"] = jnp.take_along_axis(cm, sie, axis=1)
+                    state["hv"] = jnp.take_along_axis(cv, sie, axis=1)
+                    state["hw"] = jnp.take_along_axis(cw, sie, axis=1)
+                    state["head"] = jnp.zeros_like(state["head"])
+                    # occupancy: live heap rows after the merge — the rows
+                    # event_capacity must hold
+                    state["occ_heap"] = jnp.maximum(
+                        state["occ_heap"],
+                        (state["ht"] < INF).sum(-1).astype(jnp.int32))
+                    return state
 
         # ---------------- round-end invariant audit --------------------
         # The health word: four cheap reduction-only checks folded
@@ -2113,11 +2130,15 @@ class DeviceEngine:
         # win_end / stalled on an in-window insert), then flushes. The
         # window advances only when no host has events under the
         # barrier; the predicate is a collective, so all shards agree.
-        def _round(state, win_end, gid, my_shard, host_vertex, wrld):
-            def _phase(state):
-                ob = {"t": jnp.full((H_loc, OB), INF, jnp.int64)}
-                for f in ("k", "m", "s", "v"):
-                    ob[f] = jnp.zeros((H_loc, OB), jnp.int64)
+        def _pop(state, ob, win_end, gid, host_vertex, wrld):
+            """One phase's pop loop: iterate _step until no host has a
+            runnable event before win_end or the outbox is full (B
+            iterations). Returns (state, ob, [1] iterations run)."""
+            with jax.named_scope("engine.pop"):
+                if ob is None:
+                    ob = {"t": jnp.full((H_loc, OB), INF, jnp.int64)}
+                    for f in ("k", "m", "s", "v"):
+                        ob[f] = jnp.zeros((H_loc, OB), jnp.int64)
                 dirty = jnp.zeros((H_loc,), bool)
 
                 def cond(c):
@@ -2126,14 +2147,20 @@ class DeviceEngine:
                     return ((nt < win_end) & ~dirty_).any() & \
                         (blk < B)
 
-                carry = lax.while_loop(
+                state, ob, blk, _ = lax.while_loop(
                     cond,
                     lambda c: _step(c, win_end, gid, host_vertex,
                                     wrld),
                     (state, ob, jnp.int32(0), dirty))
-                state2, ob, blk, _ = carry
-                state2["occ_trips"] = jnp.maximum(
-                    state2["occ_trips"], jnp.reshape(blk, (1,)))
+                blk = jnp.reshape(blk, (1,))
+                state["occ_trips"] = jnp.maximum(state["occ_trips"], blk)
+                state["occ_iters"] = state["occ_iters"] + blk
+                return state, ob, blk
+
+        def _round(state, win_end, gid, my_shard, host_vertex, wrld):
+            def _phase(state):
+                state2, ob, _ = _pop(state, None, win_end, gid,
+                                     host_vertex, wrld)
                 # skip the whole exchange when nothing was sent and no
                 # slots were consumed (idle windows). The predicate is
                 # COLLECTIVE: the flush contains all_to_all, so every
@@ -2161,7 +2188,8 @@ class DeviceEngine:
                 lambda c: (lambda s: (s, more(s)))(_phase(c[0])),
                 (state, more(state)))
             if AUDIT:
-                state = _audit_round(state)
+                with jax.named_scope("engine.audit"):
+                    state = _audit_round(state)
             return state
 
         # ---------------- full run ------------------------------------
@@ -2213,28 +2241,15 @@ class DeviceEngine:
                 _take_head(state["ht"], state["head"], INF).min())
             return state, nxt
 
-        # ---------------- phase-split profiling path -------------------
-        # the per-round cost hunt (BASELINE.md's 181 ms/round budget)
-        # needs pop-loop vs exchange vs merge attribution; these split
-        # jits let a host-side driver time each piece. They are traced
+        # ---------------- phase-split programs ------------------------
+        # one phase's pop loop and flush as separate jits, for
+        # micro-benchmarks of a stage in isolation (scripts/tpu_micro.py).
+        # They carry the fused round's stage scopes and are traced
         # lazily (first call), so the normal path pays nothing.
         def _pop_shard(state, ob, host_vertex, wrld, win_end):
             my_shard = lax.axis_index(AXIS)
             gid = (my_shard * H_loc + hidx).astype(jnp.int32)
-            dirty = jnp.zeros((H_loc,), bool)
-
-            def cond(c):
-                state_, _, blk, dirty_ = c
-                nt = _take_head(state_["ht"], state_["head"], INF)
-                return ((nt < win_end) & ~dirty_).any() & (blk < B)
-
-            state, ob, blk, _ = lax.while_loop(
-                cond,
-                lambda c: _step(c, win_end, gid, host_vertex, wrld),
-                (state, ob, jnp.int32(0), dirty))
-            state["occ_trips"] = jnp.maximum(
-                state["occ_trips"], jnp.reshape(blk, (1,)))
-            return state, ob, jnp.reshape(blk, (1,))
+            return _pop(state, ob, win_end, gid, host_vertex, wrld)
 
         def _flush_shard(state, ob, host_vertex, wrld, win_end):
             my_shard = lax.axis_index(AXIS)
@@ -2247,7 +2262,7 @@ class DeviceEngine:
                      "n_exec", "n_sent", "n_drop", "n_deliv",
                      "overflow", "x_overflow", "chk",
                      "occ_heap", "occ_ob", "occ_in", "occ_x",
-                     "occ_trips", "occ_phases") + \
+                     "occ_trips", "occ_phases", "occ_iters") + \
             (AUD_KEYS if AUDIT else ()) + \
             (NIC_KEYS if MB else ()) + \
             (("path_cnt",) if CP else ())
@@ -2334,12 +2349,22 @@ class DeviceEngine:
                 self, name, jit_fn, args)
         return self._aot_exec.get(name, jit_fn)
 
+    def program_text(self, name: str) -> Optional[str]:
+        """Optimized HLO text of program `name` ("run", "run_ens",
+        "pop", "flush") as dispatched through the AOT cache; each op's
+        metadata names its round stage (the `engine.*` scopes). None
+        when the program has no compiled executable here: no cache is
+        attached, it has not been dispatched yet, or the cache fell
+        back to the lazy jit."""
+        as_text = getattr(self._aot_exec.get(name), "as_text", None)
+        return as_text() if as_text is not None else None
+
     def world(self):
         """The traced world tuple (lat, rel, seed k1, seed k2,
         epoch_times) for the engine's own base world, replicated over
         the mesh — everything a run may vary without changing shapes
         (the ensemble program stacks R of these). Cached: the arrays
-        are fixed at construction, and run()/profile() call per
+        are fixed at construction, and run() calls it per
         segment — re-uploading the tables each dispatch would be pure
         waste."""
         if getattr(self, "_world_dev", None) is None:
@@ -2390,6 +2415,7 @@ class DeviceEngine:
         out["occ_x"] = sds((S, S), _np.int32)
         out["occ_trips"] = sds((S,), _np.int32)
         out["occ_phases"] = sds((S,), _np.int32)
+        out["occ_iters"] = sds((S,), _np.int32)
         if self.config.audit:
             out["aud"] = sds((H,), _np.int32)
             out["aud_t"] = sds((H,), _np.int64)
@@ -2663,90 +2689,3 @@ class DeviceEngine:
         args = (states, hv, self.ensemble_worlds_device(), stop_v,
                 final_v)
         return self._aot("run_ens", self._run_ens, args)(*args)
-
-    def profile(self, state: dict, stop: Optional[int] = None) -> dict:
-        """Phase-split run with host-side wall timing: the same round
-        structure as `run`, but each pop loop / flush executes as its
-        own jitted call with a block_until_ready fence, attributing
-        wall time to pop vs exchange+merge vs the host-sync probe.
-        Numbers include per-call dispatch + sync overhead the fused
-        `run` does not pay — use the breakdown for RATIOS and the
-        fused run for totals. Single- or multi-shard."""
-        import time as _time
-
-        repl = NamedSharding(self.mesh, self._repl_spec)
-        shard = NamedSharding(self.mesh, self._shard_spec)
-        hv = jax.device_put(jnp.asarray(self.host_vertex), repl)
-        wrld = self.world()
-        stop_t = self.config.stop_time if stop is None else stop
-        LA = max(1, self.config.lookahead)
-
-        def _ob():
-            ob = {"t": jax.device_put(
-                jnp.full(self._ob_shape_global, INF, jnp.int64),
-                shard)}
-            for f in ("k", "m", "s", "v"):
-                ob[f] = jax.device_put(
-                    jnp.zeros(self._ob_shape_global, jnp.int64), shard)
-            return ob
-
-        prof = {"rounds": 0, "phases": 0, "events": 0,
-                "pop_s": 0.0, "flush_s": 0.0, "probe_s": 0.0,
-                "compile_s": 0.0}
-        # compile both split programs up front so timings are steady;
-        # the AOT cache turns repeat profiles into warm starts (the
-        # split programs get their own cache keys)
-        t0 = _time.perf_counter()
-        win0 = jnp.int64(0)
-        pop_fn = self._aot("pop", self._pop_phase,
-                           (state, _ob(), hv, wrld, win0))
-        s_w, ob_w, _ = pop_fn(state, _ob(), hv, wrld, win0)
-        flush_fn = self._aot("flush", self._flush_phase,
-                             (s_w, ob_w, hv, wrld, win0))
-        jax.block_until_ready(flush_fn(s_w, ob_w, hv, wrld, win0))
-        jax.block_until_ready(self._probe(state))
-        prof["compile_s"] = _time.perf_counter() - t0
-
-        # the phase-split programs are the one place EXCHANGE wall is
-        # measured host-side (the fused run buries the flush inside
-        # the dispatch span) — record the splits as flight-recorder
-        # spans so a profiled run's trace shows pop vs flush lanes
-        from shadow_tpu.obs import trace as obstrace
-        tracer = obstrace.current()
-
-        exec0 = int(jnp.sum(state["n_exec"]))
-        t0 = _time.perf_counter()
-        nxt, _ = map(int, self._probe(state))
-        prof["probe_s"] += _time.perf_counter() - t0
-        t_all = _time.perf_counter()
-        while nxt < stop_t and prof["rounds"] < 10_000:
-            win_end = jnp.int64(min(nxt + LA, stop_t))
-            while True:
-                t0 = _time.perf_counter()
-                with tracer.span("profile.pop", "dispatch",
-                                 sim_t0=nxt, sim_t1=int(win_end)):
-                    state, ob, _ = pop_fn(state, _ob(), hv, wrld,
-                                          win_end)
-                    jax.block_until_ready(state)
-                prof["pop_s"] += _time.perf_counter() - t0
-
-                t0 = _time.perf_counter()
-                with tracer.span("profile.flush", "exchange",
-                                 sim_t0=nxt, sim_t1=int(win_end)):
-                    state = flush_fn(state, ob, hv, wrld,
-                                     win_end)
-                    jax.block_until_ready(state)
-                prof["flush_s"] += _time.perf_counter() - t0
-                prof["phases"] += 1
-
-                t0 = _time.perf_counter()
-                nu, _ = map(int, self._probe(state))
-                prof["probe_s"] += _time.perf_counter() - t0
-                if nu >= int(win_end):
-                    break
-            prof["rounds"] += 1
-            nxt = nu
-        prof["wall_s"] = _time.perf_counter() - t_all
-        prof["events"] = int(jnp.sum(state["n_exec"])) - exec0
-        prof["final_state"] = state
-        return prof

@@ -1165,9 +1165,9 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
                         # fused into the compiled round on-device,
                         # so its wall is inside the issued segment;
                         # the static per-flush ICI volume (buffers
-                        # ship at capacity) rides as counters
-                        # (engine.profile() measures the split
-                        # walls when real exchange timing is needed)
+                        # ship at capacity) rides as counters; its
+                        # device time is the `engine.exchange` scope
+                        # of a profiler trace
                         sp.add(exchange=eff["exchange"],
                                shards=eff["n_shards"],
                                ici_rows_per_flush=eff[

@@ -29,6 +29,9 @@ Three output surfaces (docs/observability.md):
   exactly the host-side Python time no span claims, so the buckets
   always sum to the total by construction.
 
+Every span is also a ``jax.profiler.TraceAnnotation``: under a running
+profiler it appears on the trace's host plane, on the profiler's clock.
+
 Modes (``experimental.telemetry``): ``off`` is a :class:`NullTracer`
 (every call a no-op — zero per-round work of any kind); ``summary``
 (the default) accumulates per-phase walls and a small recent-span
@@ -48,6 +51,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import time
 from collections import deque
 from typing import Optional
@@ -65,8 +69,10 @@ MODES = ("off", "summary", "trace")
 # "dispatch.issue" (asynchronous enqueue cost) and "dispatch.sync"
 # (blocking waits for device results) split the old conflated
 # "dispatch" bucket so device-bound and sync-bound wall are finally
-# distinguishable; "dispatch" itself remains for engine.profile()'s
-# fenced phase splits. "reshard" is the mesh-shrink failover's
+# distinguishable; "dispatch" and "exchange" stay as buckets because
+# METRICS records that used them are still read (trace_report). The
+# round's device stages are `engine.*` scopes in a profiler trace,
+# not spans. "reshard" is the mesh-shrink failover's
 # degradation cost (liveness probe + re-shard + re-place; the
 # rebuild's compile wall lands in "compile" as ever), "chaos" marks
 # scripted fault injections (instants — the faults themselves cost
@@ -147,10 +153,16 @@ class _Span:
     both would make the phase walls sum past the total). The JSONL /
     Perfetto records keep the GROSS duration (that is what a timeline
     renders), with ``self_s`` added when nested time was carved out.
+
+    The span is also a ``jax.profiler.TraceAnnotation`` of the same
+    name, so it lands on the host plane of any running profiler trace,
+    stamped by the profiler's clock beside the device's ops. jax is
+    never imported for it: a process that has not loaded jax runs no
+    profiler.
     """
 
     __slots__ = ("_tr", "name", "phase", "sim_t0", "sim_t1", "args",
-                 "_start", "_child_s")
+                 "_start", "_child_s", "_ann")
 
     def __init__(self, tr, name, phase, sim_t0, sim_t1, args):
         self._tr = tr
@@ -166,11 +178,18 @@ class _Span:
 
     def __enter__(self):
         self._tr._stack_of().append(self)
+        jax = sys.modules.get("jax")
+        self._ann = (jax.profiler.TraceAnnotation(self.name)
+                     if jax is not None else None)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         stack = self._tr._stack_of()
         if stack and stack[-1] is self:
             stack.pop()
@@ -187,8 +206,8 @@ class Tracer:
 
     The Controller creates ONE instance per run and attaches it to the
     runner and the Manager; module-global :func:`current` serves the
-    call sites with no plumbing path (aotcache, capacity,
-    engine.profile). Wall stamps are offsets from construction
+    call sites with no plumbing path (aotcache, capacity). Wall
+    stamps are offsets from construction
     (``perf_counter``), so the tracer's lifetime — not just the run()
     window — is the attribution total: pre-run work (bench's
     plan+warm, the engine's first compile) lands inside it.
@@ -437,7 +456,7 @@ class Tracer:
 
 # -- module-global current tracer -------------------------------------
 # set by the Controller for the run's lifetime; call sites without a
-# plumbing path (aotcache.ensure, capacity record I/O, engine.profile)
+# plumbing path (aotcache.ensure, capacity record I/O)
 # read it here. A fresh Controller overwrites it — the newest run owns
 # the recorder, which is the right owner for every in-process caller.
 _CURRENT: object = NullTracer()

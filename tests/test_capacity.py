@@ -89,6 +89,7 @@ def _replay_windows(boots, packets, H, L, stop, msgload, H_loc, S,
     occ_heap, occ_in, occ_ob = [0] * H, [0] * H, [0] * H
     occ_x = np.zeros((S, S), dtype=int)
     trips_max, phases = 0, 0
+    iters = np.zeros(S, dtype=int)
     while live:
         nxt = min(t for t, _, _ in live)
         if nxt >= stop:
@@ -126,6 +127,10 @@ def _replay_windows(boots, packets, H, L, stop, msgload, H_loc, S,
             occ_heap[h] = max(occ_heap[h], heap_now[h])
         occ_x = np.maximum(occ_x, x)
         trips_max = max(trips_max, max(per_exec))
+        # each shard's pop loop runs until ITS busiest host is done
+        for s in range(S):
+            iters[s] += max(per_exec[s * H_loc:(s + 1) * H_loc],
+                            default=0)
         phases += 1
     # the oracle trace is taken from a LONGER run so sends still in
     # flight at `stop` are visible (they ride the exchange and sit in
@@ -134,7 +139,7 @@ def _replay_windows(boots, packets, H, L, stop, msgload, H_loc, S,
     assert all(p[0] >= stop for p in pkts[ip:]), \
         "trace packets the replay never delivered"
     return dict(heap=occ_heap, inn=occ_in, ob=occ_ob, x=occ_x,
-                trips=trips_max, phases=phases)
+                trips=trips_max, phases=phases, iters=iters)
 
 
 @pytest.mark.parametrize("merge", [
@@ -197,6 +202,12 @@ def test_occupancy_marks_match_trace_brute_force(merge):
     # (burst_pops=1 here); dirty-slot stalls could only add iterations
     trips = int(np.asarray(final["occ_trips"]).max())
     assert trips >= ref["trips"]
+    # one phase per window (asserted above), so each shard's pop loop
+    # ran as many iterations as its busiest host popped events
+    np.testing.assert_array_equal(np.asarray(final["occ_iters"]),
+                                  ref["iters"])
+    assert stats.occupancy["measured"]["pop_iters"] == \
+        ref["iters"].max()
     assert stats.occupancy is not None
     assert stats.occupancy["measured"]["heap_rows_max"] == \
         max(ref["heap"])
@@ -205,6 +216,39 @@ def test_occupancy_marks_match_trace_brute_force(merge):
 # ---------------------------------------------------------------------
 # planner pure functions
 # ---------------------------------------------------------------------
+
+def test_pop_iterations_count_a_hosts_events_in_one_window():
+    """A host holding k events in one window costs the pop loop k
+    iterations (one event per host per iteration): after one round
+    occ_iters is k, with one flush, however many other hosts pop."""
+    import jax
+
+    from shadow_tpu.device.engine import INF
+
+    k = 5
+    c = Controller(_cfg("tpu", q=3, extra="  mesh_shards: 1\n"))
+    eng = c.runner.engine
+    st = eng.init_state(c.sim.starts)
+    boot = 100_000_000                       # the left group's start
+    ht, hk, hm, hw = (np.array(st[f]) for f in ("ht", "hk", "hm", "hw"))
+    assert ht[0, 0] == boot and hm[0, 0] >> 32 == KIND_BOOT
+    # host 0 also holds k-1 packets from host 1 just after its boot
+    for j in range(1, k):
+        ht[0, j] = boot + j
+        hk[0, j] = (1 << 32) | j
+        hm[0, j] = (KIND_PACKET << 32) | 512
+        hw[0, j] = 1
+    assert (ht[0, k:] == INF).all()
+    for f, a in (("ht", ht), ("hk", hk), ("hm", hm), ("hw", hw)):
+        st[f] = jax.device_put(a, st[f].sharding)
+    win_end = boot + eng.config.lookahead
+    out, _ = eng._round_step(st, np.int64(win_end),
+                             eng.host_vertex_device(), eng.world())
+    q = 3                                    # left hosts boot in window
+    assert int(np.asarray(out["occ_iters"])[0]) == k
+    assert int(np.asarray(out["occ_phases"])[0]) == 1
+    assert int(np.asarray(out["n_exec"]).sum()) == q + k - 1
+
 
 def test_plan_sizes_from_measurements():
     record = {"measured": {

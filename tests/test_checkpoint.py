@@ -263,6 +263,44 @@ def test_pre_telemetry_checkpoint_loads(tmp_path):
         _run(f"  checkpoint_load: {ck}")
 
 
+def test_pop_iteration_counter_survives_save_and_resume(tmp_path):
+    """occ_iters is cumulative: a resumed run carries the saved count
+    on, ending where the uninterrupted run ends; a checkpoint saved
+    before the counter existed still loads, the count then covering
+    the resumed part only."""
+    import json
+
+    import numpy as np
+
+    def iters(c):
+        # per shard: each shard's pop loop counts its own iterations
+        return np.asarray(c.runner.final_state["occ_iters"])
+
+    ck = str(tmp_path / "state.npz")
+    full_stats, full_c = _run()
+    _, part_c = _run(f"  checkpoint_save: {ck}\n"
+                     f"  checkpoint_save_time: 1500ms")
+    assert (iters(part_c) > 0).any()
+    assert (iters(part_c) <= iters(full_c)).all()
+    assert (iters(part_c) < iters(full_c)).any()
+    _, res_c = _run(f"  checkpoint_load: {ck}")
+    np.testing.assert_array_equal(iters(res_c), iters(full_c))
+
+    with np.load(ck, allow_pickle=False) as z:
+        meta = json.loads(str(z["__meta__"]))
+        saved = {k: z[f"leaf_{i}"] for i, k in enumerate(meta["keys"])}
+    meta["keys"] = [k for k in meta["keys"] if k != "['occ_iters']"]
+    assert len(meta["keys"]) == len(saved) - 1
+    with open(ck, "wb") as f:
+        np.savez_compressed(f, __meta__=json.dumps(meta), **{
+            f"leaf_{i}": saved[k] for i, k in enumerate(meta["keys"])})
+    old_stats, old_c = _run(f"  checkpoint_load: {ck}")
+    assert old_stats.ok
+    assert _sig(old_stats, old_c) == _sig(full_stats, full_c)
+    np.testing.assert_array_equal(iters(old_c),
+                                  iters(full_c) - iters(part_c))
+
+
 @pytest.mark.slow
 def test_resume_adopts_saved_capacities_under_plan(tmp_path,
                                                    monkeypatch):

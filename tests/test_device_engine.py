@@ -274,6 +274,37 @@ def test_tpu_default_knobs_identical_traces():
     assert a == b
 
 
+@pytest.mark.parametrize("placement,merge", [("flush", "global"),
+                                             ("step", "window")])
+def test_round_program_names_its_stages(placement, merge):
+    """The optimized HLO of the dispatched round program names each
+    op's stage in its metadata: on the TPU path (judge hoisted to the
+    flush, global merge) and with both off (in-step judge, window
+    merge), over the 8-device mesh (so the exchange is there too)."""
+    yaml = PHOLD_YAML.format(policy="tpu", seed=7, loss=0.1, q=4,
+                             msgload=2)
+    yaml = yaml.replace("experimental:",
+                        f"experimental:\n  judge_placement: {placement}"
+                        f"\n  merge_strategy: {merge}")
+    c = Controller(load_config_str(yaml))
+    assert c.run().ok
+    eng = c.runner.engine
+    hoisted = placement == "flush"
+    assert eng.program_facts["judge_hoist"] is hoisted
+    assert eng.program_facts["merge_global"] is (merge == "global")
+    text = eng.program_text("run")
+    for scope in ("engine.pop", "engine.judge", "engine.flush",
+                  "engine.exchange", "engine.merge"):
+        assert f"/{scope}/" in text, scope
+    # the in-step judge sits inside the pop loop; the hoisted one in
+    # the flush
+    outer = "engine.flush" if hoisted else "engine.pop"
+    assert f"{outer}/engine.judge/" in text or \
+        f"{outer}/while/body/engine.judge/" in text
+    # a program never dispatched has no text
+    assert eng.program_text("flush") is None
+
+
 def test_pop_strategy_identical_traces_phold():
     """One-hot masked-reduction head reads vs take_along_axis: the
     pop loop must yield the same event order (and thus bit-identical

@@ -117,6 +117,43 @@ def test_null_tracer_is_inert(tmp_path):
     assert tr.finalize() is None
 
 
+def test_spans_land_in_a_profiler_trace(tmp_path):
+    """Every recorder span is also a jax.profiler TraceAnnotation: under
+    a running profiler it shows on the trace's host plane, by name and
+    nested as recorded; the off tracer's spans add nothing."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    tr = Tracer(mode="summary")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("dispatch.sync", "dispatch.sync"):
+            with tr.span("heartbeat"):
+                time.sleep(0.002)
+        with NullTracer().span("null.span", "dispatch"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                      recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    events.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns))
+    assert len(events["dispatch.sync"]) == len(events["heartbeat"]) == 1
+    (s0, s1), (h0, h1) = events["dispatch.sync"][0], events["heartbeat"][0]
+    assert s0 <= h0 < h1 <= s1
+    assert "null.span" not in events
+    # the recorder's own record is unchanged
+    assert [r["name"] for r in tr.recent()] == ["heartbeat",
+                                                 "dispatch.sync"]
+
+
 def test_current_tracer_swap():
     tr = Tracer(mode="summary")
     old = current()
