@@ -17,7 +17,8 @@ variant 3 — the candidate gatherless flush (double-sort merge) timed
   shape. Args: [reps].
 variant 4 — the round's remaining gathers + one-hot pop head reads:
   host_vertex/table gathers vs unrolled one-hot sums, P=1 and P=8 pop
-  reads. Args: [reps].
+  reads, and the judge's lookups at R = 2, 9 and 128 host-vertex runs
+  (gathers vs run-table selects vs per-host rows). Args: [reps].
 variant 5 — the cross-shard exchange in isolation (IPU-dissection
   style attribution): the flush phase timed per exchange schedule —
   dense auto-sized all_to_all, occ_x-planned (compacted) all_to_all,
@@ -630,8 +631,102 @@ def variant4(args: list[str]) -> int:
                                   lambda: foP(ht, head), reps)
     assert bool(jnp.all(fgP(ht, head) == foP(ht, head)))
 
+    for R in (2, 9, 128):
+        r.update(_judge_lookups(R, H, OB, V, rng, reps))
+
     print(json.dumps(r))
     return 0
+
+
+def _judge_lookups(R, H, OB, V, rng, reps):
+    """The judge's per-lane topology lookups at R host-vertex runs:
+    the indexed gathers (host_vertex[dst], lat/rel[srcv, dstv]) against
+    the run-table forms the engine can use instead. The run starts and
+    vertices are jit arguments, as in the engine, not constants."""
+    import numpy as np
+    from shadow_tpu._jax import jax, jnp
+
+    starts = np.sort(rng.choice(np.arange(1, H), R - 1, replace=False))
+    vrun = np.zeros(R, np.int32)
+    for j in range(1, R):           # adjacent runs differ
+        vrun[j] = (vrun[j - 1] + 1 + rng.randint(V - 1)) % V
+    hv_np = np.repeat(vrun, np.diff(np.r_[0, starts, H]))
+    hv = jnp.asarray(hv_np.astype(np.int32))
+    st, vr = jnp.asarray(starts.astype(np.int32)), jnp.asarray(vrun)
+    lat = jnp.asarray(rng.randint(5e6, 1.4e8, (V, V)).astype(np.int32))
+    rel = jnp.asarray(rng.uniform(0.99, 1.0, (V, V)).astype(np.float32))
+    dst = jnp.asarray(rng.randint(0, H, (H, OB)).astype(np.int32))
+    srcv = hv[:, None]
+    out = {}
+
+    def gather(hv, d):
+        dv = hv[jnp.clip(d, 0, H - 1)]
+        return (lat[srcv, dv].astype(jnp.int64), rel[srcv, dv])
+
+    def run_select(st, vr, d):        # the step function by selects
+        dv = jnp.broadcast_to(vr[0], d.shape)
+        for j in range(R - 1):
+            dv = jnp.where(d >= st[j], vr[j + 1], dv)
+        return dv
+
+    def run_stepsum(st, vr, d):       # v_0 + sum (d >= s_j) * delta_j
+        dv = jnp.broadcast_to(vr[0], d.shape)
+        for j in range(R - 1):
+            dv = dv + (d >= st[j]).astype(jnp.int32) * (vr[j + 1] - vr[j])
+        return dv
+
+    def onehot(st, vr, d):            # run select + V*V one-hot sums
+        pair = srcv * V + run_select(st, vr, d)
+        lv = jnp.zeros(d.shape, jnp.int64)
+        rv = jnp.zeros(d.shape, jnp.float32)
+        lf, rf = lat.reshape(-1), rel.reshape(-1)
+        for j in range(V * V):
+            m = pair == j
+            lv = lv + jnp.where(m, lf[j].astype(jnp.int64), 0)
+            rv = rv + jnp.where(m, rf[j], jnp.float32(0))
+        return lv, rv
+
+    def rows(vr):                     # once per program invocation
+        lr = lat[srcv, vr[None, :]]
+        rr = rel[srcv, vr[None, :]]
+        return lr, rr
+
+    def row_select(st, lr, rr, d):    # R-way select per lane
+        lv, rv = lr[:, :1], rr[:, :1]
+        for j in range(R - 1):
+            past = d >= st[j]
+            lv = jnp.where(past, lr[:, j + 1:j + 2], lv)
+            rv = jnp.where(past, rr[:, j + 1:j + 2], rv)
+        return (jnp.broadcast_to(lv, d.shape).astype(jnp.int64),
+                jnp.broadcast_to(rv, d.shape))
+
+    f_dv = jax.jit(lambda hv, d: hv[jnp.clip(d, 0, H - 1)])
+    f_sel, f_sum = jax.jit(run_select), jax.jit(run_stepsum)
+    f_g, f_oh, f_rows, f_rs = (jax.jit(gather), jax.jit(onehot),
+                               jax.jit(rows), jax.jit(row_select))
+    lr, rr = f_rows(vr)
+    want = f_g(hv, dst)
+    for got in (f_oh(st, vr, dst), f_rs(st, lr, rr, dst)):
+        assert all(bool(jnp.all(a == b)) for a, b in zip(want, got))
+    assert bool(jnp.all(f_sel(st, vr, dst) == f_dv(hv, dst)))
+    assert bool(jnp.all(f_sum(st, vr, dst) == f_dv(hv, dst)))
+    out[f"e_R{R}_vertex_gather"] = timed_ms(
+        f"e R={R} host_vertex[dst]", lambda: f_dv(hv, dst), reps)
+    out[f"e_R{R}_vertex_select"] = timed_ms(
+        f"e R={R} run select", lambda: f_sel(st, vr, dst), reps)
+    out[f"e_R{R}_vertex_stepsum"] = timed_ms(
+        f"e R={R} run step sum", lambda: f_sum(st, vr, dst), reps)
+    out[f"f_R{R}_judge_gather"] = timed_ms(
+        f"f R={R} judge lookups, gathers", lambda: f_g(hv, dst), reps)
+    out[f"f_R{R}_judge_onehot"] = timed_ms(
+        f"f R={R} judge lookups, run select + VxV one-hot",
+        lambda: f_oh(st, vr, dst), reps)
+    out[f"f_R{R}_judge_rows"] = timed_ms(
+        f"f R={R} judge lookups, per-host rows",
+        lambda: f_rs(st, lr, rr, dst), reps)
+    out[f"f_R{R}_rows_build"] = timed_ms(
+        f"f R={R} per-host row build", lambda: f_rows(vr), reps)
+    return out
 
 
 # ---------------------------------------------------------------------

@@ -376,6 +376,7 @@ def _build_engine(exchange="all_to_all", app=None, ensemble=None,
     from shadow_tpu.device.engine import DeviceEngine, EngineConfig
 
     H = cfg_kw.pop("H", 8)
+    hv = cfg_kw.pop("host_vertex", np.zeros(H, np.int32))
     cfg_kw.setdefault("event_capacity", 8)
     cfg_kw.setdefault("outbox_capacity", 8)
     cfg = EngineConfig(n_hosts=H, lookahead=1_000_000,
@@ -390,7 +391,7 @@ def _build_engine(exchange="all_to_all", app=None, ensemble=None,
         lat = np.stack([lat] * epochs)
         rel = np.stack([rel] * epochs)
         ept = (np.arange(epochs) * 5_000_000).astype(np.int64)
-    return DeviceEngine(cfg, app, np.zeros(H, np.int32), lat, rel,
+    return DeviceEngine(cfg, app, hv, lat, rel,
                         epoch_times=ept, ensemble=ensemble)
 
 
@@ -442,8 +443,9 @@ def engine_matrix() -> list[tuple[str, object]]:
                                          pop_onehot=True,
                                          judge_hoist=True,
                                          outbox_compact=4)),
-        ("table_onehot", _build_engine(table_onehot=True,
-                                       judge_hoist=True)),
+        ("table_onehot", _build_engine(
+            table_onehot=True, judge_hoist=True,
+            host_vertex=np.array([0, 1, 1, 0, 0, 0, 1, 0], np.int32))),
         ("tgen_faults", _build_engine(app=tgen, epochs=2,
                                       event_capacity=16,
                                       outbox_capacity=16)),

@@ -458,10 +458,13 @@ class ExperimentalOptions:
     # take_along_axis (cheaper on one CPU core). "auto" picks by
     # platform. Bit-identical traces either way.
     pop_strategy: str = "auto"      # auto | onehot | gather
-    # topology-table lookups in the hoisted judge: "onehot" unrolls
-    # the [V,V] lat/rel lookups into masked sums (no gather; V*V <=
-    # 128 only), "gather" keeps indexed lookups. "auto" = gather
-    # until the on-chip micro decides. Bit-identical either way.
+    # the judge's topology lookups on the device engine: "onehot"
+    # resolves each send's path latency and reliability by
+    # compare-selects over host_vertex's runs (no gather; one fault
+    # epoch, dense tables, no model_bandwidth, V*V <= 128 and at most
+    # 128 host-vertex runs, else the gathers stay), "gather" keeps
+    # indexed lookups (cheaper on one CPU core). "auto" picks by
+    # platform. Bit-identical traces either way.
     table_strategy: str = "auto"    # auto | onehot | gather
     # burst-pop lane width override (0 = the app's own declaration):
     # burst apps (tgen servers, tor relays) pop up to this many

@@ -185,12 +185,20 @@ class EngineConfig:
     # reduction is real work). None = auto by platform. Traces are
     # bit-identical either way (tests pin both).
     pop_onehot: Optional[bool] = None
-    # topology-table lookups (lat[srcv,dstv] / rel[srcv,dstv] in the
-    # hoisted judge): True = one-hot masked sums over the V*V table
-    # (unrolled; only legal for V*V <= 128) — no gather; False =
-    # indexed gather. None = False everywhere until the on-chip
-    # micro (scripts/tpu_micro.py --variant 4) decides. Selection is exact
-    # (single nonzero term), so traces are bit-identical either way.
+    # the judge's topology lookups (a send lane's path latency and
+    # reliability, lat/rel[host_vertex[src], host_vertex[dst]], and
+    # the pop loop's self-latency): True = compare-selects, no gather
+    # — host_vertex is a step function of the host id with R runs, so
+    # a lane finds its destination's vertex by R-1 comparisons against
+    # the run starts (derived once per program invocation) and the
+    # (src, dst) pair by one-hot masked sums over the V*V table. Legal
+    # for one fault epoch, dense tables, no model_bandwidth, V*V <= 128
+    # and R <= 128; elsewhere the indexed gathers stay. False =
+    # indexed gathers (the cheaper form on one CPU core). None = auto
+    # by platform: on a v5e the three gathers take 6.9 ms for
+    # [10000 x 40] lanes, the selects 0.4 ms at R = 2, 9 and 128
+    # (scripts/tpu_micro.py --variant 4). Selection is exact, so
+    # traces are bit-identical either way.
     table_onehot: Optional[bool] = None
     # on-device invariant audit (experimental.state_audit): compile a
     # per-host health word of cheap reductions into the round program
@@ -657,19 +665,25 @@ class DeviceEngine:
                 return tab[sv, dv]
             return tab[_ep_of(t, ept), sv, dv]
 
-        # one-hot topology-table lookups (see EngineConfig.table_onehot)
-        TAB_ONEHOT = bool(cfg.table_onehot) and V * V <= 128 \
-            and T_EP == 1 and not HIER
-        if cfg.table_onehot and not TAB_ONEHOT:
-            if T_EP > 1:
-                log.info("table_onehot disabled: fault epoch table "
-                         "(T=%d) uses the indexed gather", T_EP)
-            elif HIER:
-                log.info("table_onehot disabled: hierarchical "
-                         "representation uses the factored gather")
-            else:
-                log.info("table_onehot disabled: V*V = %d > 128",
-                         V * V)
+        # gather-free topology lookups (see EngineConfig.table_onehot).
+        # The run count of host_vertex (padding included) is a static
+        # fact of the program; the run starts and vertices stay traced
+        # values, derived on the device by _topo.
+        hv_np = self.host_vertex
+        N_RUNS = 1 + int(np.count_nonzero(hv_np[1:] != hv_np[:-1]))
+        want_tab = (cfg.table_onehot if cfg.table_onehot is not None
+                    else platform == "tpu")
+        why_not = ("model_bandwidth judges in the step" if MB else
+                   f"fault epoch table (T={T_EP})" if T_EP > 1 else
+                   "hierarchical representation" if HIER else
+                   f"V*V = {V * V} > 128" if V * V > 128 else
+                   f"{N_RUNS} host-vertex runs > 128" if N_RUNS > 128
+                   else None)
+        TAB_ONEHOT = bool(want_tab) and why_not is None
+        R_RUNS = N_RUNS if TAB_ONEHOT else 0
+        if want_tab and not TAB_ONEHOT:
+            log.info("table_onehot disabled: %s; the lookups gather",
+                     why_not)
         # statically lossless topologies (all reliability == 1) never
         # drop: packet_drop_mask is False for every row regardless of
         # the roll, so the threefry batch is skipped outright. Under
@@ -730,14 +744,80 @@ class DeviceEngine:
                 arr, jnp.minimum(head, E - 1)[:, None], axis=1)[:, 0]
             return jnp.where(head < E, v, fill)
 
+        def _pair(tab, sv, dv):
+            """tab[sv, dv] of a dense [V,V] table by one-hot masked
+            sums over its V*V entries (exact: one nonzero term)."""
+            pair = sv * jnp.int32(V) + dv
+            flat = tab.reshape(-1)
+            out = jnp.zeros(pair.shape, tab.dtype)
+            for j in range(V * V):
+                out = out + jnp.where(pair == j, flat[j],
+                                      jnp.zeros((), tab.dtype))
+            return out
+
+        def _topo(host_vertex, my_shard, wrld):
+            """What the round's topology lookups read, built once per
+            program invocation, outside the round loop: the host ->
+            vertex table "hv", and under TAB_ONEHOT what replaces its
+            per-lane gathers. host_vertex is constant on R_RUNS runs of
+            host ids: "starts" [R-1] are the starts of the runs after
+            the first, "vrun" [R] their vertices, "srcv" [H_loc] this
+            shard's hosts' vertices (a contiguous slice) and "self"
+            [H_loc] their self-latency."""
+            topo = {"hv": host_vertex}
+            if not TAB_ONEHOT:
+                return topo
+            if R_RUNS > 1:
+                # the run starts in order, with each run's vertex: one
+                # [H_pad] compare and a sort that puts the R-1 change
+                # positions first
+                chg = host_vertex[1:] != host_vertex[:-1]
+                pos = jnp.arange(1, H_pad, dtype=jnp.int32)
+                skey, sval = lax.sort(
+                    (jnp.where(chg, pos, jnp.int32(H_pad)),
+                     host_vertex[1:]), num_keys=1)
+                topo["starts"] = skey[:R_RUNS - 1]
+                topo["vrun"] = jnp.concatenate(
+                    [host_vertex[:1], sval[:R_RUNS - 1]])
+            else:
+                topo["vrun"] = host_vertex[:1]
+            srcv = lax.dynamic_slice(host_vertex, (my_shard * H_loc,),
+                                     (H_loc,))
+            topo["srcv"] = srcv
+            topo["self"] = _pair(wrld[0], srcv, srcv).astype(jnp.int64)
+            return topo
+
+        def _lookup(topo, wrld, gid, t, dst):
+            """Path (latency i64, reliability) of each send lane
+            [H, n] from its host to dst at send time t. Under
+            TAB_ONEHOT dst's vertex is that of the last run whose
+            start is <= dst — a dst outside [0, H_pad) lands on the
+            first or last run, as the gather's clip does — and the
+            pair is read by _pair's one-hot sums."""
+            lat, rel, _, _, ept = wrld
+            if TAB_ONEHOT:
+                vrun = topo["vrun"]
+                dstv = jnp.broadcast_to(vrun[0], dst.shape)
+                for j in range(R_RUNS - 1):
+                    dstv = jnp.where(dst >= topo["starts"][j],
+                                     vrun[j + 1], dstv)
+                srcv = topo["srcv"][:, None]
+                return (_pair(lat, srcv, dstv).astype(jnp.int64),
+                        _pair(rel, srcv, dstv))
+            hv = topo["hv"]
+            srcv = hv[gid][:, None]
+            dstv = hv[jnp.clip(dst, 0, H_pad - 1)]
+            return (_tbl(lat, t, srcv, dstv, ept).astype(jnp.int64),
+                    _tbl(rel, t, srcv, dstv, ept))
+
         # ---------------- inner loop body: one event per host ----------
         # (up to P events for an app's declared burst hosts)
         # `wrld` is the traced per-world tuple (lat, rel, seed k1,
         # seed k2, epoch times): everything a replica may vary without
         # changing shapes — the ensemble program vmaps over a stacked
         # axis of exactly these plus the state.
-        def _step(carry, win_end, gid, host_vertex, wrld):
-            lat, rel, sk1, sk2, ept = wrld
+        def _step(carry, win_end, gid, topo, wrld):
+            lat, _, sk1, sk2, ept = wrld
             seed_pair = (sk1, sk2)
             state, ob, blk, dirty = carry
             head = state["head"]
@@ -952,13 +1032,10 @@ class DeviceEngine:
                         pkt_seq = state["packet_seq"][:, None] - \
                             send_valid.sum(-1).astype(jnp.int32)[:, None] \
                             + vrank
-                    srcv = host_vertex[gid][:, None]
-                    dstv = host_vertex[jnp.clip(dst, 0, H_pad - 1)]
                     # epoch keyed on the SEND time (lane_t), matching the
                     # CPU model's judge(now=send time) under faults
-                    latv = _tbl(lat, lane_t, srcv, dstv,
-                                ept).astype(jnp.int64)               # [H,K]
-                    relv = _tbl(rel, lane_t, srcv, dstv, ept)
+                    latv, relv = _lookup(topo, wrld, gid, lane_t,
+                                         dst)                    # [H,K]
                     if C > 1:
                         # packet TRAINS: one drop roll per packet, keyed by the
                         # exact (src, pkt_seq0+j) sequence individual sends
@@ -1184,9 +1261,12 @@ class DeviceEngine:
                 # send still stalls the host one phase, which only
                 # moves the phase boundary, never the per-host pop
                 # order (the trace is bit-identical either way)
-                hvg = host_vertex[gid][:, None]                  # [H,1]
-                selflat = _tbl(lat, depart, hvg, hvg,
-                               ept).astype(jnp.int64)
+                if TAB_ONEHOT:
+                    selflat = topo["self"][:, None]              # [H,1]
+                else:
+                    hvg = topo["hv"][gid][:, None]               # [H,1]
+                    selflat = _tbl(lat, depart, hvg, hvg,
+                                   ept).astype(jnp.int64)
                 self_in = send_valid & (dst == gid[:, None]) & \
                     (depart + selflat < win_end)
                 tim_in = timer_valid & (timer_t < win_end)
@@ -1243,7 +1323,9 @@ class DeviceEngine:
                           "tp_groups": [int(TP_G), int(TP_NG)],
                           "ICI_rows_per_flush": int(ici_rows),
                           "ICI_bytes_per_flush":
-                              int(ici_rows) * ici_arrays * 8}
+                              int(ici_rows) * ici_arrays * 8,
+                          "table_onehot": bool(TAB_ONEHOT),
+                          "vertex_runs": int(R_RUNS)}
         # the resolved compile-time surface of the traced programs:
         # every value the trace bakes in as a constant (capacities,
         # platform-resolved strategy flags, lookahead/bootstrap,
@@ -1274,6 +1356,9 @@ class DeviceEngine:
             "merge_global": bool(MERGE_GLOBAL),
             "pop_onehot": bool(POP_ONEHOT),
             "table_onehot": bool(TAB_ONEHOT),
+            # the judge's run table is unrolled over the runs, so
+            # tables with another run count never share an executable
+            "vertex_runs": int(R_RUNS),
             "all_rel1": bool(ALL_REL1),
             "burst_pops": int(P),
             "lanes": {"K": int(K), "K_eff": int(K_eff), "T": int(T),
@@ -1289,6 +1374,10 @@ class DeviceEngine:
             "ensemble_replicas": (int(self.ensemble.R)
                                   if self.ensemble is not None else 0),
         }
+        log.info("engine strategies (%s): judge_hoist=%s "
+                 "merge_global=%s pop_onehot=%s table_onehot=%s "
+                 "vertex_runs=%d", platform, HOIST, MERGE_GLOBAL,
+                 POP_ONEHOT, TAB_ONEHOT, R_RUNS)
 
         def _flat_sorted(state, ob, gid):
             slot = jnp.arange(OB, dtype=jnp.int64)[None, :]
@@ -1326,7 +1415,7 @@ class DeviceEngine:
                 (skey, jnp.arange(F, dtype=jnp.int64)), num_keys=1)
             return state, skey_s, perm, flat
 
-        def _count_paths(state, ob, host_vertex):
+        def _count_paths(state, ob, topo):
             """topology_incrementPathPacketCounter parity: a [V,V]
             histogram of SENT packets per (src_vertex, dst_vertex),
             drop-rolled packets included — scatter-free via one flat
@@ -1340,8 +1429,9 @@ class DeviceEngine:
             cnt = jnp.where(is_pkt, (kindf >> 8).astype(jnp.int64), 0)
             src = hi32(fk)
             dstf = hi32(fm)
-            sv = host_vertex[jnp.clip(src, 0, H_pad - 1)]
-            dv = host_vertex[jnp.clip(dstf, 0, H_pad - 1)]
+            hv = topo["hv"]
+            sv = hv[jnp.clip(src, 0, H_pad - 1)]
+            dv = hv[jnp.clip(dstf, 0, H_pad - 1)]
             pair = jnp.where(is_pkt,
                              sv.astype(jnp.int64) * V + dv, V * V)
             spair, scnt = lax.sort((pair, cnt), num_keys=1)
@@ -1387,49 +1477,29 @@ class DeviceEngine:
             return state, _seg_take(perm, rows, starts, counts, IN), \
                 counts.astype(jnp.int32)
 
-        def _judge_outbox(state, ob, gid, host_vertex, wrld,
+        def _judge_outbox(state, ob, gid, topo, wrld,
                           win_end):
             """Per-phase network judgment of the raw outbox — the
             worker_sendPacket semantics (ref worker.c:520-579) hoisted
-            out of the pop loop: latency gather, per-packet drop rolls
+            out of the pop loop: path lookups, per-packet drop rolls
             under EXACTLY the keys the in-step path would use (src,
             per-source packet seq, send time), causality bump, and the
             sent/dropped counters. Runs once per phase over [H, OB]
             instead of once per pop iteration over [H, K]."""
-            lat, rel, sk1, sk2, ept = wrld
+            _, _, sk1, sk2, _ = wrld
             seed_pair = (sk1, sk2)
             ft, fm, fv = ob["t"], ob["m"], ob["v"]
             kindrow = lo32(fm)
             is_send = (ft < INF) & ((kindrow & 0xFF) == KIND_PACKET)
             cnt = jnp.where(is_send, kindrow >> 8, 0)        # [H,OB]
             dst = hi32(fm)
-            srcv = host_vertex[gid][:, None]
-            dstv = host_vertex[jnp.clip(dst, 0, H_pad - 1)]
-            if TAB_ONEHOT:
-                # gatherless table lookup: unrolled one-hot masked
-                # sums over the tiny [V,V] table (exact — a single
-                # nonzero term per row); the indexed gather costs
-                # ~ms-class on TPU for [H,OB] outputs
-                pairv = srcv * jnp.int32(V) + dstv           # [H,OB]
-                latf, relf = lat.reshape(-1), rel.reshape(-1)
-                latv = jnp.zeros(pairv.shape, jnp.int64)
-                relv = jnp.zeros(pairv.shape, rel.dtype)
-                for j in range(V * V):
-                    m = pairv == j
-                    latv = latv + jnp.where(
-                        m, latf[j].astype(jnp.int64), jnp.int64(0))
-                    relv = relv + jnp.where(
-                        m, relf[j], jnp.zeros((), rel.dtype))
-            else:
-                # epoch keyed on the row's depart time `ft` — equal to
-                # the send time in the hoisted (no-fluid-NIC) path, so
-                # the drop-roll reliability and the latency come from
-                # the same epoch the CPU twin reads. Empty rows
-                # (ft == INF) gather the last epoch harmlessly — they
-                # are masked by is_send everywhere downstream.
-                latv = _tbl(lat, ft, srcv, dstv,
-                            ept).astype(jnp.int64)
-                relv = _tbl(rel, ft, srcv, dstv, ept)
+            # epoch keyed on the row's depart time `ft` — equal to the
+            # send time in the hoisted (no-fluid-NIC) path, so the
+            # drop-roll reliability and the latency come from the same
+            # epoch the CPU twin reads. Empty rows (ft == INF) read the
+            # last epoch harmlessly — they are masked by is_send
+            # everywhere downstream.
+            latv, relv = _lookup(topo, wrld, gid, ft, dst)    # [H,OB]
 
             # per-row packet-seq base: state["packet_seq"] is already
             # the END of the phase; outbox columns sit in consumption
@@ -1940,16 +2010,16 @@ class DeviceEngine:
             with jax.named_scope("engine.merge"):
                 return _merge_rows(state, parts)
 
-        def _exchange(state, ob, gid, my_shard, host_vertex, wrld,
+        def _exchange(state, ob, gid, my_shard, topo, wrld,
                       win_end):
             with jax.named_scope("engine.flush"):
                 if HOIST:
                     with jax.named_scope("engine.judge"):
                         state, ob = _judge_outbox(state, ob, gid,
-                                                  host_vertex, wrld,
+                                                  topo, wrld,
                                                   win_end)
                 if CP:
-                    state = _count_paths(state, ob, host_vertex)
+                    state = _count_paths(state, ob, topo)
                 # occupancy: exchangeable outbox rows per host this phase
                 # (post-judge, the population outbox_compact must hold)
                 state["occ_ob"] = jnp.maximum(
@@ -2130,7 +2200,7 @@ class DeviceEngine:
         # win_end / stalled on an in-window insert), then flushes. The
         # window advances only when no host has events under the
         # barrier; the predicate is a collective, so all shards agree.
-        def _pop(state, ob, win_end, gid, host_vertex, wrld):
+        def _pop(state, ob, win_end, gid, topo, wrld):
             """One phase's pop loop: iterate _step until no host has a
             runnable event before win_end or the outbox is full (B
             iterations). Returns (state, ob, [1] iterations run)."""
@@ -2149,7 +2219,7 @@ class DeviceEngine:
 
                 state, ob, blk, _ = lax.while_loop(
                     cond,
-                    lambda c: _step(c, win_end, gid, host_vertex,
+                    lambda c: _step(c, win_end, gid, topo,
                                     wrld),
                     (state, ob, jnp.int32(0), dirty))
                 blk = jnp.reshape(blk, (1,))
@@ -2157,10 +2227,10 @@ class DeviceEngine:
                 state["occ_iters"] = state["occ_iters"] + blk
                 return state, ob, blk
 
-        def _round(state, win_end, gid, my_shard, host_vertex, wrld):
+        def _round(state, win_end, gid, my_shard, topo, wrld):
             def _phase(state):
                 state2, ob, _ = _pop(state, None, win_end, gid,
-                                     host_vertex, wrld)
+                                     topo, wrld)
                 # skip the whole exchange when nothing was sent and no
                 # slots were consumed (idle windows). The predicate is
                 # COLLECTIVE: the flush contains all_to_all, so every
@@ -2172,7 +2242,7 @@ class DeviceEngine:
                 return lax.cond(
                     go,
                     lambda s: _exchange(s, ob, gid, my_shard,
-                                        host_vertex, wrld,
+                                        topo, wrld,
                                         win_end),
                     lambda s: s,
                     state2)
@@ -2208,6 +2278,7 @@ class DeviceEngine:
             # yields the EXACT window sequence of an unsegmented run
             my_shard = lax.axis_index(AXIS)
             gid = (my_shard * H_loc + hidx).astype(jnp.int32)
+            topo = _topo(host_vertex, my_shard, wrld)
 
             def next_time(state):
                 # rows are sorted and slots < head are INF-free only
@@ -2222,8 +2293,8 @@ class DeviceEngine:
             def body(c):
                 state, nxt, rounds = c
                 win_end = jnp.minimum(nxt + LOOKAHEAD, final_stop)
-                state = _round(state, win_end, gid, my_shard,
-                               host_vertex, wrld)
+                state = _round(state, win_end, gid, my_shard, topo,
+                               wrld)
                 return state, next_time(state), rounds + 1
 
             state, _, rounds = lax.while_loop(
@@ -2236,7 +2307,7 @@ class DeviceEngine:
             my_shard = lax.axis_index(AXIS)
             gid = (my_shard * H_loc + hidx).astype(jnp.int32)
             state = _round(state, win_end, gid, my_shard,
-                           host_vertex, wrld)
+                           _topo(host_vertex, my_shard, wrld), wrld)
             nxt = _axis_min(
                 _take_head(state["ht"], state["head"], INF).min())
             return state, nxt
@@ -2249,13 +2320,15 @@ class DeviceEngine:
         def _pop_shard(state, ob, host_vertex, wrld, win_end):
             my_shard = lax.axis_index(AXIS)
             gid = (my_shard * H_loc + hidx).astype(jnp.int32)
-            return _pop(state, ob, win_end, gid, host_vertex, wrld)
+            return _pop(state, ob, win_end, gid,
+                        _topo(host_vertex, my_shard, wrld), wrld)
 
         def _flush_shard(state, ob, host_vertex, wrld, win_end):
             my_shard = lax.axis_index(AXIS)
             gid = (my_shard * H_loc + hidx).astype(jnp.int32)
-            return _exchange(state, ob, gid, my_shard, host_vertex,
-                             wrld, win_end)
+            return _exchange(state, ob, gid, my_shard,
+                             _topo(host_vertex, my_shard, wrld), wrld,
+                             win_end)
 
         spec_keys = ("ht", "hk", "hm", "hv", "hw", "head",
                      "event_seq", "packet_seq", "app_seq", "app",

@@ -91,7 +91,13 @@ def test_real_engine_programs_pass_clean():
     for label, eng in (
             ("base", _small_engine()),
             ("two_phase", _small_engine(exchange="two_phase")),
-            ("mb", _small_engine(model_bandwidth=True))):
+            ("mb", _small_engine(model_bandwidth=True)),
+            # the compare-select lookups' run table is derived from
+            # the traced host_vertex, never captured
+            ("table_onehot", _small_engine(
+                table_onehot=True,
+                host_vertex=np.array([0, 1, 1, 0, 0, 0, 1, 0],
+                                     np.int32)))):
         found = JA.audit_engine(eng, label, ok_targets=ok)
         assert found == [], [f.format() for f in found]
 

@@ -252,14 +252,15 @@ def test_merge_strategy_identical_traces_phold():
 
 def test_tpu_default_knobs_identical_traces():
     """The combination production TPU actually runs — judgment
-    hoisted to flush AND the global double-sort merge together
-    (_judge_outbox rewrites ob t/m/v, then _ob_rows re-reads them) —
-    pinned against the CPU-default step+window combination."""
+    hoisted to flush, the global double-sort merge, one-hot pop reads
+    and compare-select table lookups together (_judge_outbox rewrites
+    ob t/m/v, then _ob_rows re-reads them) — pinned against the
+    CPU-default combination."""
     outs = {}
     for extra in ("  judge_placement: step\n  merge_strategy: window\n"
-                  "  pop_strategy: gather",
+                  "  pop_strategy: gather\n  table_strategy: gather",
                   "  judge_placement: flush\n  merge_strategy: global\n"
-                  "  pop_strategy: onehot"):
+                  "  pop_strategy: onehot\n  table_strategy: onehot"):
         yaml = PHOLD_YAML.format(policy="tpu", seed=7, loss=0.1, q=8,
                                  msgload=3)
         yaml = yaml.replace("experimental:",
@@ -346,25 +347,233 @@ def test_merge_strategy_identical_traces_all_gather():
     assert outs["window"] == outs["global"]
 
 
-def test_table_strategy_identical_traces():
-    """One-hot topology-table lookups vs indexed gathers in the
-    hoisted judge (lossy, so relv feeds real drop rolls): selection
-    is exact, traces must bit-match."""
+def _groups_yaml(groups, app, stop="2s", seed=7, loss=0.1, V=3):
+    """A lossy V-vertex graph and one host group per (name, vertex,
+    quantity, processes line) entry, in order: host ids follow the
+    groups, so the groups lay out host_vertex's runs."""
+    nodes = "\n".join(
+        f'        node [ id {v} bandwidth_down "1 Gbit" '
+        f'bandwidth_up "1 Gbit" ]' for v in range(V))
+    edges = "\n".join(
+        f"        edge [ source {a} target {b} "
+        f'latency "{10 + 7 * a + 3 * b} ms" packet_loss {loss} ]'
+        for a in range(V) for b in range(a, V))
+    hosts = "".join(
+        f"  {name}:\n    quantity: {q}\n    network_node_id: {v}\n"
+        f"    processes:\n    - {proc}\n"
+        for name, v, q, proc in groups)
+    return (f"general: {{stop_time: {stop}, seed: {seed}}}\n"
+            "network:\n  graph:\n    type: gml\n    inline: |\n"
+            f"      graph [ directed 0\n{nodes}\n{edges}\n      ]\n"
+            f"experimental:\n  scheduler_policy: tpu\n{app}"
+            f"hosts:\n{hosts}")
+
+
+def _table_case(case):
+    """(yaml, host-vertex runs the one-hot program unrolls; 0 where
+    the lookups must fall back to the gathers)."""
+    if case == "phold_lossy":
+        return PHOLD_YAML.format(policy="tpu", seed=7, loss=0.1, q=8,
+                                 msgload=3), 2
+    if case == "tgen_groups":
+        # vertex 0 holds three runs that are not contiguous (the
+        # server, a client group, the padding); 9 hosts pad to 16
+        # over the 8-device mesh: runs 0 | 1 1 1 | 0 0 | 2 2 2 | 0 x7
+        client = ("{path: model:tgen_client, args: server=server "
+                  "size=60KiB count=2 pause=100ms retry=300ms, "
+                  "start_time: 100ms}")
+        groups = [("server", 0, 1,
+                   "{path: model:tgen_server, start_time: 10ms}"),
+                  ("ca", 1, 3, client), ("cb", 0, 2, client),
+                  ("cc", 2, 3, client)]
+        return _groups_yaml(groups, "  event_capacity: 192\n"
+                            "  outbox_capacity: 256\n",
+                            stop="1500ms", loss=0.05), 5
+    # more than 128 runs: 130 one-host groups alternate between two
+    # vertices (131 runs with the padding)
+    phold = "{path: model:phold, args: msgload=1, start_time: 100ms}"
+    groups = [(f"h{i:03d}", i % 2, 1, phold) for i in range(130)]
+    return _groups_yaml(groups, "  event_capacity: 32\n"
+                        "  outbox_capacity: 8\n", stop="600ms",
+                        V=2), 0
+
+
+@pytest.mark.parametrize("case", ["phold_lossy", "tgen_groups",
+                                  "many_runs"])
+def test_table_strategy_identical_traces(case):
+    """Compare-select topology lookups vs indexed gathers (lossy, so
+    the reliability feeds real drop rolls) over the 8-device mesh:
+    selection is exact, traces must bit-match. The one-hot program
+    unrolls host_vertex's runs; past 128 it keeps the gathers."""
+    yaml0, runs = _table_case(case)
     outs = {}
     for strategy in ("gather", "onehot"):
-        yaml = PHOLD_YAML.format(policy="tpu", seed=7, loss=0.1, q=8,
-                                 msgload=3)
-        yaml = yaml.replace(
+        yaml = yaml0.replace(
             "experimental:",
             "experimental:\n  judge_placement: flush\n"
             f"  table_strategy: {strategy}")
         c = Controller(load_config_str(yaml))
         stats = c.run()
         assert stats.ok, strategy
+        facts = c.runner.engine.program_facts
+        on = strategy == "onehot" and runs > 0
+        assert facts["table_onehot"] is on
+        assert facts["vertex_runs"] == (runs if on else 0)
+        assert c.runner.engine.effective["vertex_runs"] == \
+            facts["vertex_runs"]
         outs[strategy] = (stats.events_executed, stats.packets_sent,
                           stats.packets_dropped,
                           [h.trace_checksum for h in c.sim.hosts])
+    assert outs["gather"][1] > 0
     assert outs["gather"] == outs["onehot"]
+
+
+def _judge_gathers(hlo_text):
+    """Gather instructions of an optimized HLO text whose op_name lies
+    under engine.judge; a fused gather without metadata takes the
+    op_name of the instruction that calls its computation."""
+    import re
+
+    comp_of, op_of, callers, gathers = {}, {}, {}, []
+    comp = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head and " = " not in line:
+            comp = head.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ", line)
+        if not m:
+            continue
+        name = m.group(1)
+        comp_of[name] = comp
+        op = re.search(r'op_name="([^"]*)"', line)
+        op_of[name] = op.group(1) if op else None
+        for called in re.findall(
+                r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
+            callers.setdefault(called, name)
+        if re.search(r"\bgather\(", line):
+            gathers.append(name)
+
+    def op_name(name, depth=0):
+        if op_of.get(name) or depth > 32:
+            return op_of.get(name) or ""
+        caller = callers.get(comp_of.get(name))
+        return op_name(caller, depth + 1) if caller else ""
+
+    return [g for g in gathers if "engine.judge" in op_name(g)]
+
+
+@pytest.mark.parametrize("placement", ["flush", "step"])
+def test_table_onehot_judge_makes_no_gathers(placement):
+    """With table_strategy: onehot the dispatched round program's
+    judge (hoisted to the flush, or in the pop loop's step) holds no
+    gather; with the gathers it holds some."""
+    found = {}
+    for strategy in ("gather", "onehot"):
+        yaml = PHOLD_YAML.format(policy="tpu", seed=7, loss=0.1, q=8,
+                                 msgload=3)
+        yaml = yaml.replace(
+            "experimental:",
+            f"experimental:\n  judge_placement: {placement}\n"
+            f"  table_strategy: {strategy}")
+        c = Controller(load_config_str(yaml))
+        assert c.run().ok
+        eng = c.runner.engine
+        assert eng.program_facts["table_onehot"] is (strategy == "onehot")
+        assert eng.program_facts["vertex_runs"] == \
+            (2 if strategy == "onehot" else 0)
+        found[strategy] = _judge_gathers(eng.program_text("run"))
+    assert found["gather"]
+    assert found["onehot"] == []
+
+
+@pytest.mark.parametrize("case", ["legal", "cpu_auto",
+                                  "model_bandwidth", "fault_epochs",
+                                  "hierarchical", "wide_graph"])
+def test_table_onehot_legality(case):
+    """Outside the compare-select lookups' legality the engine builds
+    the gathers' program (table_onehot false, vertex_runs 0), and on
+    the CPU `auto` resolves to the gathers."""
+    from shadow_tpu.device.apps import PholdDevice
+    from shadow_tpu.device.engine import DeviceEngine, EngineConfig
+
+    H, V = 8, (12 if case == "wide_graph" else 2)
+    hv = (np.arange(H) % V).astype(np.int32)
+    lat = np.full((V, V), 1_000_000, np.int64)
+    rel = np.full((V, V), 0.9, np.float32)
+    kw = {}
+    if case == "fault_epochs":
+        lat, rel = np.stack([lat, lat]), np.stack([rel, rel])
+        kw["epoch_times"] = np.array([0, 5_000_000], np.int64)
+    if case == "hierarchical":
+        one = np.full(V, 1_000_000, np.int64)
+        lat = (np.zeros((1, 1), np.int64), np.zeros(V, np.int32), one,
+               one)
+        rel = (np.ones((1, 1), np.float32), np.zeros(V, np.int32),
+               np.full(V, 0.9, np.float32), np.ones(V, np.float32))
+    eng = DeviceEngine(
+        EngineConfig(n_hosts=H, event_capacity=8, outbox_capacity=8,
+                     lookahead=1_000_000, stop_time=10_000_000,
+                     model_bandwidth=case == "model_bandwidth",
+                     table_onehot=None if case == "cpu_auto" else True),
+        PholdDevice(n_hosts_total=H, msgload=2), hv, lat, rel, **kw)
+    on = case == "legal"
+    assert eng.program_facts["table_onehot"] is on
+    assert eng.program_facts["vertex_runs"] == (H if on else 0)
+    assert eng.effective["table_onehot"] is on
+
+
+def test_table_onehot_ensemble_replicas_match_single_worlds():
+    """The vmapped ensemble program reads each replica's own latency
+    and reliability tables through the run table: replicas with
+    different tables and seeds equal single-world runs of the gather
+    program, over the 8-device mesh with padded hosts."""
+    from shadow_tpu import simtime
+    from shadow_tpu.device.apps import PholdDevice
+    from shadow_tpu.device.engine import DeviceEngine, EngineConfig
+    from shadow_tpu.ensemble.spec import seed_key_np
+
+    H, seeds = 12, (3, 11)
+    hv = np.array([0, 0, 1, 1, 1, 0, 2, 2, 0, 1, 1, 2], np.int32)
+    lat = np.stack([np.array([[10, 20, 30], [20, 12, 25], [30, 25, 14]]),
+                    np.array([[11, 40, 22], [40, 9, 31], [22, 31, 16]])]
+                   ).astype(np.int32) * 1_000_000
+    rel = np.stack([np.full((3, 3), 0.9), np.full((3, 3), 0.8)]) \
+        .astype(np.float32)
+    rel[1, 0, 0] = 1.0
+
+    class Worlds:
+        R = 2
+        latency, reliability = lat, rel
+        epoch_times = np.zeros((2, 1), np.int64)
+        seed_k1 = np.array([seed_key_np(s)[0] for s in seeds], np.uint32)
+        seed_k2 = np.array([seed_key_np(s)[1] for s in seeds], np.uint32)
+
+    starts = [(h, simtime.from_millis(1 + h), -1) for h in range(H)]
+
+    def engine(seed, onehot, **kw):
+        return DeviceEngine(
+            EngineConfig(n_hosts=H, event_capacity=16,
+                         outbox_capacity=8, lookahead=9_000_000,
+                         stop_time=simtime.from_millis(300), seed=seed,
+                         exchange="all_to_all", table_onehot=onehot,
+                         judge_hoist=True),
+            PholdDevice(n_hosts_total=H, msgload=2, size=64),
+            host_vertex=hv, **kw)
+
+    ens = engine(seeds[0], True, latency_ns=lat[0], reliability=rel[0],
+                 ensemble=Worlds)
+    assert ens.program_facts["table_onehot"] is True
+    # seven runs of hosts, then the padding (vertex 0) up to 16
+    assert ens.program_facts["vertex_runs"] == 8
+    got, _ = ens.run_ensemble(ens.init_ensemble_state(starts))
+    for r, seed in enumerate(seeds):
+        one = engine(seed, False, latency_ns=lat[r], reliability=rel[r])
+        want, _ = one.run(one.init_state(starts))
+        for k in ("chk", "n_exec", "n_sent", "n_drop"):
+            assert (np.asarray(got[k])[r] == np.asarray(want[k])).all(), \
+                (r, k)
+        assert int(np.asarray(want["n_drop"]).sum()) > 0
 
 
 def test_outbox_compact_global_identical_traces():

@@ -3,8 +3,8 @@
 Nothing runs: the TPU compiler, installed here, compiles for chips that
 are described and not attached (the on-chip-measurement guide, section
 2). Building the mesh from the described devices makes the engine
-resolve its TPU defaults (judge_hoist, merge_global, pop_onehot), the
-branches the CPU tests never take by default. Two compiles, about a
+resolve its TPU defaults (judge_hoist, merge_global, pop_onehot,
+table_onehot), the branches the CPU tests never take by default. Two compiles, about a
 minute each here: ``run`` on one chip and on a 2x2 mesh (a third, of
 ``round_step``, would add a minute and cover nothing ``run`` does not
 contain).
@@ -75,7 +75,8 @@ def _engine(topo, n):
     assert engine.mesh.devices.flat[0].platform == "tpu"
     facts = engine.program_facts
     assert facts["judge_hoist"] and facts["merge_global"] \
-        and facts["pop_onehot"], facts
+        and facts["pop_onehot"] and facts["table_onehot"], facts
+    assert facts["vertex_runs"] > 1, facts
     return engine
 
 
