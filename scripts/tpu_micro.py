@@ -9,9 +9,13 @@ variant 1 (default) — round-step cost attribution at a real config's
   the hot flush primitives (flat sort, merge sort, judge threefry,
   segment gathers) standalone. Args: [config] [stop_s] [reps].
 variant 2 — multi-operand sorts vs gather recovery (the flush's
-  ~10 ms-per-gather takes vs 1.6-2.6 ms sorts): 6-operand flat sort,
-  5-operand merge sort, window takes, row-stacked gathers, the
-  filler-sort expand. Args: [reps].
+  ~10 ms-per-gather takes vs 1.6-2.6 ms sorts) at the 10k-rung
+  shapes: 6-operand flat sort, 5-operand merge sort, window takes,
+  row-stacked gathers, the filler-sort expand. Args: [reps] [tor];
+  "tor" times instead the window merge's payload recovery at
+  tor_56000's shapes ([56,000 x 160]: a (t, key, iota) row sort +
+  three take_along_axis against a row sort that carries the
+  payload), exiting 1 if the forms disagree on a live slot.
 variant 3 — the candidate gatherless flush (double-sort merge) timed
   end-to-end at the 10k-rung shapes + a numpy oracle check at a small
   shape. Args: [reps].
@@ -252,6 +256,81 @@ def variant1(args: list[str]) -> int:
 # ---------------------------------------------------------------------
 # variant 2: multi-operand sorts vs gather recovery
 # ---------------------------------------------------------------------
+def merge_payload_cases(H, E, IN, reps, rng):
+    """The window merge's payload recovery over [H, E + IN] rows
+    (engine.py `_exchange`, merge_payload): sort (t, key, iota) by
+    (t, key) and take the three payload columns with take_along_axis
+    (gather_ms; the sort alone: sort3_only_ms), against one sort that
+    carries them (carry_ms, the third column riding as a u32 operand,
+    as in the engine; carry_w64_ms keeps it i64). Live rows hold unique
+    (t, key) pairs, a quarter of the rows are padding (t = INF).
+    Returns the timings and whether the three forms agree on every
+    live slot."""
+    import numpy as np
+    from shadow_tpu._jax import jax, jnp
+    from jax import lax
+
+    W = E + IN
+    INF = np.int64(1) << 62
+    t = rng.integers(0, 1 << 40, (H, W)).astype(np.int64)
+    t[rng.random((H, W)) < 0.25] = INF
+    k = (rng.integers(0, 1 << 20, (H, W)).astype(np.int64) << 32) \
+        | np.arange(W, dtype=np.int64)[None, :]
+    k = np.where(t < INF, k, np.iinfo(np.int64).max)
+    ct, ck = (jax.device_put(jnp.asarray(a)) for a in (t, k))
+    cm, cv = (jax.device_put(jnp.asarray(
+        rng.integers(0, 1 << 62, (H, W)).astype(np.int64)))
+        for _ in range(2))
+    cw = jax.device_put(jnp.asarray(
+        rng.integers(0, 1 << 32, (H, W)).astype(np.int64)))
+
+    def gather(t, k, m, v, w):
+        ci = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None, :],
+                              (H, W))
+        st, sk, si = lax.sort((t, k, ci), dimension=1, num_keys=2)
+        sie = si[:, :E]
+        return (st[:, :E], sk[:, :E],
+                jnp.take_along_axis(m, sie, axis=1),
+                jnp.take_along_axis(v, sie, axis=1),
+                jnp.take_along_axis(w, sie, axis=1))
+
+    def sort_only(t, k):
+        ci = jnp.broadcast_to(jnp.arange(W, dtype=jnp.int32)[None, :],
+                              (H, W))
+        st, sk, si = lax.sort((t, k, ci), dimension=1, num_keys=2)
+        return st[:, :E], sk[:, :E], si[:, :E]
+
+    def carry(t, k, m, v, w):
+        st, sk, sm, sv, sw = lax.sort(
+            (t, k, m, v, w.astype(jnp.uint32)), dimension=1, num_keys=2)
+        return (st[:, :E], sk[:, :E], sm[:, :E], sv[:, :E],
+                sw[:, :E].astype(jnp.int64))
+
+    def carry64(t, k, m, v, w):
+        out = lax.sort((t, k, m, v, w), dimension=1, num_keys=2)
+        return tuple(o[:, :E] for o in out)
+
+    tag = f"[{H},{W}]"
+    fg, fs, fc, f64 = (jax.jit(f) for f in (gather, sort_only, carry,
+                                            carry64))
+    args = (ct, ck, cm, cv, cw)
+    res = {"shape": [H, W], "E": E,
+           "gather_ms": timed_ms(f"sort3 + 3 take_along {tag}",
+                                 lambda: fg(*args), reps),
+           "sort3_only_ms": timed_ms(f"sort3 alone {tag}",
+                                     lambda: fs(ct, ck), reps),
+           "carry_ms": timed_ms(f"carry sort5 (w u32) {tag}",
+                                lambda: fc(*args), reps),
+           "carry_w64_ms": timed_ms(f"carry sort5 (w i64) {tag}",
+                                    lambda: f64(*args), reps)}
+    outs = [[np.asarray(a) for a in f(*args)] for f in (fg, fc, f64)]
+    live = outs[0][0] < INF
+    res["live_equal"] = all(
+        np.array_equal(o[c][live], outs[0][c][live])
+        for o in outs[1:] for c in range(5))
+    return res
+
+
 def variant2(args: list[str]) -> int:
     reps = int(args[0]) if args else REPS
     H, OB = 10000, 36
@@ -266,6 +345,11 @@ def variant2(args: list[str]) -> int:
     res = {"variant": 2, "platform": jax.devices()[0].platform,
            "reps": reps}
     rng = np.random.default_rng(0)
+    if "tor" in args[1:]:
+        # tor_56000: 56,000 hosts, event_capacity 96, exchange_in 64
+        res["tor_merge"] = merge_payload_cases(56000, 96, 64, reps, rng)
+        print(json.dumps(res), flush=True)
+        return 0 if res["tor_merge"]["live_equal"] else 1
 
     def arr64(shape, hi=1 << 60):
         return jax.device_put(jnp.asarray(
@@ -992,7 +1076,7 @@ def main() -> int:
                          "6 compile/dispatch attribution")
     ap.add_argument("args", nargs="*",
                     help="variant args (v1/v5/v6: [config] [stop_s] "
-                         "[reps]; v2-4: [reps])")
+                         "[reps]; v2: [reps] [tor]; v3-4: [reps])")
     ns = ap.parse_args()
 
     signal.signal(signal.SIGALRM, lambda *a: sys.exit(9))

@@ -439,6 +439,11 @@ def engine_matrix() -> list[tuple[str, object]]:
         ("window_merge", _build_engine(merge_global=False,
                                        pop_onehot=False,
                                        judge_hoist=False)),
+        # tor_56000's TPU stack: the window merge carrying its payload
+        ("window_merge_sort", _build_engine(merge_global=False,
+                                            merge_payload="sort",
+                                            pop_onehot=True,
+                                            judge_hoist=True)),
         ("tpu_strategies", _build_engine(merge_global=True,
                                          pop_onehot=True,
                                          judge_hoist=True,

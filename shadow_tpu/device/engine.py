@@ -168,13 +168,29 @@ class EngineConfig:
     # lands every row at its [host, slot] heap position with zero
     # gathers — on TPU a 500k-element take costs ~10 ms while a
     # 6-operand 840k-row sort costs ~3 ms, so the window path's
-    # seg_take + take_along_axis recovery (5 + 3 takes per flush) IS
-    # the round cost there. False = the flat-sort + per-host window +
-    # row-merge path (fewer/narrower sorts; the right trade on one
-    # CPU core where sorts are the cost and takes are cheap).
+    # seg_take arrival gathers (5 takes per flush) are the round cost
+    # there. False = the flat-sort + per-host window + row-merge path
+    # (fewer/narrower sorts; the right trade on one CPU core where
+    # sorts are the cost and takes are cheap; on TPU the choice where
+    # the global sort is too long to compile, see merge_payload).
     # None = auto by platform. Traces are bit-identical either way
     # (tests pin both).
     merge_global: Optional[bool] = None
+    # the window merge's payload recovery (merge_global False): "sort"
+    # carries the heap payload (m, v and the u32 train mask w) through
+    # the per-host row sort and slices the first E columns — no
+    # gathers, the TPU side of the trade (on a v5e the three
+    # take_along_axis over [56,000 x 96] took 329 ms of a 757 ms
+    # round); "gather" sorts (t, key, column iota) and recovers the
+    # payload with take_along_axis (a narrower sort, the cheaper form
+    # the CPU backend: on an 8-core Xeon the carry sort over
+    # [56,000 x 160] takes 3.7 s against 2.6 s, and a 250-host Tor
+    # run takes 33% longer). None = by platform;
+    # no config option sets it, tests pin both. (t, key) is a total
+    # order over live rows, so every live heap slot is bit-identical
+    # either way; padding slots may hold other stale payload, which
+    # nothing reads.
+    merge_payload: Optional[str] = None
     # pop head reads: True = one-hot masked reductions (compare a
     # column iota against head, select, reduce over E) — pure
     # elementwise+reduce VPU work, no gather; the pop loop's
@@ -628,6 +644,14 @@ class DeviceEngine:
         MERGE_GLOBAL = (cfg.merge_global
                         if cfg.merge_global is not None
                         else platform == "tpu")
+        # the window merge's payload recovery (see
+        # EngineConfig.merge_payload)
+        if cfg.merge_payload not in (None, "sort", "gather"):
+            raise ValueError(
+                f"merge_payload must be sort or gather, not "
+                f"{cfg.merge_payload!r}")
+        MERGE_PAYLOAD = cfg.merge_payload or (
+            "sort" if platform == "tpu" else "gather")
         # gatherless pop head reads (see EngineConfig.pop_onehot)
         POP_ONEHOT = (cfg.pop_onehot
                       if cfg.pop_onehot is not None
@@ -1297,9 +1321,12 @@ class DeviceEngine:
         XF = ("t", "k", "m", "s", "v")
 
         # Sorts move every operand through every bitonic pass, so the
-        # flush sorts ONLY (key, iota) and recovers payload rows later
-        # with gathers — the profiler showed the old 6-operand flat
-        # sort + 5-operand merge dominating round cost (~85%).
+        # window path's flat sort carries ONLY (key, iota) and recovers
+        # arrival rows with gathers (_seg_take) — the profiler showed
+        # the old 6-operand flat sort dominating round cost on one CPU
+        # core. The per-host merge row sort is the other trade on TPU:
+        # there it carries the payload (merge_payload "sort"), since a
+        # gather costs several times a row sort's extra operands.
         CX = min(cfg.outbox_compact or OB, OB)
 
         # effective (post-auto-sizing) capacities, for the occupancy
@@ -1363,6 +1390,7 @@ class DeviceEngine:
             "count_paths": bool(CP),
             "judge_hoist": bool(HOIST),
             "merge_global": bool(MERGE_GLOBAL),
+            "merge_payload": MERGE_PAYLOAD,
             "pop_onehot": bool(POP_ONEHOT),
             "table_onehot": bool(TAB_ONEHOT),
             # the judge's run table is unrolled over the runs, so
@@ -1384,9 +1412,10 @@ class DeviceEngine:
                                   if self.ensemble is not None else 0),
         }
         log.info("engine strategies (%s): judge_hoist=%s "
-                 "merge_global=%s pop_onehot=%s table_onehot=%s "
-                 "vertex_runs=%d", platform, HOIST, MERGE_GLOBAL,
-                 POP_ONEHOT, TAB_ONEHOT, R_RUNS)
+                 "merge_global=%s merge_payload=%s pop_onehot=%s "
+                 "table_onehot=%s vertex_runs=%d", platform, HOIST,
+                 MERGE_GLOBAL, MERGE_PAYLOAD, POP_ONEHOT, TAB_ONEHOT,
+                 R_RUNS)
 
         def _flat_sorted(state, ob, gid):
             slot = jnp.arange(OB, dtype=jnp.int64)[None, :]
@@ -2110,9 +2139,10 @@ class DeviceEngine:
 
                 with jax.named_scope("engine.merge"):
                     # merge: one lexicographic row sort of [live heap | inc
-                    # (| self-shard inc)] by (time, src<<32|seq) — keys +
-                    # column iota only; payload columns follow via
-                    # take_along_axis
+                    # (| self-shard inc)] by (time, src<<32|seq); the
+                    # payload columns ride the sort (merge_payload
+                    # "sort") or follow a column iota via
+                    # take_along_axis ("gather")
                     def _inc_cols(b):
                         kindb = lo32(b["m"]) & 0xFF    # strip the train count
                         return (b["t"], b["k"],
@@ -2129,25 +2159,34 @@ class DeviceEngine:
                     WID = E + IN * len(blocks)
                     ct = jnp.concatenate([mt] + [b[0] for b in blocks], axis=1)
                     ck = jnp.concatenate([mk] + [b[1] for b in blocks], axis=1)
-                    ci = jnp.broadcast_to(
-                        jnp.arange(WID, dtype=jnp.int32)[None, :],
-                        (H_loc, WID))
-                    st, sk, si = lax.sort((ct, ck, ci), dimension=1,
-                                          num_keys=2)
-                    state["overflow"] = state["overflow"] + \
-                        (st[:, E:] < INF).sum(-1).astype(jnp.int32)
-                    sie = si[:, :E]
                     cm = jnp.concatenate([state["hm"]] + [b[2] for b in blocks],
                                          axis=1)
                     cv = jnp.concatenate([state["hv"]] + [b[3] for b in blocks],
                                          axis=1)
                     cw = jnp.concatenate([state["hw"]] + [b[4] for b in blocks],
                                          axis=1)
+                    if MERGE_PAYLOAD == "sort":
+                        # w is a 32-bit mask: one u32 word in the sort
+                        st, sk, sm, sv, sw = lax.sort(
+                            (ct, ck, cm, cv, cw.astype(jnp.uint32)),
+                            dimension=1, num_keys=2)
+                        state["hm"] = sm[:, :E]
+                        state["hv"] = sv[:, :E]
+                        state["hw"] = sw[:, :E].astype(jnp.int64)
+                    else:
+                        ci = jnp.broadcast_to(
+                            jnp.arange(WID, dtype=jnp.int32)[None, :],
+                            (H_loc, WID))
+                        st, sk, si = lax.sort((ct, ck, ci), dimension=1,
+                                              num_keys=2)
+                        sie = si[:, :E]
+                        state["hm"] = jnp.take_along_axis(cm, sie, axis=1)
+                        state["hv"] = jnp.take_along_axis(cv, sie, axis=1)
+                        state["hw"] = jnp.take_along_axis(cw, sie, axis=1)
+                    state["overflow"] = state["overflow"] + \
+                        (st[:, E:] < INF).sum(-1).astype(jnp.int32)
                     state["ht"] = st[:, :E]
                     state["hk"] = sk[:, :E]
-                    state["hm"] = jnp.take_along_axis(cm, sie, axis=1)
-                    state["hv"] = jnp.take_along_axis(cv, sie, axis=1)
-                    state["hw"] = jnp.take_along_axis(cw, sie, axis=1)
                     state["head"] = jnp.zeros_like(state["head"])
                     # occupancy: live heap rows after the merge — the rows
                     # event_capacity must hold

@@ -92,6 +92,9 @@ def test_real_engine_programs_pass_clean():
             ("base", _small_engine()),
             ("two_phase", _small_engine(exchange="two_phase")),
             ("mb", _small_engine(model_bandwidth=True)),
+            # the window merge carrying its payload (tor_56000 on TPU)
+            ("window_merge_sort", _small_engine(
+                merge_global=False, merge_payload="sort")),
             # the compare-select lookups' run table is derived from
             # the traced host_vertex, never captured
             ("table_onehot", _small_engine(

@@ -576,6 +576,119 @@ def test_table_onehot_ensemble_replicas_match_single_worlds():
         assert int(np.asarray(want["n_drop"]).sum()) > 0
 
 
+TOR_GROUPS = [
+    ("relay", 0, 8, "{path: model:tor_relay, start_time: 100ms}"),
+    ("client", 1, 16, "{path: model:tor_client, args: cells=48 "
+     "count=2 pause=500ms retry=2s, start_time: 1s}")]
+
+
+def _payload_case(case):
+    """(yaml, mesh shards) of a merge_payload parity case."""
+    if case == "phold_lossy":
+        # one shard: the merge row is [heap | arrivals], E + IN wide
+        return PHOLD_YAML.format(policy="tpu", seed=7, loss=0.1, q=8,
+                                 msgload=3).replace(
+            "experimental:", "experimental:\n  mesh_shards: 1"), 1
+    if case == "phold_all_to_all":
+        # the all_to_all self-shard bypass adds a second arrival
+        # block: the row is E + 2*IN wide
+        return PHOLD_YAML.format(policy="tpu", seed=3, loss=0.05, q=8,
+                                 msgload=3).replace(
+            "experimental:", "experimental:\n  exchange: all_to_all"), 8
+    if case == "tgen_burst":
+        yaml, _ = _table_case("tgen_groups")
+        return yaml, 8
+    # onion trains forwarded across hops, relay burst pops
+    return _groups_yaml(TOR_GROUPS, "  event_capacity: 96\n"
+                        "  outbox_capacity: 48\n", stop="4s",
+                        loss=0.05), 8
+
+
+def _payload_controller(monkeypatch, yaml, payload):
+    """A window-merge Controller whose engines pin
+    EngineConfig.merge_payload (the engine picks it by platform; no
+    config option sets it)."""
+    import functools
+
+    from shadow_tpu.device import runner
+    from shadow_tpu.device.engine import EngineConfig
+
+    monkeypatch.setattr(runner, "EngineConfig", functools.partial(
+        EngineConfig, merge_payload=payload))
+    return Controller(load_config_str(yaml.replace(
+        "experimental:", "experimental:\n  merge_strategy: window")))
+
+
+@pytest.mark.parametrize("case", ["phold_lossy", "phold_all_to_all",
+                                  "tgen_burst", "tor"])
+def test_merge_payload_identical_traces(monkeypatch, case):
+    """The window merge carrying its payload through the row sort vs
+    recovering it with take_along_axis: identical stats and traces,
+    on one shard and over the 8-device mesh."""
+    yaml, shards = _payload_case(case)
+    outs = {}
+    for payload in ("gather", "sort"):
+        c = _payload_controller(monkeypatch, yaml, payload)
+        stats = c.run()
+        assert stats.ok, payload
+        eng = c.runner.engine
+        assert eng.program_facts["merge_global"] is False
+        assert eng.program_facts["merge_payload"] == payload
+        assert eng.n_shards == shards
+        outs[payload] = (stats.events_executed, stats.packets_sent,
+                         stats.packets_dropped,
+                         [h.trace_checksum for h in c.sim.hosts])
+    assert outs["gather"][2] > 0
+    assert outs["gather"] == outs["sort"]
+
+
+@pytest.mark.parametrize("case", ["tor", "tgen_burst"])
+def test_merge_payload_live_heap_slots_equal(monkeypatch, case):
+    """Round by round on one shard, the two payload recoveries leave
+    every live heap slot (ht < INF) equal in all five columns, and the
+    same head cursors: on lossy Tor (train masks in w) and tgen (its
+    32-packet trains set w's top bit)."""
+    yaml = _payload_case(case)[0].replace(
+        "experimental:", "experimental:\n  mesh_shards: 1")
+    runs = []
+    for payload in ("gather", "sort"):
+        c = _payload_controller(monkeypatch, yaml, payload)
+        eng = c.runner.engine
+        assert eng.program_facts["merge_payload"] == payload
+        runs.append((eng, eng.init_state(c.sim.starts),
+                     eng.host_vertex_device(), eng.world()))
+    INF = 1 << 62
+    stop = runs[0][0].config.stop_time
+    trains = top_bit = padded = rounds = 0
+    while True:
+        ht, head = np.asarray(runs[0][1]["ht"]), \
+            np.asarray(runs[0][1]["head"])
+        nxt = int(np.take_along_axis(ht, head[:, None].clip(
+            max=ht.shape[1] - 1), 1).min())
+        if nxt >= stop:
+            break
+        win_end = np.int64(nxt + runs[0][0].config.lookahead)
+        sts = []
+        for i, (eng, st, hv, world) in enumerate(runs):
+            st, _ = eng._round_step(st, win_end, hv, world)
+            runs[i] = (eng, st, hv, world)
+            sts.append({k: np.asarray(st[k])
+                        for k in ("ht", "hk", "hm", "hv", "hw", "head")})
+        a, b = sts
+        live = a["ht"] < INF
+        assert (live == (b["ht"] < INF)).all()
+        for k in ("ht", "hk", "hm", "hv", "hw"):
+            assert (a[k][live] == b[k][live]).all(), (rounds, k)
+        assert (a["head"] == b["head"]).all()
+        trains += int((a["hw"][live] != 0).sum())
+        top_bit += int(((a["hw"][live] >> 31) & 1).sum())
+        padded += int((~live).sum())
+        rounds += 1
+    assert int(np.asarray(runs[1][1]["overflow"]).sum()) == 0
+    assert rounds > 20 and trains > 0 and padded > 0
+    assert top_bit > 0 or case == "tor"
+
+
 def test_outbox_compact_global_identical_traces():
     """Gatherless compaction on the GLOBAL merge path (lane sort +
     static slice): with a width that fits the real per-host fan-out,

@@ -3,11 +3,11 @@
 Nothing runs: the TPU compiler, installed here, compiles for chips that
 are described and not attached (the on-chip-measurement guide, section
 2). Building the mesh from the described devices makes the engine
-resolve its TPU defaults (judge_hoist, merge_global, pop_onehot,
-table_onehot), the branches the CPU tests never take by default. Two compiles, about a
-minute each here: ``run`` on one chip and on a 2x2 mesh (a third, of
-``round_step``, would add a minute and cover nothing ``run`` does not
-contain).
+resolve its TPU defaults (judge_hoist, merge_global, merge_payload,
+pop_onehot, table_onehot), the branches the CPU tests never take by
+default. Two compiles, about a minute each here: ``run`` on one chip
+and on a 2x2 mesh (a third, of ``round_step``, would add a minute and
+cover nothing ``run`` does not contain).
 
 Config: examples/tgen_1000.yaml with every host group cut tenfold to
 100 hosts: the 10,000-host deployment's graph (6 cities, loss on every
@@ -77,7 +77,63 @@ def _engine(topo, n):
     assert facts["judge_hoist"] and facts["merge_global"] \
         and facts["pop_onehot"] and facts["table_onehot"], facts
     assert facts["vertex_runs"] > 1, facts
+    # the window merge (pinned where the global sort is too long to
+    # compile) carries its payload through the row sort on the TPU
+    assert facts["merge_payload"] == "sort", facts
     return engine
+
+
+def test_cpu_defaults_keep_the_gathers():
+    """The same deployment on the CPU mesh resolves every strategy to
+    its CPU side, the window merge's take_along_axis included."""
+    from shadow_tpu.config import load_config
+    from shadow_tpu.core.controller import build
+    from shadow_tpu.device.runner import DeviceRunner
+
+    cfg = load_config(CONFIG, TENTH)
+    cfg.experimental.compile_cache = "off"
+    engine = DeviceRunner(build(cfg)).engine
+    assert engine.mesh.devices.flat[0].platform == "cpu"
+    facts = engine.program_facts
+    assert facts["merge_payload"] == "gather", facts
+    assert not (facts["judge_hoist"] or facts["merge_global"]
+                or facts["pop_onehot"] or facts["table_onehot"]), facts
+
+
+def test_window_merge_carries_its_payload_on_v5e(topo,
+                                                 no_persistent_cache):
+    """The window merge's TPU side, which the deployment above does
+    not take (it resolves to the global merge): a small PHOLD engine
+    with merge_global False compiles for one described chip, and its
+    row sort carrying the payload leaves fewer gathers in the program
+    than the take_along_axis recovery pinned on the same chip."""
+    from jax.sharding import Mesh
+
+    from shadow_tpu.device.apps import PholdDevice
+    from shadow_tpu.device.engine import AXIS, DeviceEngine, EngineConfig
+
+    H = 64
+    lat = np.full((2, 2), 1_000_000, np.int64)
+    rel = np.full((2, 2), 0.99, np.float32)
+    gathers = {}
+    for payload in (None, "gather"):
+        engine = DeviceEngine(
+            EngineConfig(n_hosts=H, lookahead=1_000_000,
+                         stop_time=10_000_000, event_capacity=16,
+                         outbox_capacity=8, merge_global=False,
+                         merge_payload=payload),
+            PholdDevice(n_hosts_total=H, msgload=2),
+            np.arange(H, dtype=np.int32) % 2, lat, rel,
+            mesh=Mesh(np.array(topo.devices[:1]), (AXIS,)))
+        facts = engine.program_facts
+        assert facts["merge_global"] is False, facts
+        assert facts["merge_payload"] == (payload or "sort"), facts
+        fn, args = engine.lowerable_programs()["run"]
+        compiled = fn.lower(*args).compile()
+        _fits(compiled)
+        gathers[facts["merge_payload"]] = \
+            compiled.as_text().count(" gather(")
+    assert gathers["sort"] < gathers["gather"], gathers
 
 
 def _fits(compiled):
