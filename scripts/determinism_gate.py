@@ -65,15 +65,6 @@ candidate at once, reshaping ones included) must both bit-match the
 default-knob run — a tuned plan changes wall time only, and the
 composition of individually-pinned knobs stays pinned.
 
-`--pipelined` switches to the PIPELINED-DISPATCH gate
-(device/supervise.py segment pipeline): pipeline_depth {1,2,4} —
-dispatch-segmented with the state-audit word compiled in — must be
-bit-identical to the serial oracle; a supervised child with a
-depth-4 window in flight is SIGTERM'd (the drain must complete the
-window and exit rc 75), and its checkpoint must resume at depth 1
-bit-identically (cross-depth resume: depth is host orchestration,
-never part of the checkpoint contract).
-
 `--chaos` switches to the ELASTIC MESH-SHRINK gate (device/chaos.py
 + failover: shrink): on a forced >= 4-device mesh, a scripted device
 loss (deterministic chaos injector) kills mesh device 1 at the 2nd
@@ -93,8 +84,8 @@ run must never OOM blind. `admission: strict` under a deliberately
 tiny `device_memory_budget` must refuse with the readable "needs X,
 budget Y on N devices" diagnostic before ANY compile; a scripted
 RESOURCE_EXHAUSTED (chaos oom) at the 0th program compile (cold AOT
-cache, so the compile really runs) and at the 2nd dispatch issue of
-a depth-4 pipelined run must each walk the degradation ladder —
+cache, so the compile really runs) and at the 2nd dispatch issue
+must each walk the degradation ladder —
 degrade >= 1, the retry budget NOT exhausted — and finish
 bit-identical to the serial oracle; and `--chaos-ensemble`'s
 campaign run in sequential replica batches
@@ -291,13 +282,11 @@ def run_ensemble_gate(config: str, policies: list[str],
 
 
 def _preempt_child(config: str, base: str, every_ns: int,
-                   data_dir: str, ensemble: bool, extra=None):
+                   data_dir: str, ensemble: bool):
     """Launch the supervised run as a child CLI process (the gate
     needs a real SIGTERM against a real process, not an in-process
     flag), SIGTERM it once the first rotating checkpoint exists, and
-    return its exit code. `extra` appends raw -o override pairs (the
-    pipelined gate preempts a child with a depth-4 window in
-    flight)."""
+    return its exit code."""
     import signal
     import subprocess
     import time
@@ -309,8 +298,6 @@ def _preempt_child(config: str, base: str, every_ns: int,
         "-o", "experimental.state_audit=true",
         "-o", f"general.data_directory={data_dir}",
     ]
-    for o in (extra or []):
-        overrides += ["-o", o]
     if not ensemble:
         overrides += ["-o", "experimental.scheduler_policy=tpu"]
     env = dict(os.environ)
@@ -1099,7 +1086,7 @@ def run_degrade_gate(config: str, ensemble_config: str) -> int:
        the finished run must bit-match the oracle with degrade >= 1
        and the retry budget unexhausted;
     4. dispatch-seam oom: the same scripted oom at the 2nd dispatch
-       issue of a depth-4 pipelined run — the FIRST failure charges
+       issue — the FIRST failure charges
        one normal retry, the second consecutive identical one routes
        to the ladder (deterministic OOMs must never exhaust
        dispatch_retries), and the run bit-matches the oracle;
@@ -1188,7 +1175,6 @@ def run_degrade_gate(config: str, ensemble_config: str) -> int:
 
         # leg 3: scripted oom at the 0th program compile (cold cache)
         def oom_compile(cfg):
-            cfg.experimental.pipeline_depth = 2
             cfg.experimental.dispatch_retries = 3
             cfg.experimental.dispatch_retry_backoff = 0.0
             cfg.experimental.compile_cache = os.path.join(
@@ -1213,9 +1199,8 @@ def run_degrade_gate(config: str, ensemble_config: str) -> int:
                   f"{stats_c.retries} retries — the ladder must "
                   "engage before the budget of 3 exhausts")
 
-        # leg 4: scripted oom at the 2nd dispatch issue, depth 4
+        # leg 4: scripted oom at the 2nd dispatch issue
         def oom_dispatch(cfg):
-            cfg.experimental.pipeline_depth = 4
             cfg.experimental.dispatch_retries = 3
             cfg.experimental.dispatch_retry_backoff = 0.0
             cfg.experimental.chaos = [
@@ -1333,121 +1318,6 @@ def run_degrade_gate(config: str, ensemble_config: str) -> int:
                   f"retry budget intact]; {ensemble_config} in "
                   "replica batches of 2 bit-matches the full-vmap "
                   "campaign and standalone replica 0)")
-        return rc
-
-
-def run_pipelined_gate(config: str) -> int:
-    """Pipelined-dispatch gate (device/supervise.py segment
-    pipeline): overlap must never change the simulation. Three legs
-    against one config:
-
-    1. depth sweep: the tpu policy at pipeline_depth {1, 2, 4} —
-       dispatch-segmented so real windows are in flight, with the
-       state-audit word compiled in — must be bit-identical to the
-       SERIAL ORACLE (not merely to each other);
-    2. recovery composition: a supervised child running with a
-       depth-4 window in flight is SIGTERM'd mid-run — the
-       preemption drain must complete the window, land a resume
-       checkpoint, and exit with the distinct preemption rc;
-    3. cross-depth resume: the checkpoint saved under depth 4 is
-       resumed at depth 1 (depth is host-side orchestration, never
-       part of the checkpoint contract) and the resumed run must
-       bit-match the uninterrupted oracle.
-    """
-    from shadow_tpu.config import load_config
-    from shadow_tpu.core.controller import Controller
-    from shadow_tpu.device.supervise import EXIT_PREEMPTED
-
-    cfg0 = load_config(config)
-    stop = cfg0.general.stop_time
-    seg_ns = max(1, stop // 8)
-
-    with tempfile.TemporaryDirectory() as tmp:
-        sig_oracle, stats_oracle = run_once(
-            config, "serial", os.path.join(tmp, "oracle",
-                                           "shadow.data"))
-
-        def run_depth(depth: int, tag: str, load: str = ""):
-            cfg = load_config(config)
-            cfg.experimental.scheduler_policy = "tpu"
-            cfg.experimental.pipeline_depth = depth
-            cfg.experimental.dispatch_segment = seg_ns
-            cfg.experimental.state_audit = True
-            if load:
-                cfg.experimental.checkpoint_load = load
-            cfg.general.data_directory = os.path.join(
-                tmp, tag, "shadow.data")
-            c = Controller(cfg)
-            stats = c.run()
-            if not stats.ok:
-                print(f"FAIL: {tag} run reported not-ok")
-                sys.exit(1)
-            sig = [(h.name, h.trace_checksum, h.events_executed,
-                    h.packets_sent, h.packets_dropped,
-                    h.packets_delivered) for h in c.sim.hosts]
-            return sig, stats
-
-        rc = 0
-        pipe_stats = {}
-        for depth in (1, 2, 4):
-            sig_d, stats_d = run_depth(depth, f"depth{depth}")
-            pipe_stats[depth] = stats_d.pipeline or {}
-            if sig_d != sig_oracle:
-                rc = 1
-                print(f"DETERMINISM FAILURE: pipeline_depth={depth} "
-                      "diverges from the serial oracle")
-                for a, b in zip(sig_oracle, sig_d):
-                    if a != b:
-                        print(f"  {a[0]}: oracle {a[1:]} != depth"
-                              f"{depth} {b[1:]}")
-            want_flight = min(depth, max(1, stop // seg_ns))
-            got_flight = pipe_stats[depth].get("max_in_flight", 0)
-            if got_flight < min(2, want_flight):
-                rc = 1
-                print(f"FAIL: pipeline_depth={depth} never held "
-                      f"{min(2, want_flight)} segments in flight "
-                      f"(max_in_flight={got_flight}) — the window "
-                      "is not actually pipelining")
-
-        # leg 2: SIGTERM with a depth-4 window in flight. The child
-        # gets a much finer boundary cadence (stop//64, vs the depth
-        # sweep's stop//8): at depth 4 the first rotation entry — the
-        # parent's SIGTERM trigger — lags issue progress by a full
-        # window, so with 8 coarse segments the signal would race the
-        # run's tail; 64 boundaries leave ~90% of the run as runway.
-        base = os.path.join(tmp, "ck.npz")
-        pre_ns = max(1, stop // 64)
-        child_rc = _preempt_child(
-            config, base, pre_ns,
-            os.path.join(tmp, "pre", "shadow.data"), False,
-            extra=["experimental.pipeline_depth=4",
-                   f"experimental.dispatch_segment={pre_ns}ns"])
-        if child_rc != EXIT_PREEMPTED:
-            print(f"FAIL: preempted depth-4 run exited rc {child_rc}"
-                  f", expected the preemption rc {EXIT_PREEMPTED}")
-            return 1
-
-        # leg 3: resume the depth-4 checkpoint at depth 1
-        sig_res, _ = run_depth(1, "resume", load=base)
-        if sig_res != sig_oracle:
-            rc = 1
-            print("DETERMINISM FAILURE: the depth-4 checkpoint "
-                  "resumed at depth 1 diverges from the "
-                  "uninterrupted oracle")
-            for a, b in zip(sig_oracle, sig_res):
-                if a != b:
-                    print(f"  {a[0]}: oracle {a[1:]} != resumed "
-                          f"{b[1:]}")
-
-        if rc == 0:
-            flights = {d: p.get("max_in_flight")
-                       for d, p in pipe_stats.items()}
-            print(f"pipelined OK: {config} (depths 1/2/4 "
-                  f"bit-identical to the serial oracle "
-                  f"[{stats_oracle.events_executed} events], "
-                  f"max_in_flight {flights}; SIGTERM with a depth-4 "
-                  f"window drained to rc {EXIT_PREEMPTED} and the "
-                  "checkpoint resumed at depth 1 bit-matches)")
         return rc
 
 
@@ -1733,13 +1603,6 @@ def main() -> int:
                          "record and a composed adversarial plan "
                          "must both bit-match the default-knob run "
                          "(a tuned plan changes wall time only)")
-    ap.add_argument("--pipelined", action="store_true",
-                    help="pipelined-dispatch gate: pipeline_depth "
-                         "1/2/4 (segmented, state-audited) must be "
-                         "bit-identical to the serial oracle; a "
-                         "SIGTERM with a depth-4 window in flight "
-                         "must drain to a resume checkpoint that a "
-                         "depth-1 run resumes bit-identically")
     ap.add_argument("--chaos", action="store_true",
                     help="elastic mesh-shrink gate: scripted 4 -> 3 "
                          "device loss (deterministic chaos injector) "
@@ -1801,8 +1664,8 @@ def main() -> int:
     if args.server:
         if args.ensemble or args.preempt or args.policy or \
                 args.compile_cache or args.telemetry or args.tuned \
-                or args.analyze_consistency or args.pipelined or \
-                args.chaos or args.degrade:
+                or args.analyze_consistency or args.chaos \
+                or args.degrade:
             # the server gate drives whole daemon processes; the
             # standalone reference runs are baked into its legs
             print("FAIL: --server does not combine with other gate "
@@ -1814,8 +1677,7 @@ def main() -> int:
     if args.degrade:
         if args.ensemble or args.preempt or args.policy or \
                 args.compile_cache or args.telemetry or args.tuned \
-                or args.analyze_consistency or args.pipelined or \
-                args.chaos:
+                or args.analyze_consistency or args.chaos:
             # the degrade gate runs the serial oracle, both oom
             # seams, the strict refusal, and its own replica-batch
             # ensemble leg by construction
@@ -1828,7 +1690,7 @@ def main() -> int:
     if args.chaos:
         if args.ensemble or args.preempt or args.policy or \
                 args.compile_cache or args.telemetry or args.tuned \
-                or args.analyze_consistency or args.pipelined:
+                or args.analyze_consistency:
             # the chaos gate runs the serial oracle, the M-shard
             # comparison, the shrink/resume legs, and its own
             # ensemble leg by construction
@@ -1837,18 +1699,6 @@ def main() -> int:
                   "its own checkpoint/ensemble legs)")
             return 1
         return run_chaos_gate(args.config, args.chaos_ensemble)
-
-    if args.pipelined:
-        if args.ensemble or args.preempt or args.policy or \
-                args.compile_cache or args.telemetry or args.tuned \
-                or args.analyze_consistency:
-            # the pipelined gate composes its own preemption leg and
-            # runs the serial oracle + depth sweep by construction
-            print("FAIL: --pipelined does not combine with other "
-                  "gate flags (it runs serial + tpu depths 1/2/4 "
-                  "plus its own preemption/resume legs)")
-            return 1
-        return run_pipelined_gate(args.config)
 
     if args.host_plane:
         if args.ensemble or args.preempt or args.policy or \

@@ -6,10 +6,8 @@ Reads a ``METRICS_*.json`` summary (or a ``TRACE_*.jsonl`` span log,
 aggregated on the fly) and prints the per-phase wall attribution —
 host / judge / dispatch / exchange / checkpoint / retry / compile /
 plan / reshard / chaos / failover — with span counts, flags the
-dominant phase, and names the lever it implicates. This is the
-concrete evidence the pipelining
-and auto-tuning work cite: e.g. a dispatch-dominant tgen_100 run is
-the per-round-dispatch-latency bottleneck MPMD overlap attacks.
+dominant phase, and names the lever it implicates — the evidence
+the auto-tuning work cites.
 
 ``--compare A B`` diffs two records phase-by-phase (delta walls +
 pkts/s) — the one-command before/after surface tuner trials and
@@ -38,13 +36,11 @@ from shadow_tpu.obs.trace import PHASES          # noqa: E402
 # dominant phase -> the lever it implicates (the ROADMAP's open
 # items), printed under the table so the report ends with an action
 LEVERS = {
-    "dispatch": "per-round dispatch latency dominates - the "
-                "pipelined/MPMD-overlap dispatch lever (ROADMAP)",
+    "dispatch": "per-round dispatch latency dominates - batch more "
+                "work per dispatch (dispatch_segment)",
     "dispatch.sync": "blocking waits for device results dominate - "
-                     "the run is device-bound; raise pipeline_depth "
-                     "so host-side boundary work overlaps device "
-                     "rounds (docs/operations.md#pipelining), or "
-                     "attack the round program itself",
+                     "the run is device-bound; attack the round "
+                     "program itself",
     "dispatch.issue": "host-side dispatch enqueue dominates - raise "
                       "dispatch_segment (fewer, longer segments) or "
                       "device_batch_rounds to batch more work per "
@@ -175,17 +171,11 @@ def print_report(m: dict, top: int = 0) -> None:
               "(docs/topology.md)")
     pipe = (m.get("counters") or {}).get("pipeline")
     if pipe:
-        # the pipelined-dispatch summary: how deep the window ran
-        # and how much host wall the in-flight segments overlapped
-        print(f"pipeline: depth {pipe.get('depth')}, "
-              f"{pipe.get('issued', '?')} issued / "
-              f"{pipe.get('drained', '?')} drained"
-              + (f" / {pipe['discarded']} discarded"
-                 if pipe.get("discarded") else "")
-              + f"; sync {pipe.get('sync_wall_s', 0.0):.3f}s, "
-              f"overlapped host {pipe.get('overlapped_host_s', 0.0):.3f}s "
-              f"-> overlap efficiency "
-              f"{pipe.get('overlap_efficiency', 0.0):.0%}")
+        # the advance loop's dispatch summary: how much of its wall
+        # the host spent blocked waiting for the device
+        print(f"dispatch: {pipe.get('segments', '?')} segment(s); "
+              f"sync {pipe.get('sync_wall_s', 0.0):.3f}s of "
+              f"{pipe.get('advance_wall_s', 0.0):.3f}s advance wall")
     reshards = (m.get("counters") or {}).get("reshards")
     if reshards or phases.get("reshard_s"):
         # the shrink's degradation cost as a first-class line: wall
@@ -272,10 +262,9 @@ def print_compare(a: dict, b: dict, name_a: str, name_b: str) -> None:
     pipe_b = (b.get("counters") or {}).get("pipeline")
     if pipe_a or pipe_b:
         def _pfmt(p):
-            return (f"depth {p.get('depth')} overlap "
-                    f"{p.get('overlap_efficiency', 0.0):.0%}"
+            return (f"{p.get('sync_wall_s', 0.0):.3f}s"
                     if p else "n/a")
-        print(f"pipeline: A {_pfmt(pipe_a)} -> B {_pfmt(pipe_b)}")
+        print(f"sync wall: A {_pfmt(pipe_a)} -> B {_pfmt(pipe_b)}")
     rsh_a = (a.get("counters") or {}).get("reshards", 0)
     rsh_b = (b.get("counters") or {}).get("reshards", 0)
     if rsh_a or rsh_b or pa.get("reshard_s") or pb.get("reshard_s"):

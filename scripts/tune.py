@@ -36,7 +36,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 # the tuner drives many short runs; the XLA machine-feature WARNING
-# spam would drown the trial log (bench.py's rule)
+# spam would drown the trial log
 os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
 
 

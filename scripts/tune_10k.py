@@ -12,7 +12,7 @@ steady state, so a chosen width must be validated on a full run
 before it is set in a config.
 
 When a measured occupancy record (artifacts/OCC_*.json, written by
-bench.py or any capacity_plan run — see device/capacity.py) exists
+any capacity_plan run — see device/capacity.py) exists
 for a workload with this host count, compact widths below the
 measured busiest-host outbox fill are PRUNED from the grid up front:
 they can only overflow loudly, so sweeping them burns chip time to
@@ -56,7 +56,7 @@ def prune_compacts(compacts: tuple, config: str, stop_ns: int) -> tuple:
     record a light-traffic variant; among matches the longest
     measured window wins. A record covering a PREFIX of the sweep
     slice (stop_time <= `stop_ns`) proves the width overflows in the
-    sweep itself; a longer record (e.g. bench.py's full-run headline)
+    sweep itself; a longer record (e.g. a full run)
     proves it overflows at the real rung even if the shorter slice
     survives it — either way the width is not worth chip time.
     Outbox fill per phase is a property of the event windows, which
@@ -138,11 +138,11 @@ def main() -> int:
         c = Controller(cfg)
         compile_s = 0.0
         try:
-            # warm the compile BEFORE timing (bench.py does the
-            # same): with the persistent compilation cache a
-            # previously-compiled combo would otherwise skip ~50 s
-            # of compile inside its timed window and win on that
-            # alone, crowning a combo by cache state, not runtime
+            # warm the compile BEFORE timing: with the persistent
+            # compilation cache a previously-compiled combo would
+            # otherwise skip ~50 s of compile inside its timed window
+            # and win on that alone, crowning a combo by cache state,
+            # not runtime
             t0 = time.perf_counter()
             st = c.runner.engine.init_state(c.sim.starts)
             c.runner.engine.run(

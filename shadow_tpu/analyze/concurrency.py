@@ -50,20 +50,10 @@ LOCK_REGISTRY = {
     "shadow_tpu/core/netmodel.py": {
         "self.path_packets": "self._lock",
     },
-    # the segment pipeline's in-flight ring (PipelineWindow): the
-    # advance loop's issue/drain halves share it today from one
-    # thread (the lock is uncontended), but it is exactly the
-    # structure a future async drain worker would contend on —
-    # every mutation goes through the lock now so that refactor
-    # inherits a linted discipline instead of retrofitting one
-    "shadow_tpu/device/supervise.py": {
-        "self._ring": "self._lock",
-    },
     # the chaos injector's schedule counters + dead-device set: the
-    # dispatch seam runs on the advance loop's thread, but the
-    # checkpoint and cache seams are exactly the calls a future
-    # async drain worker would issue — every mutation takes the
-    # lock now (the PipelineWindow rationale)
+    # injector is process-global (chaos.current()), so any thread
+    # that reaches one of its seams shares them — every mutation
+    # takes the lock
     "shadow_tpu/device/chaos.py": {
         "self._dead": "self._lock",
         "self._issues": "self._lock",

@@ -476,26 +476,8 @@ class ExperimentalOptions:
     # max simulated time per device dispatch (ns; 0 = unbounded):
     # long runs split into several invocations of the one compiled
     # program with identical traces (window clamping stays on the
-    # global stop). Bench full runs bound each dispatch to a few
-    # wall-seconds of work; the cost of a dispatch on the chip is not
-    # measured.
+    # global stop).
     dispatch_segment: int = 0
-    # pipelined segment dispatch (device/supervise.py): how many
-    # dispatch segments may be in flight on the device at once.
-    # 0/1 = the serial issue-then-sync loop (byte-identical
-    # behavior); N >= 2 = the issue half enqueues up to N segments
-    # back-to-back while the drain half performs the blocking syncs,
-    # validation, checkpoints, and heartbeats for the oldest — so
-    # host-side boundary work overlaps device execution. The
-    # compiled device program is untouched at ANY depth (pipelining
-    # is pure host-side orchestration) and traces are bit-identical
-    # to the serial loop (determinism_gate --pipelined pins depths
-    # 1/2/4 against the serial oracle). Each in-flight segment pins
-    # one state copy on device — memory scales with depth. Requires
-    # scheduler_policy: tpu; recovery (overflow re-plan, transient
-    # retry, audit, SIGTERM drain) discards the speculative window
-    # and replays from the last validated state.
-    pipeline_depth: int = 0
     # device-state checkpoint / resume (device/checkpoint.py; the
     # reference has no checkpoint at all — SURVEY §5). checkpoint_save
     # writes the full simulation state at checkpoint_save_time
@@ -530,7 +512,7 @@ class ExperimentalOptions:
     # else <checkout>/.cache/jax; shadow_tpu/_jax.py cache_root)
     # keyed by the full program fingerprint, so repeat processes
     # (supervised restarts, failover re-runs, ensemble campaigns, CI
-    # rungs, bench iterations) skip the XLA compile; "off" disables;
+    # rungs, benchmark iterations) skip the XLA compile; "off" disables;
     # any other value is the cache DIRECTORY path (it must look like
     # a path — contain a separator or start with ./ ~ / — so a
     # typo'd keyword fails at load, like capacity_plan). A cache hit
@@ -667,8 +649,8 @@ class ExperimentalOptions:
     # admission_verdict; docs/operations.md#admission): before any
     # compile, both runners estimate the per-device byte footprint
     # and compare it to the per-device budget. "auto" (default)
-    # admits, statically degrades (pipeline_depth shrink, ensemble
-    # replica batching), or admits loudly over budget — the runtime
+    # admits, statically degrades (ensemble replica batching), or
+    # admits loudly over budget — the runtime
     # degradation ladder is the backstop; "strict" refuses an
     # over-budget config with a readable diagnostic; "off" skips.
     admission: str = "auto"
@@ -875,17 +857,6 @@ class ExperimentalOptions:
             raise ValueError(
                 "experimental.dispatch_retries/failover supervise "
                 "DEVICE dispatches and require scheduler_policy: tpu")
-        if out.pipeline_depth >= 2 and out.scheduler_policy != "tpu":
-            raise ValueError(
-                "experimental.pipeline_depth >= 2 pipelines DEVICE "
-                "dispatch segments and requires scheduler_policy: "
-                "tpu (CPU policies have no asynchronous dispatch to "
-                "overlap)")
-        if out.pipeline_depth > 64:
-            raise ValueError(
-                "experimental.pipeline_depth must be <= 64 — every "
-                "in-flight segment pins a full device state copy, "
-                "and depths past the segment count buy nothing")
         if out.dispatch_retry_backoff < 0:
             raise ValueError(
                 "experimental.dispatch_retry_backoff must be >= 0")
@@ -896,7 +867,6 @@ class ExperimentalOptions:
                 "is sequential per event; judgment stays in-step)")
         for name, minimum in (("event_capacity", 2),
                               ("dispatch_segment", 0),
-                              ("pipeline_depth", 0),
                               ("checkpoint_save_time", 0),
                               ("checkpoint_every", 0),
                               ("checkpoint_keep", 1),

@@ -652,7 +652,7 @@ class Controller:
         exit path — success, failover, or a raised error — so a
         failed run still leaves its trace artifacts (the post-mortem
         is most valuable exactly then), and the summary lands on
-        SimStats.telemetry for bench/tooling."""
+        SimStats.telemetry for tooling."""
         stats = None
         try:
             stats = self._run_inner()
@@ -671,9 +671,8 @@ class Controller:
                     # counters, the wall rides the reshard phase
                     counters["reshards"] = stats.reshards
                 if stats.pipeline:
-                    # the METRICS record's overlap-efficiency line:
-                    # depth, issue/drain counts, sync wall, and the
-                    # host wall hidden behind in-flight device work
+                    # the METRICS record's dispatch block: segments,
+                    # sync wall and advance wall
                     counters["pipeline"] = dict(stats.pipeline)
             # a nested run (the hybrid failover rerun shares its
             # parent's tracer) must NOT finalize: the parent closes

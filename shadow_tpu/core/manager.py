@@ -105,26 +105,23 @@ class SimStats:
     # flight-recorder summary (shadow_tpu/obs): per-phase wall
     # attribution (host_s/judge_s/dispatch_s/exchange_s/checkpoint_s/
     # retry_s/...), span counts, and the paths of any TRACE_*/
-    # METRICS_* artifacts written. None with telemetry: off. bench.py
-    # stamps the phase walls into its records from here.
+    # METRICS_* artifacts written. None with telemetry: off.
     telemetry: Optional[dict] = None
     # strategy-plan provenance (shadow_tpu/tune/plan.py adopt()):
     # which PLAN record steered this run's execution knobs, the
     # knobs actually applied, and the ones skipped (hand-set or
     # inapplicable). None when experimental.strategy_plan resolved
-    # to nothing. bench.py stamps this into its records — plans
-    # change wall time only, so provenance is what keeps tuned and
-    # default records honestly comparable.
+    # to nothing. Plans change wall time only, so provenance is what
+    # keeps tuned and default runs honestly comparable.
     strategy_plan: Optional[dict] = None
-    # pipelined segment dispatch telemetry (device/supervise.py
-    # advance): depth, issued/drained/discarded segment counts, the
-    # wall blocked in dispatch.sync, the host wall overlapped with
-    # in-flight device work, and the overlap-efficiency share.
-    # None on CPU policies (no segment pipeline to report).
+    # segment dispatch telemetry (device/supervise.py advance): the
+    # segments synced, the wall blocked in dispatch.sync, and the
+    # advance loop's wall. None on CPU policies (no device dispatch
+    # to report).
     pipeline: Optional[dict] = None
     # OOM degradation-ladder rungs engaged (device/supervise.py): a
-    # deterministic RESOURCE_EXHAUSTED walked the ladder (pipeline
-    # depth / replica batching / dispatch segment) this many times —
+    # deterministic RESOURCE_EXHAUSTED walked the ladder (replica
+    # batching / dispatch segment) this many times —
     # each rung shrank the footprint and replayed bit-identically
     degrades: int = 0
     # preflight admission verdict (device/capacity.py

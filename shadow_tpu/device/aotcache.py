@@ -31,8 +31,7 @@ module is that cache for the simulation engine:
   past the cap;
 * hits/misses are **loud**: every ``ensure`` records an attribution
   event (lower/compile/serialize/load walls) that the runners surface
-  through ``SimStats.compile_cache`` and bench stamps into every
-  BENCH_*/MULTICHIP_* record.
+  through ``SimStats.compile_cache``.
 
 Concurrent-writer safety rides the artifacts helper: tmp files carry
 the writer's pid and land via ``os.replace``, so two processes racing
@@ -228,8 +227,8 @@ def code_digest() -> str:
 def backend_identity(devs) -> dict:
     """jax/jaxlib versions, platform, and device kinds for a device
     list — the ONE definition of "backend identity", shared by the
-    cache key (backend_signature) and bench's record stamps, so the
-    two surfaces cannot drift on what identifies a backend."""
+    cache key (backend_signature) and scripts/tune.py's plan records,
+    so the two surfaces cannot drift on what identifies a backend."""
     import jax
     import jaxlib
 
@@ -739,8 +738,8 @@ class AotCache:
                  rep["load_s"], rep["dir"])
 
     def report(self) -> dict:
-        """The run's loud hit/miss surface (SimStats.compile_cache /
-        bench records): per-program events plus the totals a record
+        """The run's loud hit/miss surface (SimStats.compile_cache):
+        per-program events plus the totals a record
         reader needs without walking the event list."""
         hits = sum(1 for e in self.events if e.get("hit"))
         misses = sum(1 for e in self.events

@@ -622,14 +622,14 @@ def load_record(path: str) -> dict:
 # preflight admission: footprint estimate vs per-device budget
 # ----------------------------------------------------------------------
 # The byte model counts exactly what the engine pins on device: the
-# sharded state pytree (state_structs), in-flight copies of it (the
-# segment pipeline keeps up to `depth` issued segments plus the last
-# validated snapshot alive), the replica axis R, the per-flush outbox
-# and exchange buffers at their effective capacities, and the
-# replicated world tables. XLA's transient workspace (sort scratch,
-# fusion temporaries) is deliberately NOT modeled — the estimate is a
-# floor on steady-state live bytes, and the honesty tests pin it to
-# measured live bytes within FOOTPRINT_TOLERANCE.
+# sharded state pytree (state_structs), two live copies of it (the
+# segment in flight plus the last validated snapshot), the replica
+# axis R, the per-flush outbox and exchange buffers at their
+# effective capacities, and the replicated world tables. XLA's
+# transient workspace (sort scratch, fusion temporaries) is
+# deliberately NOT modeled — the estimate is a floor on steady-state
+# live bytes, and the honesty tests pin it to measured live bytes
+# within FOOTPRINT_TOLERANCE.
 FOOTPRINT_TOLERANCE = 4.0
 
 
@@ -641,8 +641,7 @@ def _nbytes(struct) -> int:
     return n * np.dtype(struct.dtype).itemsize
 
 
-def footprint(engine, pipeline_depth: int = 0,
-              replicas: int = None) -> dict:
+def footprint(engine, replicas: int = None) -> dict:
     """Static per-device byte model of an engine's resident state —
     from the same resolved inputs program_facts reports, with zero
     device work (admission must run BEFORE any compile).
@@ -659,9 +658,9 @@ def footprint(engine, pipeline_depth: int = 0,
     structs = engine.state_structs()
     state_total = sum(_nbytes(v) for v in structs.values())
     state_dev = -(-state_total // S)
-    # the segment pipeline holds `depth` issued segment outputs plus
-    # the last validated snapshot (rewind source) concurrently
-    copies = max(1, int(pipeline_depth)) + 1
+    # the advance loop holds the segment in flight plus the last
+    # validated snapshot (rewind source) concurrently
+    copies = 2
     # per-flush scratch: the 5 int64 outbox field arrays plus the
     # exchange send+receive buffers at the effective capacities
     H_pad, OB = engine._ob_shape_global
@@ -702,7 +701,6 @@ def footprint(engine, pipeline_depth: int = 0,
         "world_bytes": int(world_total),
         "copies": int(copies),
         "replicas": int(R),
-        "pipeline_depth": int(pipeline_depth),
         "n_devices": int(S),
     }
 
@@ -746,30 +744,29 @@ def admission_diagnostic(est: dict, budget: int, source: str) -> str:
         f"{fmt_bytes(est['scratch_bytes'])}, world "
         f"{fmt_bytes(est['world_bytes'])} "
         f"({est.get('representation', 'dense')} tables); raise the "
-        "budget or lower pipeline_depth / ensemble.replicas / "
-        "capacities")
+        "budget or lower ensemble.replicas / capacities")
 
 
-def admission_verdict(engine, xp, pipeline_depth: int = 0,
-                      batchable: bool = False) -> dict:
+def admission_verdict(engine, xp, batchable: bool = False) -> dict:
     """The preflight admission gate, shared by both runners.
 
     * ``strict``  — refuse an over-budget estimate outright (raises
       ValueError with the readable diagnostic) before any compile.
     * ``auto``    — degrade statically along the same ladder the
-      runtime walks (shrink pipeline_depth, then split the ensemble
-      into replica batches); if the estimate still exceeds the
-      budget, admit LOUDLY — the runtime degradation ladder in
-      supervise.advance is the backstop for what the static model
-      cannot shed (dispatch_segment halving, failover).
+      runtime walks (split the ensemble into replica batches); if
+      the estimate still exceeds the budget, admit LOUDLY — the
+      runtime degradation ladder in supervise.advance is the
+      backstop for what the static model cannot shed
+      (dispatch_segment halving, failover).
     * ``off``     — skip entirely.
 
     Returns the verdict dict the runners stash on ``runner.admission``
-    (bench stamps it; supervise reads the imposed overrides)."""
+    (SimStats.admission carries it; the campaign reads the
+    replica_batch override)."""
     mode = str(getattr(xp, "admission", "auto"))
     ens = getattr(engine, "ensemble", None)
     R_full = int(ens.R) if ens is not None else 1
-    est = footprint(engine, pipeline_depth=pipeline_depth)
+    est = footprint(engine)
     budget, source = device_budget(engine, xp)
     out = {"mode": mode, "budget": int(budget),
            "budget_source": source, "estimate": est,
@@ -795,17 +792,11 @@ def admission_verdict(engine, xp, pipeline_depth: int = 0,
         raise ValueError(diag)
     # auto: statically walk the ladder's estimable rungs
     overrides = {}
-    depth = max(1, int(pipeline_depth))
-    while est["per_device"] > budget and depth > 1:
-        depth //= 2
-        overrides["pipeline_depth"] = depth
-        est = footprint(engine, pipeline_depth=depth)
     batch = R_full
     while est["per_device"] > budget and batchable and batch > 1:
         batch = (batch + 1) // 2
         overrides["replica_batch"] = batch
-        est = footprint(engine, pipeline_depth=depth,
-                        replicas=batch)
+        est = footprint(engine, replicas=batch)
     out["estimate"] = est
     out["overrides"] = overrides
     out["fits"] = est["per_device"] <= int(budget)

@@ -180,11 +180,10 @@ def events_from_config(raw: list) -> list[ChaosEvent]:
 class ChaosInjector:
     """Fires a validated schedule at the supervise/engine seams.
 
-    Every mutation of the shared counters/ledger holds ``_lock``:
-    the dispatch seam runs on the advance loop's thread, but the
-    checkpoint and cache seams are exactly the calls a future async
-    drain worker would issue — same rationale as PipelineWindow, and
-    the same LOCK_REGISTRY discipline."""
+    Every mutation of the shared counters/ledger holds ``_lock``
+    (registered in the concurrency lint's LOCK_REGISTRY): the
+    injector is process-global (:func:`current`), so any thread that
+    reaches one of its seams shares these counters."""
 
     def __init__(self, events: list[ChaosEvent]):
         self._lock = threading.Lock()

@@ -280,7 +280,7 @@ class DeviceRunner:
         # them. Adoption changes wall time only — every plan-space
         # knob is bit-identity-pinned — and a fingerprint mismatch
         # refuses loudly inside adopt(). The provenance rides
-        # SimStats.strategy_plan so bench can stamp it.
+        # SimStats.strategy_plan.
         from shadow_tpu.tune import plan as planmod
         self.strategy_plan = planmod.adopt(
             cfg, self.app, len(sim.hosts),
@@ -354,8 +354,7 @@ class DeviceRunner:
         # walks the ladder; the heartbeat and SimStats report it)
         self.degrades = 0
         # preflight admission verdict (capacity.admission_verdict),
-        # set per run(); the advance loop honors its overrides and
-        # SimStats/bench stamp it
+        # set per run(); SimStats.admission carries it
         self.admission = None
         # flight recorder (shadow_tpu/obs): the Controller attaches
         # its run-wide tracer; None (direct construction in tests)
@@ -368,7 +367,7 @@ class DeviceRunner:
         self._ck_extra_meta: Optional[dict] = None
         # set once _plan_capacities has sized the engine: run() skips
         # re-planning, so a caller may plan ahead of its timed window
-        # (bench.py) and a re-used runner keeps its plan
+        # and a re-used runner keeps its plan
         self._planned = False
 
     def _build_engine(self, ensemble=None,
@@ -880,16 +879,14 @@ class DeviceRunner:
             # padded width instead of a loud layout mismatch
             self._adopt_checkpoint_geometry(load_path)
         # preflight admission (capacity.py): the modeled footprint —
-        # state copies x pipeline depth, exchange scratch, world
-        # tables — against the per-device budget, BEFORE any compile
-        # (the first compile happens lazily at the first dispatch,
-        # which the capacity warm-up below would trigger). strict
-        # refuses over-budget with a readable diagnostic; auto may
-        # statically lower the pipeline depth, and the runtime
-        # degradation ladder backstops what the model cannot see.
-        self.admission = capacity.admission_verdict(
-            self.engine, xp,
-            pipeline_depth=getattr(xp, "pipeline_depth", 0))
+        # two state copies, exchange scratch, world tables — against
+        # the per-device budget, BEFORE any compile (the first
+        # compile happens lazily at the first dispatch, which the
+        # capacity warm-up below would trigger). strict refuses
+        # over-budget with a readable diagnostic; auto admits loudly,
+        # and the runtime degradation ladder backstops what the
+        # model cannot see.
+        self.admission = capacity.admission_verdict(self.engine, xp)
         if xp.capacity_plan != "static" and not self._planned:
             with tracer.span("capacity.plan", "plan",
                              mode=xp.capacity_plan):
@@ -1065,10 +1062,9 @@ class DeviceRunner:
         stats.resume_path = adv.resume_path
         if self.hb_monitor is not None:
             stats.stale_heartbeats = self.hb_monitor.stale_events
-        # segment-pipeline telemetry (supervise.advance): depth,
-        # issue/drain counts, sync wall, and the overlap the depth
-        # bought — bench stamps it and trace_report prints the
-        # overlap-efficiency line from it
+        # dispatch telemetry (supervise.advance): segments, sync
+        # wall, advance wall — METRICS carries it and trace_report
+        # prints the sync-wall line from it
         stats.pipeline = adv.pipeline or None
         stats.events_executed = n_exec_total
         stats.packets_sent = int(final["n_sent"][:H].sum())

@@ -50,16 +50,15 @@ ensemble campaigns need the same guarantees:
    exhaustion (the same RESOURCE_EXHAUSTED twice in a row at the
    same validated boundary, or one that survives the retry budget)
    is a capacity fact, not a transient: each recurrence walks one
-   rung that actually shrinks the footprint — halve the pipeline
-   window depth, split the ensemble into sequential replica batches
-   (:class:`DegradeToReplicaBatch`, caught by the campaign), halve
-   the dispatch segment — and replays bit-identically from the last
-   validated state without charging ``dispatch_retries``. Out of
-   rungs, the existing ``failover:`` escalation applies. The ladder
-   is the runtime backstop of the preflight admission gate
-   (capacity.footprint / ``experimental.admission``) and is drilled
-   by the chaos injector's ``oom`` seam (determinism_gate
-   --degrade).
+   rung that actually shrinks the footprint — split the ensemble
+   into sequential replica batches (:class:`DegradeToReplicaBatch`,
+   caught by the campaign), halve the dispatch segment — and replays
+   bit-identically from the last validated state without charging
+   ``dispatch_retries``. Out of rungs, the existing ``failover:``
+   escalation applies. The ladder is the runtime backstop of the
+   preflight admission gate (capacity.footprint /
+   ``experimental.admission``) and is drilled by the chaos injector's
+   ``oom`` seam (determinism_gate --degrade).
 
 :func:`advance` is the single segmented-advance loop both
 ``DeviceRunner`` and ``EnsembleRunner`` now share: it generalizes the
@@ -67,24 +66,16 @@ overflow re-plan/retry loop PR 1 built into one recovery path for all
 failure classes (capacity overflow, transient dispatch errors, audit
 violations, preemption).
 
-Since PR 11 the loop is an event-driven segment *pipeline*
-(``experimental.pipeline_depth``): an ISSUE half enqueues up to N
-dispatch segments back-to-back (jax dispatch is asynchronous — each
-``run`` call returns device futures in ~ms), and a strictly-ordered
-DRAIN half performs the blocking ``overflow``/``seg_rounds`` syncs,
-audit validation, known-good snapshotting, checkpoint rotation, and
-heartbeat emission for the oldest in-flight segment — so host-side
-work for boundary *k* overlaps device execution of segments k+1..k+N.
-Depth 0/1 reproduces today's serial issue-then-drain ordering exactly
-(and the compiled device program is untouched by the knob at ANY
-depth — pipelining is pure host-side orchestration). Every recovery
-class survives pipelining by the same rule: the speculative in-flight
-window is discarded and the loop replays serially from the last
-validated state, which is bit-identical by the determinism contract
-(recomputing a deterministic segment yields the same trace). A
-preemption drain completes the in-flight window before saving the
-boundary checkpoint, so issued device work is never thrown away on a
-SIGTERM.
+The loop is serial: each segment is issued (``dispatch.issue`` — jax
+dispatch is asynchronous, so ``run`` returns device futures in ~ms),
+then synchronized (``dispatch.sync`` — the blocking
+``overflow``/``seg_rounds`` fetches, where asynchronously raised
+dispatch errors surface), then its boundary work runs: overflow
+re-plan, the round budget, audit validation, heartbeat, checkpoint
+rotation and the known-good snapshot. At most one segment is in
+flight, so every recovery class replays from the last validated
+state, which is bit-identical by the determinism contract
+(recomputing a deterministic segment yields the same trace).
 """
 
 from __future__ import annotations
@@ -94,7 +85,6 @@ import os
 import signal
 import threading
 import time
-from collections import deque
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -667,61 +657,6 @@ class Checkpointer:
 
 
 @dataclass
-class _InFlight:
-    """One issued-but-undrained dispatch segment: its sim window and
-    the asynchronous device arrays the dispatch returned. The state
-    pytree pins device buffers until the drain validates (or the
-    recovery discards) it — at depth N up to N segment states are
-    alive at once, which is the pipeline's memory cost."""
-
-    t0: int
-    t1: int
-    state: object
-    rounds: object
-
-
-class PipelineWindow:
-    """Bounded ring of in-flight dispatch segments (FIFO: the drain
-    consumes strictly in issue order — validation, checkpoints, and
-    heartbeats are order-dependent side effects). Mutations are
-    lock-protected and the ring is registered in the concurrency
-    lint's LOCK_REGISTRY (shadow_tpu/analyze/concurrency.py). Today
-    the advance loop is single-threaded and the lock is never
-    contended — it exists because the ring is exactly the structure
-    a future async drain worker would share, and taking the lock
-    from day one means that refactor inherits a mechanically-linted
-    discipline instead of having to retrofit one."""
-
-    def __init__(self, depth: int):
-        self.depth = max(1, int(depth))
-        self._lock = threading.Lock()
-        self._ring: deque = deque()
-
-    def __len__(self) -> int:
-        return len(self._ring)
-
-    @property
-    def full(self) -> bool:
-        return len(self._ring) >= self.depth
-
-    def push(self, fl: _InFlight) -> None:
-        with self._lock:
-            self._ring.append(fl)
-
-    def pop(self) -> _InFlight:
-        with self._lock:
-            return self._ring.popleft()
-
-    def discard(self) -> int:
-        """Drop every speculative in-flight segment (recovery path);
-        returns how many were discarded so the caller can log it."""
-        with self._lock:
-            n = len(self._ring)
-            self._ring.clear()
-        return n
-
-
-@dataclass
 class AdvanceResult:
     """What supervise.advance hands back to the runner, beyond the
     final state: the (per-replica) round counts and every way the
@@ -740,14 +675,12 @@ class AdvanceResult:
     # surviving devices
     reshards: int = 0
     # OOM degradation-ladder rungs engaged (deterministic
-    # RESOURCE_EXHAUSTED): each one shrank the footprint (pipeline
-    # depth, replica batching, or dispatch segment) and replayed
-    # bit-identically from the last validated state
+    # RESOURCE_EXHAUSTED): each one shrank the footprint (replica
+    # batching or dispatch segment) and replayed bit-identically from
+    # the last validated state
     degrades: int = 0
-    # pipeline telemetry (always populated): depth, issued/drained
-    # segment counts, discarded speculative segments, the wall spent
-    # blocked in dispatch.sync, and the host wall that ran with >= 1
-    # segment in flight (the overlap the depth bought)
+    # dispatch telemetry (always populated): the segments synced, the
+    # wall spent blocked in dispatch.sync, and the advance loop's wall
     pipeline: dict = field(default_factory=dict)
 
 
@@ -766,22 +699,14 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
       or re-raise;
     * audit violation    -> AuditFailure (fatal: never checkpoint or
       run forward a corrupted state);
-    * preemption request -> complete the in-flight window, save a
-      resume checkpoint at the last drained boundary, and return
-      preempted.
+    * preemption request -> save a resume checkpoint at the last
+      validated boundary, and return preempted.
 
-    The loop is split into ISSUE and DRAIN halves around a bounded
-    :class:`PipelineWindow` (``experimental.pipeline_depth``; 0/1 =
-    serial issue-then-drain, N >= 2 = up to N segments in flight).
-    The issue half enqueues segment k+1 immediately after segment
-    k's dispatch returns its asynchronous device arrays; the drain
-    half performs the blocking syncs and every order-dependent side
-    effect (validation, known-good snapshot, checkpoint rotation,
-    heartbeats) strictly in issue order. Segment boundaries are a
-    pure function of sim time, so the issue half computes the exact
-    boundary sequence the serial loop would — traces are
-    bit-identical at every depth, and a recovery discards the
-    speculative window and replays from the last validated state.
+    Each segment is issued, synchronized and validated, and its
+    boundary work done, before the next one is issued. Segment
+    boundaries are a pure function of sim time, so a recovery that
+    replays from the last validated state cuts the same segments and
+    recomputes the same trace.
 
     Every unit of work records a flight-recorder span (shadow_tpu/obs
     — ``dispatch.issue`` enqueues and ``dispatch.sync`` blocking
@@ -848,47 +773,26 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
             audit_meta={"enabled": audit_on, "violations": 0})
         return path
 
-    depth = max(1, int(getattr(xp, "pipeline_depth", 0) or 0))
-    adm = getattr(runner, "admission", None)
-    if isinstance(adm, dict):
-        # the preflight admission gate (capacity.admission_verdict)
-        # may have statically degraded the pipeline depth to fit the
-        # per-device budget — honor it before the first issue
-        ov_depth = (adm.get("overrides") or {}).get("pipeline_depth")
-        if ov_depth:
-            depth = max(1, min(depth, int(ov_depth)))
     chaos_inj = getattr(runner, "chaos", None)
     res = AdvanceResult()
-    window = PipelineWindow(depth)
     good_state, good_t = (state if keep_good else None), t_start
     failures = 0
     oom_streak = 0              # consecutive memory-exhaustion errors
     # at the current validated boundary; 2 = deterministic, walk the
     # degradation ladder instead of the retry budget
     seg_halvings = 0
-    t = t_start                 # drained/validated sim time
-    t_issue = t_start           # where the next issued segment starts
-    cur_state = state           # issue-side head (newest issued state)
-    pending_error = None        # an issue-time dispatch error, held
-    # until the segments issued before it drain — exactly when the
-    # serial loop would have observed it
+    t = t_start                 # validated sim time
     next_hb = (t // hb + 1) * hb if hb else None
     next_ck = ck.next_after(t) if ck is not None else None
-    pstats = {"depth": depth, "issued": 0, "drained": 0,
-              "discarded": 0, "max_in_flight": 0,
-              "sync_wall_s": 0.0, "overlapped_host_s": 0.0}
+    pstats = {"segments": 0, "sync_wall_s": 0.0}
     res.pipeline = pstats
     adv_wall0 = time.perf_counter()
-    last_sync_end = None
 
     def next_boundary(ti):
-        """The segment boundary the serial loop would cut at sim
-        time `ti` — a pure function of the heartbeat cadence, the
-        dispatch segment, and the checkpoint cadence, so the issue
-        half can compute the boundary sequence ahead of the drain
-        and the two halves can never disagree on where segments
-        end (the drain's stateful next_hb/next_ck bookkeeping below
-        walks the same sequence)."""
+        """The segment boundary cut at sim time `ti` — a pure
+        function of the heartbeat cadence, the dispatch segment, and
+        the checkpoint cadence, so a replay from any validated
+        boundary cuts the same segments as the first pass."""
         nxt = pause
         if hb:
             nxt = min(nxt, (ti // hb + 1) * hb)
@@ -900,39 +804,30 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
 
     def rewind_to_good(new_state, new_t=None):
         """Recovery epilogue shared by every replay path: install
-        the re-placed state as both the validated snapshot and the
-        issue head, and rewind both clocks to the last validated
-        boundary. The replay then proceeds through the normal
-        issue/drain loop — deterministic segments recompute
-        bit-identically, so a replayed prefix never changes the
-        trace. ``new_t`` overrides the boundary the state pins (the
-        shrink failover may fall back to an on-disk checkpoint older
-        than the in-memory snapshot)."""
-        nonlocal cur_state, t, t_issue, next_hb, next_ck
-        nonlocal good_state, good_t, pending_error, last_sync_end
+        the re-placed state as the validated snapshot and rewind the
+        clock to the last validated boundary. The replay then
+        proceeds through the normal loop — deterministic segments
+        recompute bit-identically, so a replayed prefix never changes
+        the trace. ``new_t`` overrides the boundary the state pins
+        (the shrink failover may fall back to an on-disk checkpoint
+        older than the in-memory snapshot)."""
+        nonlocal t, next_hb, next_ck, good_state, good_t
         if new_t is not None:
             good_t = int(new_t)
-        cur_state = new_state
         good_state = new_state
-        t = t_issue = good_t
+        t = good_t
         next_hb = (t // hb + 1) * hb if hb else None
         next_ck = ck.next_after(t) if ck is not None else None
-        pending_error = None
-        # the wall since the last sync was recovery work (backoff,
-        # state re-placement, engine rebuild) spent with a DISCARDED
-        # window — the device was idle, so it must not be credited
-        # as overlapped host time
-        last_sync_end = None
         return new_state
 
     def recover_transient(e):
-        """Transient dispatch error (issue- or drain-side): discard
-        the speculative in-flight window, count a CONSECUTIVE
-        failure, back off, and replay from the last validated
-        state. `failures` resets on every drained-and-validated
-        segment: unrelated transient incidents hours apart must not
-        pool into one exhausted budget — a genuinely dead device
-        still exhausts it, because no segment ever drains clean."""
+        """Transient dispatch error (issue- or sync-side): count a
+        CONSECUTIVE failure, back off, and replay from the last
+        validated state. `failures` resets on every synced-and-
+        validated segment: unrelated transient incidents hours apart
+        must not pool into one exhausted budget — a genuinely dead
+        device still exhausts it, because no segment ever syncs
+        clean."""
         nonlocal failures, oom_streak
         if not is_transient(e) or good_state is None:
             raise e
@@ -946,8 +841,6 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
         oom_streak = oom_streak + 1 if is_oom(e) else 0
         if oom_streak >= 2:
             return recover_oom(e)
-        discarded = window.discard()
-        pstats["discarded"] += discarded
         failures += 1
         res.retries += 1
         # live cumulative count: the supervise heartbeat line
@@ -978,9 +871,8 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
             BACKOFF_CAP_S)
         log.warning(
             "transient %sdevice dispatch error past t=%d ns (%s); "
-            "discarding %d speculative in-flight segment(s), retry "
-            "%d/%d from the last validated state t=%d ns after "
-            "%.1fs backoff", label, good_t, e, discarded, failures,
+            "retry %d/%d from the last validated state t=%d ns after "
+            "%.1fs backoff", label, good_t, e, failures,
             xp.dispatch_retries, good_t, delay)
         if delay:
             with tracer.span("retry.backoff", "retry",
@@ -1002,22 +894,19 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
         the last validated state, and leaves the retry budget
         untouched. Rungs, in order:
 
-        1. halve the pipeline window depth — each unit of depth pins
-           a full extra segment state on-device;
-        2. ensembles only: raise :class:`DegradeToReplicaBatch` —
+        1. ensembles only: raise :class:`DegradeToReplicaBatch` —
            the campaign re-runs the sweep in sequential replica
            batches (bit-identical to the full vmap);
-        3. halve the dispatch segment — shorter segments bound the
+        2. halve the dispatch segment — shorter segments bound the
            transient exchange/working-set peak (segmentation never
            changes traces: the engine clamps on the global stop);
-        4. out of rungs: the existing ``failover:`` escalation.
+        3. out of rungs: the existing ``failover:`` escalation.
 
         Every rung logs a ``degrade`` span, notifies the chaos
         injector (so a scripted repeating OOM stops firing exactly
         when a real one would — the footprint shrank), and logs the
         re-admission estimate against the budget."""
         nonlocal seg, seg_halvings, oom_streak
-        pstats["discarded"] += window.discard()
         # a recurrence AFTER a rung is a fresh deterministic-OOM
         # incident at streak 2 immediately — walking the next rung
         # must not charge the retry budget either
@@ -1025,13 +914,7 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
         res.degrades += 1
         runner.degrades = res.degrades
         rung, span_kw = "", {}
-        if window.depth > 1:
-            new_depth = max(1, window.depth // 2)
-            rung = f"pipeline_depth {window.depth}->{new_depth}"
-            span_kw = {"depth": new_depth}
-            window.depth = new_depth
-            pstats["depth"] = new_depth
-        elif ensemble and \
+        if ensemble and \
                 int(getattr(runner, "_replica_batchable", 0) or 0):
             batch = int(runner._replica_batchable)
             rung = f"replica_batch {batch}"
@@ -1051,18 +934,17 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
                 f"ensemble footprint exhausted device memory ({e}); "
                 f"degrade to replica batches of {batch}",
                 batch=batch) from e
-        else:
-            cur_seg = seg if seg else max(1, int(pause) - int(good_t))
-            if cur_seg > 1 and seg_halvings < MAX_SEG_HALVINGS:
-                seg = max(1, cur_seg // 2)
-                seg_halvings += 1
-                rung = f"dispatch_segment {cur_seg}->{seg}"
-                span_kw = {"segment": seg}
+        cur_seg = seg if seg else max(1, int(pause) - int(good_t))
+        if cur_seg > 1 and seg_halvings < MAX_SEG_HALVINGS:
+            seg = max(1, cur_seg // 2)
+            seg_halvings += 1
+            rung = f"dispatch_segment {cur_seg}->{seg}"
+            span_kw = {"segment": seg}
         if not rung:
             log.error(
                 "OOM ladder exhausted: deterministic memory "
                 "exhaustion past t=%d ns (%s) with no rung left "
-                "(depth=1, segment floor reached); escalating via "
+                "(segment floor reached); escalating via "
                 "failover: %s", good_t, e, xp.failover)
             _escalate(runner, e, good_state, good_t, stop, ensemble,
                       ck)
@@ -1072,15 +954,14 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
                 hasattr(chaos_inj, "on_degrade_rung"):
             chaos_inj.on_degrade_rung(rung)
         try:
-            est = capacity.footprint(runner.engine,
-                                     pipeline_depth=window.depth)
+            est = capacity.footprint(runner.engine)
             b, src = capacity.device_budget(runner.engine, xp)
             log.warning(
                 "OOM ladder rung %d (%s): deterministic memory "
                 "exhaustion past t=%d ns (%s); re-admission "
                 "estimate ~%s per device%s — replaying from the "
                 "last validated state (bit-identical: segmentation "
-                "and pipelining are pure host orchestration)",
+                "is pure host orchestration)",
                 res.degrades, rung, good_t, e,
                 capacity.fmt_bytes(est["per_device"]),
                 (f" vs budget {capacity.fmt_bytes(b)} ({src})"
@@ -1097,67 +978,50 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
         return rewind_to_good(new_state)
 
     while t < pause:
-        # ---- ISSUE half: keep up to `depth` segments in flight.
-        # Dispatch is asynchronous — run_segment returns device
-        # futures in milliseconds — so each push hands the device
-        # its next segment before the previous one synchronized. A
-        # pending error or a preemption request stops new issues;
-        # the drain below settles what is already in flight.
-        while pending_error is None and not window.full \
-                and t_issue < pause \
-                and not (guard is not None and guard.requested):
-            nxt = next_boundary(t_issue)
-            try:
-                with tracer.span("dispatch.issue", "dispatch.issue",
-                                 sim_t0=t_issue, sim_t1=nxt,
-                                 in_flight=len(window)):
-                    if chaos_inj is not None:
-                        # the deterministic chaos seam: counts this
-                        # issue and raises the scripted error when a
-                        # fault (or a previously killed device on
-                        # this mesh) is scheduled here — routed
-                        # through pending_error like any real
-                        # asynchronous dispatch failure
-                        chaos_inj.on_dispatch_issue(runner.engine)
-                    cur_state, seg_rounds = run_segment(cur_state,
-                                                        nxt)
-            except AuditFailure:
-                raise
-            except Exception as e:  # noqa: BLE001 — classified at drain
-                # segments issued before this failure may be fine —
-                # hold the error until they drain
-                pending_error = e
-                break
-            window.push(_InFlight(t_issue, nxt, cur_state,
-                                  seg_rounds))
-            pstats["issued"] += 1
-            pstats["max_in_flight"] = max(pstats["max_in_flight"],
-                                          len(window))
-            t_issue = nxt
-
-        # ---- DRAIN half: sync + validate + boundary side effects
-        # for the OLDEST in-flight segment, strictly in issue order.
-        if len(window):
-            fl = window.pop()
+        if guard is not None and guard.requested:
+            # preemption drain: the previous segment finished its
+            # boundary work — save the resume checkpoint at this
+            # validated boundary. (A signal during the FINAL segment
+            # needs no drain — the t >= pause case falls out of the
+            # loop and the run completes normally.)
+            tracer.instant("preempt.request", "checkpoint", sim_t0=t,
+                           signum=guard.signum)
+            with tracer.span("checkpoint.drain_save", "checkpoint",
+                             sim_t0=t) as sp:
+                res.resume_path = drain_save(state, t)
+                sp.add(path=res.resume_path)
+            res.preempted = True
+            log.warning(
+                "%srun preempted at t=%d ns: resume checkpoint -> %s "
+                "(re-run with experimental.checkpoint_load: %s to "
+                "continue; the resumed run is bit-identical to an "
+                "uninterrupted one)", label, t, res.resume_path,
+                ck.base if ck is not None else res.resume_path)
+            break
+        nxt = next_boundary(t)
+        try:
+            with tracer.span("dispatch.issue", "dispatch.issue",
+                             sim_t0=t, sim_t1=nxt):
+                if chaos_inj is not None:
+                    # the deterministic chaos seam: counts this issue
+                    # and raises the scripted error when a fault (or
+                    # a previously killed device on this mesh) is
+                    # scheduled here — recovered like any real
+                    # dispatch failure
+                    chaos_inj.on_dispatch_issue(runner.engine)
+                new_state, seg_rounds = run_segment(state, nxt)
             sync0 = time.perf_counter()
-            if last_sync_end is not None and len(window):
-                # host wall since the last sync ended, spent with
-                # further segments in flight: exactly the work the
-                # device execution of those segments overlapped
-                pstats["overlapped_host_s"] += sync0 - last_sync_end
             try:
-                # the blocking half the old conflated "dispatch"
-                # span hid: both device_gets synchronize segment
-                # [t0, t1), so asynchronously raised dispatch errors
-                # surface inside this try. A raised error closes the
-                # span with an error tag, so retries show on the
-                # timeline as failed-sync + backoff + recover spans.
+                # both device_gets synchronize segment [t, nxt), so
+                # asynchronously raised dispatch errors surface
+                # inside this span. A raised error closes the span
+                # with an error tag, so retries show on the timeline
+                # as failed-sync + backoff + recover spans.
                 with tracer.span("dispatch.sync", "dispatch.sync",
-                                 sim_t0=fl.t0, sim_t1=fl.t1,
-                                 in_flight=len(window)) as sp:
-                    dims = capacity.overflow_dims(fl.state)
+                                 sim_t0=t, sim_t1=nxt) as sp:
+                    dims = capacity.overflow_dims(new_state)
                     seg_rounds = np.asarray(
-                        jax.device_get(fl.rounds))
+                        jax.device_get(seg_rounds))
                     sp.add(rounds=int(np.max(seg_rounds)))
                     eff = runner.engine.effective
                     if eff.get("n_shards", 1) > 1:
@@ -1174,137 +1038,78 @@ def advance(runner, state, t_start: int, pause: int, stop: int,
                                    "ICI_rows_per_flush"],
                                ici_bytes_per_flush=eff[
                                    "ICI_bytes_per_flush"])
-            except AuditFailure:
-                raise
-            except Exception as e:  # noqa: BLE001 — classified below
-                last_sync_end = time.perf_counter()
-                pstats["sync_wall_s"] += last_sync_end - sync0
-                state = recover_transient(e)
-                continue
-            last_sync_end = time.perf_counter()
-            pstats["sync_wall_s"] += last_sync_end - sync0
-            pstats["drained"] += 1
-            if dims:
-                discarded = window.discard()
-                pstats["discarded"] += discarded
-                if not retry_ok or \
-                        runner.replans >= capacity.MAX_REPLANS:
-                    res.rounds = res.rounds + seg_rounds
-                    state = fl.state
-                    t = fl.t1
-                    res.overflowed = True
-                    tracer.instant("capacity.overflow", "plan",
-                                   sim_t0=t, dims=list(dims))
-                    break       # loud failure (stats.ok = False)
-                runner.replans += 1
-                runner._capacity_overrides = capacity.widen(
-                    runner._capacity_overrides, dims,
-                    runner.engine.effective)
-                log.warning(
-                    "%scapacity overflow on %s in (%d, %d] ns; "
-                    "re-plan #%d with %s (%d speculative segment(s) "
-                    "discarded), re-running from t=%d ns", label,
-                    dims, fl.t0, fl.t1, runner.replans,
-                    runner._capacity_overrides, discarded, good_t)
-                with tracer.span("capacity.replan", "plan",
-                                 sim_t0=good_t, sim_t1=fl.t1,
-                                 dims=list(dims),
-                                 replan=runner.replans):
-                    runner.engine = runner._build_engine()
-                    # the re-plan just named the next program: its
-                    # AOT entry read overlaps the state transfer
-                    prefetch_programs(runner, ensemble)
-                    new_state = replace_state(
-                        jax.device_get(good_state))
-                state = rewind_to_good(new_state)
-                continue
-            state = fl.state
-            res.rounds = res.rounds + seg_rounds
-            t = fl.t1
-            failures = 0        # the segment drained clean; see above
-            oom_streak = 0      # ... and so did any OOM streak
-            if int(np.max(res.rounds)) >= budget:
-                # enforced cumulatively (per-invocation caps would
-                # reset each segment); speculative segments past the
-                # budget are discarded un-synced — the serial loop
-                # would never have issued them
-                pstats["discarded"] += window.discard()
-                if t < pause:
-                    log.warning("max_rounds (%d) exhausted during "
-                                "%ssegmentation; stopping", budget,
-                                label)
-                res.budget_hit = True
-                tracer.instant("budget.exhausted", "host", sim_t0=t,
-                               budget=int(budget))
-                break
-            if audit_on:
-                # the boundary state is validated BEFORE it becomes
-                # the known-good snapshot or a checkpoint — a
-                # corrupted state is never the one a retry or a
-                # restart resumes from (in-flight successors of a
-                # corrupted state die with the raise)
-                check_audit(fl.state, where=f"t={t} ns",
-                            last_good=(ck.last_path if ck is not None
-                                       else ""))
-            if next_hb is not None and t >= next_hb and t < stop:
-                with tracer.span("heartbeat", "host", sim_t0=t):
-                    runner._emit_heartbeats(t, fl.state)
-                next_hb += hb
-            if next_ck is not None and t >= next_ck and t < stop:
-                with tracer.span("checkpoint.save", "checkpoint",
-                                 sim_t0=t) as sp:
-                    sp.add(path=ck.save(runner.engine, fl.state, t))
-                next_ck = ck.next_after(t)
-            if keep_good:
-                good_state, good_t = fl.state, t
-        elif pending_error is not None:
-            e, pending_error = pending_error, None
+            finally:
+                pstats["sync_wall_s"] += time.perf_counter() - sync0
+        except AuditFailure:
+            raise
+        except Exception as e:  # noqa: BLE001 — classified there
             state = recover_transient(e)
-        elif guard is not None and guard.requested and t < pause:
-            # preemption drain: the issue half stopped on the
-            # request and every in-flight segment has drained
-            # through its normal boundary work above — save the
-            # resume checkpoint at the last validated boundary. (A
-            # signal during the FINAL segment needs no drain — the
-            # t >= pause case falls out of the loop and the run
-            # completes normally.)
-            tracer.instant("preempt.request", "checkpoint", sim_t0=t,
-                           signum=guard.signum)
-            with tracer.span("checkpoint.drain_save", "checkpoint",
-                             sim_t0=t) as sp:
-                res.resume_path = drain_save(state, t)
-                sp.add(path=res.resume_path)
-            res.preempted = True
+            continue
+        pstats["segments"] += 1
+        if dims:
+            if not retry_ok or runner.replans >= capacity.MAX_REPLANS:
+                res.rounds = res.rounds + seg_rounds
+                state = new_state
+                t = nxt
+                res.overflowed = True
+                tracer.instant("capacity.overflow", "plan",
+                               sim_t0=t, dims=list(dims))
+                break           # loud failure (stats.ok = False)
+            runner.replans += 1
+            runner._capacity_overrides = capacity.widen(
+                runner._capacity_overrides, dims,
+                runner.engine.effective)
             log.warning(
-                "%srun preempted at t=%d ns: resume checkpoint -> %s "
-                "(re-run with experimental.checkpoint_load: %s to "
-                "continue; the resumed run is bit-identical to an "
-                "uninterrupted one)", label, t, res.resume_path,
-                ck.base if ck is not None else res.resume_path)
+                "%scapacity overflow on %s in (%d, %d] ns; re-plan "
+                "#%d with %s, re-running from t=%d ns", label, dims,
+                t, nxt, runner.replans, runner._capacity_overrides,
+                good_t)
+            with tracer.span("capacity.replan", "plan",
+                             sim_t0=good_t, sim_t1=nxt,
+                             dims=list(dims), replan=runner.replans):
+                runner.engine = runner._build_engine()
+                # the re-plan just named the next program: its AOT
+                # entry read overlaps the state transfer
+                prefetch_programs(runner, ensemble)
+                new_state = replace_state(jax.device_get(good_state))
+            state = rewind_to_good(new_state)
+            continue
+        state = new_state
+        res.rounds = res.rounds + seg_rounds
+        t = nxt
+        failures = 0            # the segment synced clean; see above
+        oom_streak = 0          # ... and so did any OOM streak
+        if int(np.max(res.rounds)) >= budget:
+            # enforced cumulatively (per-invocation caps would reset
+            # each segment)
+            if t < pause:
+                log.warning("max_rounds (%d) exhausted during "
+                            "%ssegmentation; stopping", budget, label)
+            res.budget_hit = True
+            tracer.instant("budget.exhausted", "host", sim_t0=t,
+                           budget=int(budget))
             break
-        else:
-            # unreachable by construction (t < pause with nothing in
-            # flight, nothing pending, and no preemption means the
-            # issue half must have issued) — fail loudly rather than
-            # spin silently if a refactor ever breaks the invariant
-            raise RuntimeError(
-                f"segment pipeline stalled at t={t} ns < pause="
-                f"{pause} ns with an empty window")
+        if audit_on:
+            # the boundary state is validated BEFORE it becomes the
+            # known-good snapshot or a checkpoint — a corrupted state
+            # is never the one a retry or a restart resumes from
+            check_audit(state, where=f"t={t} ns",
+                        last_good=(ck.last_path if ck is not None
+                                   else ""))
+        if next_hb is not None and t >= next_hb and t < stop:
+            with tracer.span("heartbeat", "host", sim_t0=t):
+                runner._emit_heartbeats(t, state)
+            next_hb += hb
+        if next_ck is not None and t >= next_ck and t < stop:
+            with tracer.span("checkpoint.save", "checkpoint",
+                             sim_t0=t) as sp:
+                sp.add(path=ck.save(runner.engine, state, t))
+            next_ck = ck.next_after(t)
+        if keep_good:
+            good_state, good_t = state, t
     res.t_end = t
-    adv_wall = time.perf_counter() - adv_wall0
-    host_s = max(0.0, adv_wall - pstats["sync_wall_s"])
-    pstats["advance_wall_s"] = round(adv_wall, 3)
+    pstats["advance_wall_s"] = round(time.perf_counter() - adv_wall0, 3)
     pstats["sync_wall_s"] = round(pstats["sync_wall_s"], 3)
-    pstats["overlapped_host_s"] = round(pstats["overlapped_host_s"],
-                                        3)
-    # share of the advance loop's non-blocked host wall that ran
-    # with >= 1 dispatch in flight — 0 at depth 1 by construction
-    # (the window is empty whenever the host works), approaching 1
-    # when the device always had queued segments during host-side
-    # boundary work. This is the METRICS overlap-efficiency line.
-    pstats["overlap_efficiency"] = (
-        round(pstats["overlapped_host_s"] / host_s, 3)
-        if host_s > 1e-9 else 0.0)
     return state, res
 
 

@@ -100,8 +100,8 @@ class EnsembleRunner:
         self.degrades = 0
         self._planned = False
         # preflight admission verdict (capacity.admission_verdict),
-        # set per run(); the shared advance loop reads its overrides
-        # and the ENSEMBLE/bench records stamp it
+        # set per run(); run() reads its replica_batch override and
+        # the ENSEMBLE record carries it
         self.admission = None
         # nonzero = the OOM ladder may degrade this campaign to
         # sequential replica batches of this size (set per run();
@@ -473,6 +473,7 @@ class EnsembleRunner:
         engine_full, finals, rounds_parts = self.engine, [], []
         ck_full = self.checkpointer
         combined = supervise.AdvanceResult()
+        pl = combined.pipeline
         try:
             for b in range(n_batches):
                 lo, hi = b * batch, min(R, (b + 1) * batch)
@@ -523,7 +524,10 @@ class EnsembleRunner:
                 combined.degrades += adv.degrades
                 combined.budget_hit |= adv.budget_hit
                 combined.overflowed |= adv.overflowed
-                combined.pipeline = adv.pipeline
+                # the batches run one after another: their dispatch
+                # telemetry sums
+                for k, v in adv.pipeline.items():
+                    pl[k] = pl.get(k, 0) + v
                 if adv.preempted:
                     # the drain already saved THIS batch's rotation
                     # entry; stop the loop — later batches never
@@ -540,10 +544,10 @@ class EnsembleRunner:
             self._replica_offset = 0
             self.engine = engine_full
             self.checkpointer = ck_full
-        pl = dict(combined.pipeline or {})
+        for k in ("sync_wall_s", "advance_wall_s"):
+            pl[k] = round(pl[k], 3)
         pl["replica_batches"] = int(n_batches)
         pl["replica_batch"] = int(batch)
-        combined.pipeline = pl
         if isinstance(self.admission, dict):
             self.admission["replica_batch"] = int(batch)
         if combined.preempted:
@@ -630,20 +634,18 @@ class EnsembleRunner:
             if self._base._adopt_checkpoint_geometry(load_path):
                 self.engine = self._build_engine()
         # preflight admission (capacity.py): the campaign footprint —
-        # per-replica state x R, exchange scratch, pipeline copies —
+        # per-replica state x R x two copies, exchange scratch —
         # against the per-device budget, BEFORE any compile (the
         # first compile happens lazily at the first dispatch, which
         # the capacity warm-up below would trigger). strict refuses
-        # over-budget here; auto may statically degrade the pipeline
-        # depth or pre-split the sweep into replica batches.
+        # over-budget here; auto may pre-split the sweep into replica
+        # batches.
         batch = knob_batch
         ck_on = bool(xp.checkpoint_save or xp.checkpoint_load
                      or xp.checkpoint_every)
         can_batch = w.R > 1 and not batch and not ck_on
         self.admission = capacity.admission_verdict(
-            self.engine, xp,
-            pipeline_depth=getattr(xp, "pipeline_depth", 0),
-            batchable=can_batch)
+            self.engine, xp, batchable=can_batch)
         adm_ov = self.admission.get("overrides") or {}
         if not batch and adm_ov.get("replica_batch"):
             batch = int(adm_ov["replica_batch"])
@@ -823,8 +825,7 @@ class EnsembleRunner:
         self.record = self._build_record(final, rounds_r, wall, ok)
         if self.admission is not None:
             # the preflight verdict (and any replica-batch split)
-            # rides the campaign record — bench.py stamps it into
-            # the ensemble BENCH records from here
+            # rides the campaign record
             self.record["admission"] = self.admission
         if batch:
             self.record["replica_batch"] = int(batch)
@@ -870,7 +871,7 @@ class EnsembleRunner:
         stats.resume_path = adv.resume_path
         if self.hb_monitor is not None:
             stats.stale_heartbeats = self.hb_monitor.stale_events
-        # campaigns ride the same segment pipeline as standalone runs
+        # campaigns ride the same advance loop as standalone runs
         # (supervise.advance is shared) — report its telemetry too
         stats.pipeline = adv.pipeline or None
         stats.ensemble = self.record

@@ -23,8 +23,7 @@ Three output surfaces (docs/observability.md):
 * a ``METRICS_<label>.json`` summary with per-phase wall attribution
   (host_s / judge_s / dispatch.issue_s / dispatch.sync_s /
   exchange_s / checkpoint_s / retry_s, plus compile_s / plan_s) that
-  bench.py and
-  scripts/trace_report.py consume. ``host_s`` is the RESIDUAL — total
+  scripts/trace_report.py consumes. ``host_s`` is the RESIDUAL — total
   tracer-lifetime wall minus every non-host measured bucket — i.e.
   exactly the host-side Python time no span claims, so the buckets
   always sum to the total by construction.
@@ -209,8 +208,8 @@ class Tracer:
     call sites with no plumbing path (aotcache, capacity). Wall
     stamps are offsets from construction
     (``perf_counter``), so the tracer's lifetime — not just the run()
-    window — is the attribution total: pre-run work (bench's
-    plan+warm, the engine's first compile) lands inside it.
+    window — is the attribution total: pre-run work (capacity
+    planning, the engine's first compile) lands inside it.
     """
 
     def __init__(self, mode: str = "summary", directory: str = "",

@@ -84,10 +84,8 @@ def workload_stamp(app, n_hosts: int) -> dict:
 def verify_workload(record: dict, app, n_hosts: int,
                     path: str = "") -> None:
     """Loud mismatch refusal: the record's workload stamp must match
-    this simulation exactly. Shared by runner adoption AND bench's
-    provenance stamping (bench must never stamp plan provenance from
-    a fingerprint-mismatched file), so the two checks cannot
-    drift."""
+    this simulation exactly (runner adoption goes through
+    :func:`resolve_plan`)."""
     want = workload_stamp(app, n_hosts)
     got = {k: record.get("workload", {}).get(k) for k in want}
     if got != want:

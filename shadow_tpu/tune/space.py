@@ -104,33 +104,6 @@ def _seg_candidates(cfg, ctx) -> tuple:
     return tuple(uniq)
 
 
-def _coerce_depth(v) -> int:
-    n = int(v)
-    if n < 0:
-        raise ValueError(f"negative pipeline_depth {v!r}")
-    if n > 64:
-        raise ValueError(f"pipeline_depth {v!r} must be <= 64 "
-                         "(each in-flight segment pins a device "
-                         "state copy)")
-    return n
-
-
-def _pipeline_candidates(cfg, ctx) -> tuple:
-    """Pipeline-depth ladder: serial (1), double-buffered (2), and a
-    deep window (4). The trade is overlap of host-side boundary work
-    with device rounds (deeper hides more) against device memory
-    (each in-flight segment pins a state copy) and recovery replay
-    distance. Depth never reshapes the compiled program and is
-    bit-identity-pinned at every value (determinism_gate
-    --pipelined), so it joins the space as a free runtime knob — the
-    autotuner's biggest new lever on sync-bound meshes. A hand-set
-    0 normalizes to 1 — advance() runs both as the identical serial
-    loop, and two byte-identical trials would waste a full
-    bounded-sim run per descent pass."""
-    cur = max(1, int(cfg.experimental.pipeline_depth))
-    return tuple(dict.fromkeys((cur, 1, 2, 4)))
-
-
 def _judge_candidates(cfg, ctx) -> tuple:
     cur = int(cfg.experimental.hybrid_judge_min_batch)
     ladder = (0, 64, 192, 512, 1024)
@@ -190,14 +163,6 @@ KNOBS: tuple[Knob, ...] = (
          _seg_candidates,
          lambda cfg, ctx: ctx["policy"] == "tpu",
          _coerce_time_ns),
-    Knob("pipeline_depth", "experimental", False,
-         "in-flight dispatch segments (0/1 = serial issue+sync)",
-         _pipeline_candidates,
-         # device policies only: the pipeline lives in the device
-         # runners' shared segmented-advance loop — the hybrid
-         # policy's judge flushes have no segment window to overlap
-         lambda cfg, ctx: ctx["policy"] == "tpu",
-         _coerce_depth),
     Knob("hybrid_judge_min_batch", "experimental", False,
          "rounds smaller than this judge on the CPU, not the device",
          _judge_candidates,

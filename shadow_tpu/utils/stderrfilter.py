@@ -1,6 +1,6 @@
 """Known-noise XLA stderr filtering for captured log tails.
 
-The driver that runs bench.py / __graft_entry__.py captures the last
+A harness that runs __graft_entry__.py captures the last
 few KB of stderr into BENCH_*/MULTICHIP_*.json ``tail`` fields. On
 every CPU(-fallback) start, XLA's cpu_aot_loader logs a multi-KB
 single-line machine-feature WARNING (see MULTICHIP_r05.json) that
@@ -8,7 +8,7 @@ drowns every useful line in that window. ``TF_CPP_MIN_LOG_LEVEL=2``
 suppresses most of it, but the AOT loader line is emitted through a
 path that ignores the knob on some jaxlib builds — so the entry
 points additionally route fd 2 through :func:`install_fd_filter`,
-which drops known-noise lines AT THE PIPE, before anything the driver
+which drops known-noise lines AT THE PIPE, before anything a harness
 could capture. Everything else (including real XLA errors) passes
 through byte-for-byte.
 

@@ -6,10 +6,10 @@ The contract under test: a run must never OOM blind. Before any
 compile, both runners estimate the per-device byte footprint and
 compare it to the budget — `admission: strict` refuses over-budget
 configs with a readable diagnostic, `auto` statically degrades
-(pipeline_depth, then ensemble replica batches) or admits loudly.
-At runtime, a deterministic RESOURCE_EXHAUSTED walks a degradation
-ladder (halve pipeline depth -> split the ensemble into sequential
-replica batches -> halve the dispatch segment -> failover) instead
+(ensemble replica batches) or admits loudly. At runtime, a
+deterministic RESOURCE_EXHAUSTED walks a degradation ladder (split
+the ensemble into sequential replica batches -> halve the dispatch
+segment -> failover) instead
 of draining dispatch_retries, and every rung is bit-identical to
 the undegraded run. The footprint model itself is kept honest
 against live device bytes within capacity.FOOTPRINT_TOLERANCE.
@@ -189,7 +189,7 @@ def test_strict_refusal_is_readable_and_precedes_compile(tmp_path):
              "  device_memory_budget: 4KiB\n"
              f"  compile_cache: {aot}")
     # the diagnostic must name the levers, not just the numbers
-    assert "pipeline_depth" in str(ei.value)
+    assert "ensemble.replicas" in str(ei.value)
     assert not aot.is_dir() or not list(aot.iterdir())
 
 
@@ -227,64 +227,31 @@ def test_auto_over_budget_admits_loudly_and_runs(ref):
     assert _sig(stats, c) == sig_ref
 
 
-def test_auto_degrades_pipeline_depth_preflight(ref):
-    sig_ref, _, c_ref = ref
-    # a budget BETWEEN the depth-1 and depth-4 footprints: auto must
-    # shed depth until the estimate fits, and the shallower run must
-    # stay bit-identical (depth is pure host orchestration)
-    est1 = capacity.footprint(c_ref.runner.engine,
-                              pipeline_depth=1)["per_device"]
-    est4 = capacity.footprint(c_ref.runner.engine,
-                              pipeline_depth=4)["per_device"]
-    assert est1 < est4
-    budget = (est1 + est4) // 2
-    stats, c = _run("  dispatch_segment: 200ms\n"
-                    "  state_audit: true\n"
-                    "  pipeline_depth: 4\n"
-                    f"  device_memory_budget: {budget}")
-    assert stats.ok
-    adm = stats.admission
-    assert adm["action"] == "degrade" and adm["fits"]
-    assert 1 <= adm["overrides"]["pipeline_depth"] < 4
-    assert _sig(stats, c) == sig_ref
-
-
 # ---------------------------------------------------------------------------
 # the runtime ladder: deterministic OOM degrades instead of aborting
 # ---------------------------------------------------------------------------
 
-def test_deterministic_oom_walks_depth_rung_within_retry_budget(ref):
+@pytest.mark.parametrize("retries", [1, 3])
+def test_deterministic_oom_halves_dispatch_segment(ref, retries):
     sig_ref, _, _ = ref
-    # a scripted RESOURCE_EXHAUSTED that REPEATS until a rung engages,
-    # against a retry budget of ONE: without the ladder short-circuit
-    # (second consecutive identical OOM -> degrade, budget untouched)
-    # this run could only escalate
-    stats, c = _run(OOM_BASE +
-                    "  pipeline_depth: 2\n"
+    # a scripted RESOURCE_EXHAUSTED that REPEATS until a rung engages:
+    # the first one charges one ordinary retry, the second
+    # consecutive identical one routes to the ladder whatever budget
+    # is left. No ensemble: the rung halves the dispatch segment and
+    # replays
+    stats, c = _run(OOM_BASE.replace("dispatch_retries: 1",
+                                     f"dispatch_retries: {retries}") +
                     "  chaos:\n"
                     "  - {kind: oom, segment: 1}")
     assert stats.ok
     assert stats.degrades == 1
-    assert stats.retries <= 1      # the budget was never exhausted
+    assert stats.retries == 1      # the ladder walk charged nothing
     assert _sig(stats, c) == sig_ref
     kinds = [f["kind"] for f in c.runner.chaos.fired]
     assert "oom" in kinds and "oom_cleared" in kinds
-
-
-def test_deterministic_oom_at_depth_1_halves_dispatch_segment(ref):
-    sig_ref, _, _ = ref
-    # no pipeline depth to shed, no ensemble: the ladder's next rung
-    # halves the dispatch segment and replays
-    stats, c = _run(OOM_BASE +
-                    "  chaos:\n"
-                    "  - {kind: oom, segment: 1}")
-    assert stats.ok
-    assert stats.degrades >= 1
-    assert stats.retries <= 1
-    assert _sig(stats, c) == sig_ref
     cleared = [f for f in c.runner.chaos.fired
                if f["kind"] == "oom_cleared"]
-    assert cleared and "dispatch_segment" in cleared[0]["rung"]
+    assert cleared[0]["rung"] == "dispatch_segment 200000000->100000000"
 
 
 def test_compile_seam_oom_walks_ladder(tmp_path, ref):
@@ -292,7 +259,6 @@ def test_compile_seam_oom_walks_ladder(tmp_path, ref):
     # a COLD private cache so the compile actually runs (a warm hit
     # compiles nothing and the seam never fires)
     stats, c = _run(OOM_BASE +
-                    "  pipeline_depth: 2\n"
                     f"  compile_cache: {tmp_path / 'aot'}\n"
                     "  chaos:\n"
                     "  - {kind: oom, compile: 0}")
@@ -327,7 +293,7 @@ def test_replica_batch_config_bitmatches_full_vmap(tmp_path, ens_full):
 
 
 def test_oom_walks_replica_batch_rung_bitmatch(tmp_path, ens_full):
-    # depth 1, ensemble: the ladder's replica-batch rung re-runs the
+    # ensemble: the ladder's replica-batch rung re-runs the
     # campaign as sequential batches — bit-identical to the full vmap
     rec = tmp_path / "ENSEMBLE.json"
     c = Controller(load_config_str(
@@ -345,6 +311,34 @@ def test_oom_walks_replica_batch_rung_bitmatch(tmp_path, ens_full):
     cleared = [f for f in c.runner.chaos.fired
                if f["kind"] == "oom_cleared"]
     assert cleared and "replica" in cleared[0]["rung"]
+
+
+def test_replica_batches_sum_dispatch_telemetry(tmp_path, monkeypatch):
+    # the batches run one after another: the campaign's dispatch
+    # block is the sum of each batch's advance telemetry
+    from shadow_tpu.device import supervise
+
+    per_batch = []
+    orig = supervise.advance
+
+    def spy(*a, **kw):
+        state, adv = orig(*a, **kw)
+        per_batch.append(dict(adv.pipeline))
+        return state, adv
+
+    monkeypatch.setattr(supervise, "advance", spy)
+    c = Controller(load_config_str(
+        YAML.format(extra="  dispatch_segment: 200ms")
+        + ENS.format(rec=tmp_path / "ENSEMBLE.json")
+        + "  replica_batch: 1\n"))
+    stats = c.run()
+    assert stats.ok
+    assert len(per_batch) == 2
+    p = stats.pipeline
+    assert p["segments"] == sum(b["segments"] for b in per_batch) == 8
+    for k in ("sync_wall_s", "advance_wall_s"):
+        assert p[k] == round(sum(b[k] for b in per_batch), 3)
+    assert 0.0 < p["sync_wall_s"] <= p["advance_wall_s"]
 
 
 # ---------------------------------------------------------------------------
@@ -366,30 +360,39 @@ def _spy_live(monkeypatch, cls):
     return samples
 
 
-def _honest(samples, engine, depth):
+def _honest(samples, engine):
     assert samples
     live = max(samples)
-    est = capacity.footprint(engine,
-                             pipeline_depth=depth)["per_device"]
+    est = capacity.footprint(engine)["per_device"]
     tol = capacity.FOOTPRINT_TOLERANCE
     assert live <= est * tol, (live, est)   # never a blind underestimate
     assert est <= live * tol, (live, est)   # never uselessly conservative
 
 
-def test_footprint_honest_standalone(monkeypatch):
+@pytest.mark.parametrize("extra", [
+    "  dispatch_segment: 200ms",
+    "  dispatch_segment: 200ms\n  state_audit: true",
+])
+def test_footprint_honest_standalone(monkeypatch, extra):
     gc.collect()
     samples = _spy_live(monkeypatch, DeviceRunner)
-    stats, c = _run("  dispatch_segment: 200ms")
+    stats, c = _run(extra)
     assert stats.ok
-    _honest(samples, c.runner.engine, 0)
+    _honest(samples, c.runner.engine)
 
 
-def test_footprint_honest_pipelined_depth_4(monkeypatch):
-    gc.collect()
-    samples = _spy_live(monkeypatch, DeviceRunner)
-    stats, c = _run("  dispatch_segment: 200ms\n  pipeline_depth: 4")
-    assert stats.ok
-    _honest(samples, c.runner.engine, 4)
+def test_footprint_counts_two_state_copies(ref):
+    # the segment in flight plus the last validated snapshot: the
+    # model holds exactly two copies of the state
+    _, _, c_ref = ref
+    est = capacity.footprint(c_ref.runner.engine)
+    assert est["copies"] == 2 and est["replicas"] == 1
+    assert est["per_device"] == (2 * est["state_bytes"]
+                                 + est["scratch_bytes"]
+                                 + est["world_bytes"])
+    assert set(est) == {"representation", "per_device", "total",
+                        "state_bytes", "scratch_bytes", "world_bytes",
+                        "copies", "replicas", "n_devices"}
 
 
 def test_footprint_honest_ensemble(monkeypatch, tmp_path):
@@ -400,7 +403,7 @@ def test_footprint_honest_ensemble(monkeypatch, tmp_path):
         + ENS.format(rec=tmp_path / "ENSEMBLE.json")))
     stats = c.run()
     assert stats.ok
-    _honest(samples, c.runner.engine, 0)
+    _honest(samples, c.runner.engine)
 
 
 # ---------------------------------------------------------------------------

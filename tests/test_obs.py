@@ -494,3 +494,46 @@ def test_supervise_heartbeat_line(tmp_path, caplog):
         assert "retries=0" in ln and "replans=0" in ln
     if len(lines) > 1:
         assert "pkts/s=n/a" not in lines[1]
+
+
+def _dispatch_metrics(sync_s, advance_s, segments=8):
+    """A METRICS record carrying the advance loop's dispatch block."""
+    return {"format": 1, "mode": "summary", "total_wall_s": 4.0,
+            "phases": {"dispatch.sync_s": sync_s, "host_s": 1.0},
+            "dominant_phase": "dispatch.sync", "spans": 17,
+            "counters": {"packets": 1000,
+                         "pipeline": {"segments": segments,
+                                      "sync_wall_s": sync_s,
+                                      "advance_wall_s": advance_s}}}
+
+
+def test_trace_report_prints_dispatch_block(tmp_path, capsys):
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "scripts"))
+    import trace_report
+
+    p = tmp_path / "METRICS_d.json"
+    p.write_text(json.dumps(_dispatch_metrics(2.5, 3.25)))
+    trace_report.print_report(trace_report.load_metrics(str(p)))
+    out = capsys.readouterr().out
+    assert ("dispatch: 8 segment(s); sync 2.500s of 3.250s advance "
+            "wall") in out
+    assert "depth" not in out and "overlap" not in out
+
+
+def test_trace_report_compare_prints_sync_wall(tmp_path, capsys):
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "scripts"))
+    import trace_report
+
+    pa, pb = tmp_path / "METRICS_a.json", tmp_path / "METRICS_b.json"
+    pa.write_text(json.dumps(_dispatch_metrics(2.5, 3.25)))
+    pb.write_text(json.dumps(_dispatch_metrics(1.0, 2.0, 4)))
+    trace_report.print_compare(trace_report.load_metrics(str(pa)),
+                               trace_report.load_metrics(str(pb)),
+                               str(pa), str(pb))
+    out = capsys.readouterr().out
+    assert "sync wall: A 2.500s -> B 1.000s" in out
+    assert "depth" not in out and "overlap" not in out

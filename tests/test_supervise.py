@@ -420,3 +420,220 @@ def test_ensemble_preemption_gate_slow():
         cwd=repo, capture_output=True, text=True, timeout=1800)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "preemption OK" in r.stdout
+
+
+# ---------------------------------------------------------------------------
+# the segmented advance loop: issue -> sync -> boundary work, serially
+# ---------------------------------------------------------------------------
+
+SEGMENTED = ("  dispatch_segment: 100ms\n"
+             "  state_audit: true")
+
+
+def test_segmented_advance_bitmatches_unsegmented():
+    ref_stats, ref_c = _run()
+    stats, c = _run(SEGMENTED)
+    assert stats.ok
+    assert _sig(stats, c) == _sig(ref_stats, ref_c)
+    p = stats.pipeline
+    # 800ms / 100ms segments, each issued and synced once
+    assert p["segments"] == 8
+    assert stats.telemetry["span_counts"]["dispatch.sync"] == 8
+    assert stats.telemetry["span_counts"]["dispatch.issue"] == 8
+    # the sync wall is measured, not the whole advance: issue
+    # enqueues and boundary work are not blocking waits
+    assert 0.0 < p["sync_wall_s"] <= p["advance_wall_s"]
+    assert set(p) == {"segments", "sync_wall_s", "advance_wall_s"}
+
+
+def test_forced_overflow_mid_run_replays_and_bitmatches(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("SHADOW_TPU_OCC_DIR", str(tmp_path))
+    ref_stats, ref_c = _run("  dispatch_segment: 100ms")
+    assert ref_stats.ok
+    ref = _sig(ref_stats, ref_c)
+
+    # the warm-up slice ends before the phold boots at 10ms, so the
+    # plan is sized on an empty slice (floors only) and a real
+    # segment must overflow — the re-plan replays from the last
+    # validated boundary
+    stats, c = _run("  dispatch_segment: 100ms\n"
+                    "  capacity_plan: auto\n"
+                    "  capacity_warmup: 5ms")
+    assert stats.ok, "re-plan/retry failed to absorb the overflow"
+    assert stats.replans >= 1
+    assert _sig(stats, c) == ref
+    # the overflowing segment was synced, then replayed
+    assert stats.pipeline["segments"] > 8
+
+
+def test_transient_error_respects_consecutive_budget(monkeypatch):
+    ref_stats, ref_c = _run()
+    ref = _sig(ref_stats, ref_c)
+
+    import shadow_tpu.device.engine as eng
+    orig = eng.DeviceEngine.run
+    calls = {"n": 0}
+
+    def flaky(self, state, stop=None, final_stop=None):
+        calls["n"] += 1
+        if calls["n"] == 4:     # a mid-run issue, 3 segments done
+            raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+        return orig(self, state, stop=stop, final_stop=final_stop)
+
+    monkeypatch.setattr(eng.DeviceEngine, "run", flaky)
+    stats, c = _run(SEGMENTED +
+                    "\n  dispatch_retries: 2"
+                    "\n  dispatch_retry_backoff: 0.0")
+    assert stats.ok
+    assert stats.retries == 1
+    assert _sig(stats, c) == ref
+
+    # CONSECUTIVE-failure budget: two hiccups in different segments
+    # each recover under dispatch_retries: 1 — a segment that syncs
+    # clean resets the count
+    calls["n"] = 0
+
+    def flaky_twice(self, state, stop=None, final_stop=None):
+        calls["n"] += 1
+        if calls["n"] in (3, 9):
+            raise RuntimeError("UNAVAILABLE: injected hiccup")
+        return orig(self, state, stop=stop, final_stop=final_stop)
+
+    monkeypatch.setattr(eng.DeviceEngine, "run", flaky_twice)
+    stats2, c2 = _run(SEGMENTED +
+                      "\n  dispatch_retries: 1"
+                      "\n  dispatch_retry_backoff: 0.0")
+    assert stats2.ok
+    assert stats2.retries == 2
+    assert _sig(stats2, c2) == ref
+
+    # a genuinely dead device exhausts the budget: no segment ever
+    # syncs clean, so the failures stay consecutive and the error
+    # surfaces after dispatch_retries replays
+    def dead(self, state, stop=None, final_stop=None):
+        raise RuntimeError("UNAVAILABLE: device went away")
+
+    monkeypatch.setattr(eng.DeviceEngine, "run", dead)
+    with pytest.raises(RuntimeError, match="UNAVAILABLE"):
+        _run(SEGMENTED +
+             "\n  dispatch_retries: 2"
+             "\n  dispatch_retry_backoff: 0.0")
+
+
+def test_preempt_between_segments_resumes_bitidentical(
+        tmp_path, monkeypatch):
+    full_stats, full_c = _run()
+    assert full_stats.ok
+    ref = _sig(full_stats, full_c)
+
+    # SIGTERM raised synchronously inside the third dispatch: the
+    # segment still syncs and finishes its boundary work, then the
+    # loop saves the resume checkpoint before issuing the next
+    base = str(tmp_path / "ck.npz")
+    import shadow_tpu.device.engine as eng
+    orig = eng.DeviceEngine.run
+    calls = {"n": 0}
+
+    def poking(self, state, stop=None, final_stop=None):
+        out = orig(self, state, stop=stop, final_stop=final_stop)
+        calls["n"] += 1
+        if calls["n"] == 3:
+            signal.raise_signal(signal.SIGTERM)
+        return out
+
+    monkeypatch.setattr(eng.DeviceEngine, "run", poking)
+    pre_stats, _ = _run(
+        SEGMENTED +
+        f"\n  checkpoint_save: {base}"
+        f"\n  checkpoint_every: 200ms"
+        f"\n  checkpoint_keep: 3")
+    assert pre_stats.preempted
+    assert pre_stats.resume_path
+    assert os.path.exists(pre_stats.resume_path)
+    # the signalled segment was synced, not thrown away
+    assert pre_stats.pipeline["segments"] == 3
+    assert pre_stats.events_executed < full_stats.events_executed
+
+    monkeypatch.setattr(eng.DeviceEngine, "run", orig)
+    # the resume is bit-identical unsegmented and unaudited...
+    res1_stats, res1_c = _run(f"  checkpoint_load: {base}")
+    assert res1_stats.ok and not res1_stats.preempted
+    assert _sig(res1_stats, res1_c) == ref
+    # ...and segmented with the audit on — segmentation and audit
+    # are host orchestration, never part of the checkpoint contract
+    res2_stats, res2_c = _run(SEGMENTED +
+                              f"\n  checkpoint_load: {base}")
+    assert res2_stats.ok
+    assert _sig(res2_stats, res2_c) == ref
+
+
+def test_preempt_before_first_segment_saves_at_start(tmp_path):
+    # a drain requested before anything was issued saves the start
+    # state and dispatches nothing
+    base = str(tmp_path / "ck.npz")
+    c = Controller(load_config_str(YAML.format(
+        extra=SEGMENTED + f"\n  checkpoint_save: {base}")))
+    runner = c.runner
+    runner.guard = supervise.PreemptionGuard()
+    runner.guard.request()
+    state = runner.engine.init_state(runner.sim.starts)
+    out, res = supervise.advance(runner, state, 0, 800_000_000,
+                                 800_000_000)
+    assert res.preempted and res.t_end == 0
+    assert res.resume_path == base and os.path.exists(base)
+    assert res.pipeline["segments"] == 0
+    assert int(np.max(res.rounds)) == 0
+    assert out is state
+
+
+def test_schema_rejects_pipeline_depth_as_unknown():
+    for policy in ("tpu", "serial"):
+        with pytest.raises(ValueError,
+                           match="unknown key.*pipeline_depth"):
+            load_config_str(YAML.format(
+                extra="  pipeline_depth: 1").replace(
+                    "scheduler_policy: tpu",
+                    f"scheduler_policy: {policy}"))
+
+
+def test_plan_roundtrips_dispatch_segment_and_refuses_pipeline_depth(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("SHADOW_TPU_OCC_DIR", str(tmp_path))
+    from shadow_tpu.core.controller import build
+    from shadow_tpu.device.runner import device_twin
+    from shadow_tpu.tune import plan as planmod
+    from shadow_tpu.tune import space
+
+    cfg = load_config_str(YAML.format(extra=""))
+    assert "pipeline_depth" not in space.KNOB_BY_NAME
+    with pytest.raises(ValueError,
+                       match="unknown knob 'pipeline_depth'"):
+        space.apply_assignment(cfg, {"pipeline_depth": 2})
+    assert space.apply_assignment(
+        cfg, {"dispatch_segment": "100000000"}) == {
+            "dispatch_segment": 100_000_000}
+
+    # plan adoption round-trips the knob and stays bit-identical; a
+    # knob the plan space no longer has is skipped, never applied
+    ref_stats, ref_c = _run()
+    sim = build(load_config_str(YAML.format(extra="")))
+    twin, H = device_twin(sim), len(sim.hosts)
+    path = str(tmp_path / "PLAN_seg.json")
+    planmod.save_plan(
+        {"format": planmod.FORMAT,
+         "workload": {**planmod.workload_stamp(twin, H),
+                      "stop_time": 800_000_000, "seed": 9},
+         "default": {},
+         "knobs": {"dispatch_segment": 100_000_000,
+                   "pipeline_depth": 2},
+         "score": {"pkts_per_s": 1.0}}, path)
+    stats, c = _run(f"  strategy_plan: {path}")
+    assert stats.ok
+    assert c.sim.cfg.experimental.dispatch_segment == 100_000_000
+    assert stats.strategy_plan["knobs"] == {
+        "dispatch_segment": 100_000_000}
+    assert "unknown knob" in stats.strategy_plan["skipped"][
+        "pipeline_depth"]
+    assert stats.pipeline["segments"] == 8
+    assert _sig(stats, c) == _sig(ref_stats, ref_c)

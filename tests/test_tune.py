@@ -400,74 +400,6 @@ def test_tuner_search_writes_no_slower_plan(tmp_path):
 
 
 # ---------------------------------------------------------------------
-# bench provenance stamping: verified plans stamp, mismatches refuse
-# ---------------------------------------------------------------------
-
-def test_bench_plan_stamp_refuses_mismatch(tmp_path):
-    """bench._plan_stamp re-verifies the PLAN file on disk against
-    the run's workload fingerprint before stamping provenance — a
-    mismatched (or vanished) file stamps the refusal, never the
-    plan. Provenance comes from SimStats, so a tpu rung that fell
-    back to hybrid (runner None) still stamps its adopted plan."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-
-    twin, H = _twin(_cfg())
-    path = str(tmp_path / "PLAN_t.json")
-    planmod.save_plan(_record(twin, H, {"dispatch_segment": 7}), path)
-
-    class FakeRunner:
-        app = twin
-
-    class FakeSim:
-        hosts = [object()] * H
-
-    class FakeC:
-        runner = FakeRunner()
-        sim = FakeSim()
-
-    class FakeStats:
-        strategy_plan = {"path": path,
-                         "knobs": {"dispatch_segment": 7},
-                         "skipped": {}, "score": None}
-
-    stamp = bench._plan_stamp(FakeC(), FakeStats())
-    assert stamp["plan"]["path"] == path
-    assert stamp["plan"]["knobs"] == {"dispatch_segment": 7}
-
-    # the hybrid-fallback shape: no runner, the twin re-derived from
-    # the sim — the stamp must still carry the plan
-    class HybridC:
-        runner = None
-        sim = None          # replaced below with a real built sim
-
-    from shadow_tpu.core.controller import build
-    HybridC.sim = build(_cfg())
-    stamp = bench._plan_stamp(HybridC(), FakeStats())
-    assert stamp["plan"]["path"] == path
-
-    # corrupt the on-disk fingerprint: the stamp must flip to the
-    # refusal, not carry stale provenance
-    rec = _record(twin, H, {"dispatch_segment": 7})
-    rec["workload"]["app_fp"] = "deadbeef0000"
-    planmod.save_plan(rec, path)
-    stamp = bench._plan_stamp(FakeC(), FakeStats())
-    assert stamp["plan"] is None
-    assert "tuned for" in stamp["plan_error"]
-
-    os.unlink(path)
-    stamp = bench._plan_stamp(FakeC(), FakeStats())
-    assert stamp["plan"] is None and "plan_error" in stamp
-
-    # no plan in play -> an explicit None stamp, never a KeyError
-    class NoPlanStats:
-        strategy_plan = None
-
-    assert bench._plan_stamp(FakeC(), NoPlanStats()) == {"plan": None}
-
-
-# ---------------------------------------------------------------------
 # trace_report --compare
 # ---------------------------------------------------------------------
 
