@@ -15,7 +15,9 @@ variant 2 — multi-operand sorts vs gather recovery (the flush's
   "tor" times instead the window merge's payload recovery at
   tor_56000's shapes ([56,000 x 160]: a (t, key, iota) row sort +
   three take_along_axis against a row sort that carries the
-  payload), exiting 1 if the forms disagree on a live slot.
+  payload) and the flush's arrival windows ([56,000 x 40] outbox
+  rows into [56,000 x 64]: seg_take's gathers against one filler sort
+  carrying the merge columns), exiting 1 if the forms disagree.
 variant 3 — the candidate gatherless flush (double-sort merge) timed
   end-to-end at the 10k-rung shapes + a numpy oracle check at a small
   shape. Args: [reps].
@@ -331,6 +333,86 @@ def merge_payload_cases(H, E, IN, reps, rng):
     return res
 
 
+def arrival_window_cases(H, OB, IN, reps, rng):
+    """The flush's per-host arrival windows over [H, IN] (engine.py
+    `_host_windows`) from H*OB unsorted outbox rows: the (key, iota)
+    flat sort, searchsorted counts and seg_take's two-hop gathers of
+    the five i64 fields (gather_ms), against a key-only flat sort for
+    the counts and one filler sort carrying the five merge columns
+    (sort_ms, engine.sorted_windows), and against the gathers cut to
+    an i32 permutation and the packed merge words (fallback_ms).
+    Arrivals follow Tor's fan-in: a tenth of the hosts are relays with
+    Poisson(27) arrivals, the rest clients with at most one; host 0
+    takes exactly IN, host 1 none. Returns the timings, each form's
+    compile seconds and temp bytes, the filler share, and whether the
+    three forms give equal windows."""
+    import numpy as np
+    from shadow_tpu._jax import jax, jnp
+    from jax import lax
+    from shadow_tpu.device.engine import (IMAX, INF, XF, merge_cols,
+                                          seg_take, sorted_windows)
+
+    F, SPAN = H * OB, np.int64(H) * OB
+    want = np.where(np.arange(H) < H // 10,
+                    np.minimum(rng.poisson(27, H), IN),
+                    rng.binomial(1, 0.5, H))
+    want[0], want[1] = IN, 0
+    slots = rng.choice(F, int(want.sum()), replace=False)
+    ukey = np.full(F, IMAX, np.int64)
+    ukey[slots] = np.repeat(np.arange(H, dtype=np.int64), want) * SPAN \
+        + slots
+    rows = {f: rng.integers(0, 1 << 62, F).astype(np.int64) for f in XF}
+    rows["t"] = np.where(ukey < IMAX, rows["t"] >> 22, INF)
+    args = (jax.device_put(jnp.asarray(ukey)),
+            {f: jax.device_put(jnp.asarray(v)) for f, v in rows.items()})
+    hb = jnp.arange(H + 1, dtype=jnp.int64) * SPAN
+
+    def counted(skey):
+        edges = jnp.searchsorted(skey, hb)
+        return edges[:-1], edges[1:] - edges[:-1]
+
+    def gather(ukey, rows):
+        skey, perm = lax.sort((ukey, jnp.arange(F, dtype=jnp.int64)),
+                              num_keys=1)
+        starts, counts = counted(skey)
+        return merge_cols(seg_take(perm, rows, starts, counts, IN))
+
+    def sort(ukey, rows):
+        _, counts = counted(lax.sort(ukey, is_stable=False))
+        return sorted_windows(ukey, merge_cols(rows), counts,
+                              jnp.int64(0), SPAN, IN)
+
+    def fallback(ukey, rows):
+        skey, perm = lax.sort((ukey, jnp.arange(F, dtype=jnp.int32)),
+                              num_keys=1)
+        starts, counts = counted(skey)
+        cols = merge_cols(rows)
+        packed = dict(zip(XF, cols[:4] + (cols[4].astype(jnp.uint32),)))
+        win = seg_take(perm, packed, starts, counts, IN)
+        return tuple(win[f] for f in XF[:4]) + \
+            (win["v"].astype(jnp.int64),)
+
+    res = {"shape": [H, OB], "IN": IN,
+           "filler_share": round(
+               1 - float(np.minimum(want, IN).sum()) / (H * IN), 6)}
+    outs = {}
+    for name, fn in (("gather", gather), ("sort", sort),
+                     ("fallback", fallback)):
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        res[f"{name}_compile_s"] = round(time.perf_counter() - t0, 3)
+        res[f"{name}_temp_bytes"] = int(
+            compiled.memory_analysis().temp_size_in_bytes)
+        res[f"{name}_ms"] = timed_ms(f"arrival windows, {name} "
+                                     f"[{H},{IN}]",
+                                     lambda: compiled(*args), reps)
+        outs[name] = [np.asarray(a) for a in compiled(*args)]
+    res["windows_equal"] = all(
+        np.array_equal(a, b) for o in ("sort", "fallback")
+        for a, b in zip(outs[o], outs["gather"]))
+    return res
+
+
 def variant2(args: list[str]) -> int:
     reps = int(args[0]) if args else REPS
     H, OB = 10000, 36
@@ -348,8 +430,12 @@ def variant2(args: list[str]) -> int:
     if "tor" in args[1:]:
         # tor_56000: 56,000 hosts, event_capacity 96, exchange_in 64
         res["tor_merge"] = merge_payload_cases(56000, 96, 64, reps, rng)
+        # outbox_capacity 40: the flush's rows are [56,000 x 40]
+        res["tor_windows"] = arrival_window_cases(56000, 40, 64, reps,
+                                                  rng)
         print(json.dumps(res), flush=True)
-        return 0 if res["tor_merge"]["live_equal"] else 1
+        return 0 if res["tor_merge"]["live_equal"] \
+            and res["tor_windows"]["windows_equal"] else 1
 
     def arr64(shape, hi=1 << 60):
         return jax.device_put(jnp.asarray(

@@ -439,7 +439,8 @@ def engine_matrix() -> list[tuple[str, object]]:
         ("window_merge", _build_engine(merge_global=False,
                                        pop_onehot=False,
                                        judge_hoist=False)),
-        # tor_56000's TPU stack: the window merge carrying its payload
+        # tor_56000's TPU stack: the window merge carrying its payload,
+        # its arrival windows laid out by the filler sort
         ("window_merge_sort", _build_engine(merge_global=False,
                                             merge_payload="sort",
                                             pop_onehot=True,

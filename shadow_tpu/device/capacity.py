@@ -172,6 +172,11 @@ def measure(engine, state, source: str = "run") -> dict:
         "overflow": int(occ["overflow"][:H].sum()),
         "x_overflow": int(occ["x_overflow"][:H].sum()),
     }
+    if "occ_arrivals" in state:
+        # the window merge's flushes only: real arrival rows windowed,
+        # of phases x H x IN slots a block (the rest is filler)
+        measured["arrivals_windowed"] = int(np.asarray(
+            jax.device_get(state["occ_arrivals"])).sum())
     return {
         "format": FORMAT,
         "source": source,
@@ -443,7 +448,7 @@ def grow_heaps(host_state: dict, new_e: int) -> dict:
 # padded width.)
 RESHARD_HOST_ROWS = ("ht", "hk", "hm", "hv", "hw", "app")
 RESHARD_SHARD_ZERO = ("occ_x", "occ_trips", "occ_phases", "occ_iters",
-                      "occ_burst")
+                      "occ_burst", "occ_arrivals")
 RESHARD_SHARD_SUM = ("path_cnt",)
 
 

@@ -100,6 +100,72 @@ AUD_CONSERVE = 8   # event-row conservation broke: rows produced !=
                    # lost — the exchange dropped something silently
 AUD_KEYS = ("aud", "aud_t", "aud_tx")
 
+# the flush's exchanged row fields, and what an empty slot holds
+XF = ("t", "k", "m", "s", "v")
+XF_FILL = {"t": INF, "k": IMAX, "m": 0, "s": 0, "v": 0}
+
+
+def seg_take(perm, rows, starts, counts, width):
+    """Contiguous per-segment windows of a SORTED order: row i of the
+    result is sorted-rows[starts[i]:starts[i]+width], filled (XF_FILL)
+    past counts[i] — a two-hop gather through the sort permutation
+    `perm` (the rows, a dict of XF fields, stay unsorted)."""
+    G = perm.shape[0]
+    idx = starts[:, None] + jnp.arange(width, dtype=starts.dtype)
+    ok = jnp.arange(width)[None, :] < \
+        jnp.minimum(counts, width)[:, None]
+    pidx = jnp.take(perm, jnp.clip(idx, 0, G - 1).reshape(-1))
+    return {f: jnp.where(ok, jnp.take(v, pidx).reshape(idx.shape),
+                         XF_FILL[f])
+            for f, v in rows.items()}
+
+
+def merge_cols(b):
+    """The window merge's five heap columns of a block of XF rows:
+    (t, k, kind << 32 | hi32(s), lo32(s) << 32 | lo32(v), the train
+    survivor mask hi32(v)). The m word's train count is stripped."""
+    u32 = jnp.int64(0xFFFFFFFF)
+    return (b["t"], b["k"],
+            ((b["m"] & 0xFF) << 32) | ((b["s"] >> 32) & u32),
+            ((b["s"] & u32) << 32) | (b["v"] & u32),
+            (b["v"] >> 32) & u32)
+
+
+def sorted_windows(ukey, cols, counts, lo, span, width):
+    """[n, width] arrival windows of hosts lo .. lo+n-1 with no gather:
+    one flat sort of the source rows and n*width filler rows that
+    carries the merge columns (merge_cols, aligned with `ukey`).
+
+    `ukey` holds each row's unsorted key dst*span + okey (okey < span;
+    IMAX = no row) and `counts` the rows keyed to each host. A real row
+    of this range sorts at 2*key; host h gets width - min(counts[h],
+    width) fillers at 2*(h+1)*span - 1, after its last real row and
+    before the next host's first, and everything else sorts at IMAX.
+    So when no count passes `width`, the first n*width sorted rows are
+    exactly the per-host windows of seg_take (content, order and fill).
+    A host past `width` takes all its rows, so every later host's
+    window shifts by the excess (seg_take truncates only that host):
+    those windows are garbage and only the counts the caller keeps are
+    exact. The survivor mask rides as one u32 word."""
+    n = counts.shape[0]
+    lo = lo.astype(jnp.int64)
+    mine = (ukey >= lo * span) & (ukey < (lo + n) * span)
+    rkey = jnp.where(mine, 2 * ukey, IMAX)
+    fok = jnp.arange(width)[None, :] < \
+        (width - jnp.minimum(counts, width))[:, None]
+    fkey = jnp.where(
+        fok, (2 * (lo + 1 + jnp.arange(n, dtype=jnp.int64)) * span
+              - 1)[:, None], IMAX).reshape(n * width)
+    cols = cols[:4] + (cols[4].astype(jnp.uint32),)
+    ops = [jnp.concatenate([fkey, rkey])] + [
+        jnp.concatenate([jnp.full((n * width,), fill, c.dtype), c])
+        for c, fill in zip(cols, (INF, IMAX, 0, 0, 0))]
+    # unstable: rows that tie are fillers of one host, alike in every
+    # column, or IMAX rows, which no window takes
+    out = lax.sort(tuple(ops), num_keys=1, is_stable=False)[1:]
+    out = tuple(o[:n * width].reshape(n, width) for o in out)
+    return out[:4] + (out[4].astype(jnp.int64),)
+
 
 @dataclass
 class EngineConfig:
@@ -176,20 +242,28 @@ class EngineConfig:
     # None = auto by platform. Traces are bit-identical either way
     # (tests pin both).
     merge_global: Optional[bool] = None
-    # the window merge's payload recovery (merge_global False): "sort"
-    # carries the heap payload (m, v and the u32 train mask w) through
-    # the per-host row sort and slices the first E columns — no
-    # gathers, the TPU side of the trade (on a v5e the three
-    # take_along_axis over [56,000 x 96] took 329 ms of a 757 ms
-    # round); "gather" sorts (t, key, column iota) and recovers the
-    # payload with take_along_axis (a narrower sort, the cheaper form
-    # the CPU backend: on an 8-core Xeon the carry sort over
-    # [56,000 x 160] takes 3.7 s against 2.6 s, and a 250-host Tor
-    # run takes 33% longer). None = by platform;
-    # no config option sets it, tests pin both. (t, key) is a total
-    # order over live rows, so every live heap slot is bit-identical
-    # either way; padding slots may hold other stale payload, which
-    # nothing reads.
+    # how the window merge (merge_global False) moves its payload
+    # through its two sorts. "sort": both sorts carry it, no gathers,
+    # the TPU side of the trade. The flush's arrival windows ([H, IN],
+    # _host_windows) are ONE flat sort of the outbox rows and H*IN
+    # filler rows carrying the merge columns (sorted_windows; on a
+    # v5e seg_take's two-hop gathers over [56,000 x 64] took 377 ms
+    # of a 438 ms Tor round), and the per-host row sort carries the
+    # heap payload (m, v and the u32 train mask w) and slices the
+    # first E columns (its three take_along_axis over [56,000 x 96]
+    # took 329 ms of a 757 ms round). "gather": the sorts carry keys
+    # and an iota, and the payload follows by gathers (seg_take, then
+    # take_along_axis) — narrower sorts, the cheaper form on the CPU
+    # backend (PERF.md section 6 has the CPU timings). None = by
+    # platform; no config option sets it, tests pin both. Every live
+    # heap slot is bit-identical either way
+    # ((t, key) is a total order over live rows; padding slots may
+    # hold other stale payload, which nothing reads) while no host's
+    # arrivals pass IN. Past it "sort" shifts every later host's
+    # window too, so the rest of the segment is garbage on those
+    # hosts (and on a mesh may also trip x_overflow, widening the
+    # exchange capacities on the re-plan); the overflow count is
+    # exact and the segment is discarded either way.
     merge_payload: Optional[str] = None
     # pop head reads: True = one-hot masked reductions (compare a
     # column iota against head, select, reduce over E) — pure
@@ -508,6 +582,11 @@ class DeviceEngine:
             "occ_iters": np.zeros(self.n_shards, dtype=np.int32),
             "occ_burst": np.zeros(self.n_shards, dtype=np.int32),
         }
+        if not self.program_facts["merge_global"]:
+            #   occ_arrivals [S] total arrival rows windowed (the
+            #                    window merge's flushes only)
+            small["occ_arrivals"] = np.zeros(self.n_shards,
+                                             dtype=np.int64)
         if self.config.audit:
             # invariant-audit leaves (AUD_* bits above):
             #   aud    [H] the health word (0 = every invariant held)
@@ -1318,15 +1397,15 @@ class DeviceEngine:
         # and exchange strategy. Rows beyond a dst host's IN window (or
         # a shard pair's CAP) are counted in overflow/x_overflow and
         # fail the run — never silently lost (SURVEY hard-part #2).
-        XF = ("t", "k", "m", "s", "v")
 
-        # Sorts move every operand through every bitonic pass, so the
-        # window path's flat sort carries ONLY (key, iota) and recovers
-        # arrival rows with gathers (_seg_take) — the profiler showed
-        # the old 6-operand flat sort dominating round cost on one CPU
-        # core. The per-host merge row sort is the other trade on TPU:
-        # there it carries the payload (merge_payload "sort"), since a
-        # gather costs several times a row sort's extra operands.
+        # Sorts move every operand through every bitonic pass, so on
+        # one CPU core the window path's flat sort carries ONLY (key,
+        # iota) and recovers arrival rows with gathers (seg_take) — the
+        # profiler showed the old 6-operand flat sort dominating round
+        # cost there. On TPU a gather costs several times a sort's
+        # extra operands, so the arrival windows and the per-host merge
+        # row sort both carry the payload instead (merge_payload
+        # "sort").
         CX = min(cfg.outbox_compact or OB, OB)
 
         # effective (post-auto-sizing) capacities, for the occupancy
@@ -1350,7 +1429,7 @@ class DeviceEngine:
             ici_arrays = 6          # keys route phase 2 on both paths
         else:                       # all_gather replicates everything
             ici_rows = (n_shards - 1) * H_loc * CX
-            ici_arrays = 5 if MERGE_GLOBAL else 7   # + skey + perm
+            ici_arrays = 5 if MERGE_GLOBAL else 6   # + the keys
         self.effective = {"E": E, "B": B, "OB": OB, "IN": IN,
                           "CAP": int(CAP), "CAP2": int(CAP2),
                           "CX": CX, "M_out": M_out,
@@ -1417,7 +1496,10 @@ class DeviceEngine:
                  MERGE_GLOBAL, MERGE_PAYLOAD, POP_ONEHOT, TAB_ONEHOT,
                  R_RUNS)
 
-        def _flat_sorted(state, ob, gid):
+        def _flat_rows(state, ob, gid):
+            """The outbox as flat rows: (state, keys, rows), each row's
+            key dst*SPAN + okey (IMAX = nothing to exchange), in outbox
+            order — unsorted."""
             slot = jnp.arange(OB, dtype=jnp.int64)[None, :]
             okey2 = gid.astype(jnp.int64)[:, None] * OB + slot
             fdst2 = hi32(ob["m"]).astype(jnp.int64)
@@ -1449,9 +1531,15 @@ class DeviceEngine:
                 F = H_loc * OB
                 flat = {f: ob[f].reshape(F) for f in XF}
                 skey = skey2.reshape(F)
-            skey_s, perm = lax.sort(
-                (skey, jnp.arange(F, dtype=jnp.int64)), num_keys=1)
-            return state, skey_s, perm, flat
+            return state, skey, flat
+
+        def _sorted_keys(ukey, with_perm=True):
+            """(sorted keys, the sort permutation or None)."""
+            if not with_perm:
+                return lax.sort(ukey, is_stable=False), None
+            return lax.sort((ukey, jnp.arange(ukey.shape[0],
+                                              dtype=jnp.int64)),
+                            num_keys=1)
 
         def _count_paths(state, ob, topo):
             """topology_incrementPathPacketCounter parity: a [V,V]
@@ -1481,30 +1569,16 @@ class DeviceEngine:
                 (prefix[edges[1:]] - prefix[edges[:-1]])[None, :]
             return state
 
-        def _seg_take(perm, rows, starts, counts, width):
-            """Contiguous per-segment windows of the SORTED order: row
-            i of the result is sorted-rows[starts[i]:starts[i]+width],
-            masked past counts — realized as a two-hop gather through
-            the sort permutation (rows stay unsorted)."""
-            G = perm.shape[0]
-            idx = starts[:, None] + jnp.arange(width,
-                                               dtype=starts.dtype)
-            ok = jnp.arange(width)[None, :] < \
-                jnp.minimum(counts, width)[:, None]
-            cidx = jnp.clip(idx, 0, G - 1).reshape(-1)
-            pidx = jnp.take(perm, cidx)
-            out = {}
-            for f in XF:
-                v = jnp.take(rows[f], pidx).reshape(idx.shape)
-                fillv = INF if f == "t" else (IMAX if f == "k" else 0)
-                out[f] = jnp.where(ok, v, fillv)
-            return out
-
-        def _host_windows(state, skey, perm, rows, my_shard):
-            """Per-host contiguous arrival segments -> [H_loc, IN]
-            windows + overflow accounting (shared by the self-shard
-            bypass and the post-exchange arrival step). Also returns
+        def _host_windows(state, ukey, rows, my_shard, srt=None):
+            """My hosts' arrival rows -> the merge columns of their
+            [H_loc, IN] windows (merge_cols) + overflow accounting
+            (shared by the self-shard bypass and the post-exchange
+            arrival step). `ukey` keys the unsorted `rows`; `srt` is
+            _sorted_keys(ukey) where the caller has it. Also returns
             the per-host arrival counts (occupancy telemetry)."""
+            if srt is None:
+                srt = _sorted_keys(ukey, with_perm=MERGE_PAYLOAD == "gather")
+            skey, perm = srt
             base = my_shard.astype(jnp.int64) * H_loc
             hb = (base + jnp.arange(H_loc + 1, dtype=jnp.int64)) \
                 * SPAN
@@ -1512,8 +1586,13 @@ class DeviceEngine:
             starts, counts = edges[:-1], edges[1:] - edges[:-1]
             state["overflow"] = state["overflow"] + \
                 jnp.maximum(0, counts - IN).astype(jnp.int32)
-            return state, _seg_take(perm, rows, starts, counts, IN), \
-                counts.astype(jnp.int32)
+            if MERGE_PAYLOAD == "sort":
+                cols = sorted_windows(ukey, merge_cols(rows), counts,
+                                      base, SPAN, IN)
+            else:
+                cols = merge_cols(seg_take(perm, rows, starts, counts,
+                                           IN))
+            return state, cols, counts.astype(jnp.int32)
 
         def _judge_outbox(state, ob, gid, topo, wrld,
                           win_end):
@@ -1804,7 +1883,7 @@ class DeviceEngine:
             lost_mask = (skey < IMAX) & (rank >= CAP) & \
                 (shard_of != my_shard.astype(jnp.int64))
             state = _lost_to_local(state, lost_mask, skey, my_shard)
-            win = _seg_take(perm, rows, starts, counts, CAP)
+            win = seg_take(perm, rows, starts, counts, CAP)
             with jax.named_scope("engine.exchange"):
                 moved = {f: lax.all_to_all(
                     win[f], AXIS, split_axis=0, concat_axis=0)
@@ -1847,9 +1926,8 @@ class DeviceEngine:
         TP_FIELDS = ("key",) + XF       # stacked ppermute channels
 
         def _tp_mask(ch, vals, ok):
-            fill = IMAX if ch in ("key", "k") else \
-                (INF if ch == "t" else 0)
-            return jnp.where(ok, vals, fill)
+            return jnp.where(ok, vals,
+                             IMAX if ch == "key" else XF_FILL[ch])
 
         def _pack_two_phase(state, skey, perm, rows, my_shard):
             """Returns (state, keys, rows) of everything this shard
@@ -1975,7 +2053,7 @@ class DeviceEngine:
         def _compact_flat(state, ob):
             """Gatherless outbox compaction for the GLOBAL merge
             (outbox_compact; the window path has its own in
-            _flat_sorted): one 5-operand lane sort brings each
+            _flat_rows): one 5-operand lane sort brings each
             host's exchangeable rows (t < DROP_T — they sort before
             judged-drop DROP_T markers and empty INF slots) to the
             front, then a STATIC slice keeps the first CX columns —
@@ -2001,11 +2079,12 @@ class DeviceEngine:
                 # remote rows pack per (src shard, dst shard) for the
                 # all_to_all (x_overflow accounting shared with the
                 # window path); self-shard rows bypass the pack and
-                # feed the merge directly. _flat_sorted already
+                # feed the merge directly. _flat_rows already
                 # compacts its returned rows to CX (and counts the
                 # loss once) — reuse them for the self-shard part
                 # instead of re-compacting ob
-                state, skey, perm, rows = _flat_sorted(state, ob, gid)
+                state, ukey, rows = _flat_rows(state, ob, gid)
+                skey, perm = _sorted_keys(ukey)
                 state, moved, _ = _pack_remote(
                     state, skey, perm, rows, my_shard,
                     ship_keys=False)
@@ -2020,7 +2099,8 @@ class DeviceEngine:
                 # holds the forwards this shard relayed (and any
                 # phase-2 loss) — _ob_rows' [lo, hi) destination mask
                 # drops them, so only true arrivals reach the merge
-                state, skey, perm, rows = _flat_sorted(state, ob, gid)
+                state, ukey, rows = _flat_rows(state, ob, gid)
+                skey, perm = _sorted_keys(ukey)
                 state, kout, rout = _pack_two_phase(
                     state, skey, perm, rows, my_shard)
                 parts = [
@@ -2074,68 +2154,53 @@ class DeviceEngine:
                         (ob["t"] < DROP_T).sum(-1).astype(jnp.int64)
                 if MERGE_GLOBAL:
                     return _exchange_global(state, ob, gid, my_shard)
-                state, skey, perm, rows = _flat_sorted(state, ob, gid)
-                G = H_loc * CX
+                state, ukey, rows = _flat_rows(state, ob, gid)
 
                 inc2 = None
                 arr2 = jnp.zeros(H_loc, jnp.int32)
-                if n_shards > 1 and cfg.exchange == "all_to_all":
+                if n_shards > 1 and cfg.exchange in ("all_to_all",
+                                                     "two_phase"):
                     # SELF-SHARD rows (timers, model-NIC READY reinserts,
                     # local sends — often half the outbox) never need to
                     # move: they bypass the pack entirely (zero ICI, zero
                     # CAP consumption) and reach the merge as a second
                     # incoming block below. Only genuinely remote rows
-                    # pack into [n_shards, CAP] for the all_to_all.
-                    # my own range: straight per-host windows (IN each)
-                    state, inc2, arr2 = _host_windows(state, skey, perm,
-                                                      rows, my_shard)
-
-                    state, moved, kmoved = _pack_remote(
-                        state, skey, perm, rows, my_shard,
-                        ship_keys=True)
-                    G = n_shards * CAP
-                    skey, perm = lax.sort(
-                        (kmoved, jnp.arange(G, dtype=jnp.int64)),
-                        num_keys=1)
-                    rows = moved
-                elif n_shards > 1 and cfg.exchange == "two_phase":
-                    # self-shard bypass identical to the direct path;
-                    # the two-phase received block still holds relayed
-                    # forwards, whose skeys fall outside this shard's
-                    # host boundaries — _host_windows never takes them
-                    state, inc2, arr2 = _host_windows(state, skey, perm,
-                                                      rows, my_shard)
-                    state, kout, rout = _pack_two_phase(
-                        state, skey, perm, rows, my_shard)
-                    G = kout.shape[0]
-                    skey, perm = lax.sort(
-                        (kout, jnp.arange(G, dtype=jnp.int64)),
-                        num_keys=1)
-                    rows = rout
+                    # pack for the exchange: into [n_shards, CAP] for
+                    # the all_to_all, or through the two-phase route,
+                    # whose received block still holds relayed
+                    # forwards — their skeys fall outside this shard's
+                    # host boundaries, and _host_windows never takes
+                    # them. My own range: straight per-host windows.
+                    srt = _sorted_keys(ukey)
+                    state, inc2, arr2 = _host_windows(state, ukey, rows,
+                                                      my_shard, srt)
+                    if cfg.exchange == "all_to_all":
+                        state, rows, ukey = _pack_remote(
+                            state, *srt, rows, my_shard, ship_keys=True)
+                    else:
+                        state, ukey, rows = _pack_two_phase(
+                            state, *srt, rows, my_shard)
                 elif n_shards > 1:
-                    # all_gather fallback: replicate every shard's rows,
-                    # then one global key re-sort (debug / hub-heavy)
+                    # all_gather fallback: replicate every shard's rows
+                    # and keys (debug / hub-heavy)
                     with jax.named_scope("engine.exchange"):
                         rows = {f: lax.all_gather(rows[f], AXIS)
-                                .reshape(n_shards * G) for f in XF}
-                        kg = lax.all_gather(skey, AXIS).reshape(
-                            n_shards * G)
-                        pg = lax.all_gather(perm, AXIS)
-                    pg = (pg.reshape(n_shards, G)
-                          + (jnp.arange(n_shards, dtype=jnp.int64)
-                             * G)[:, None]).reshape(n_shards * G)
-                    skey, perm = lax.sort(
-                        (kg, pg), num_keys=2)
-                    G = n_shards * G
+                                .reshape(-1) for f in XF}
+                        ukey = lax.all_gather(ukey, AXIS).reshape(-1)
 
-                # my hosts' contiguous arrival segments -> [H_loc, IN]
-                state, inc, arr = _host_windows(state, skey, perm, rows,
+                # my hosts' arrival rows -> [H_loc, IN] windows
+                state, inc, arr = _host_windows(state, ukey, rows,
                                                 my_shard)
                 # occupancy: the self-shard bypass and the post-exchange
                 # arrivals are windowed to IN separately, so the
                 # capacity-relevant mark is the per-block max, not the sum
                 state["occ_in"] = jnp.maximum(state["occ_in"],
                                               jnp.maximum(arr, arr2))
+                # real rows windowed (the rest of phases * H * IN per
+                # block is filler)
+                state["occ_arrivals"] = state["occ_arrivals"] + (
+                    jnp.minimum(arr, IN).sum()
+                    + jnp.minimum(arr2, IN).sum()).astype(jnp.int64)
 
                 with jax.named_scope("engine.merge"):
                     # merge: one lexicographic row sort of [live heap | inc
@@ -2143,16 +2208,7 @@ class DeviceEngine:
                     # payload columns ride the sort (merge_payload
                     # "sort") or follow a column iota via
                     # take_along_axis ("gather")
-                    def _inc_cols(b):
-                        kindb = lo32(b["m"]) & 0xFF    # strip the train count
-                        return (b["t"], b["k"],
-                                pack2(kindb, hi32(b["s"])),
-                                pack2(lo32(b["s"]), lo32(b["v"])),
-                                (b["v"] >> 32) & U32)  # d2 (train survivors)
-
-                    blocks = [_inc_cols(inc)]
-                    if inc2 is not None:
-                        blocks.append(_inc_cols(inc2))
+                    blocks = [inc] if inc2 is None else [inc, inc2]
                     live = jnp.arange(E)[None, :] >= state["head"][:, None]
                     mt = jnp.where(live, state["ht"], INF)
                     mk = jnp.where(live, state["hk"], IMAX)
@@ -2300,11 +2356,24 @@ class DeviceEngine:
                     jnp.where((state["ht"][:, 0] < win_end).any(),
                               jnp.int64(0), jnp.int64(1))) == 0
 
-            state = _phase(state)
+            if MERGE_GLOBAL:
+                # the first phase peeled off the loop: a second copy of
+                # the pop loop and the flush in the program. Nothing
+                # shows the peel helps; it stays only until the unpeeled
+                # form is measured in tgen and PHOLD (ROADMAP speed
+                # item 11), then one round shape serves both merges
+                state = _phase(state)
+                go = more(state)
+            else:
+                # the first phase is the loop's first iteration, so the
+                # program holds one copy of the phase: the code of each
+                # sort in it counts against device memory (a second copy
+                # of the window flush's filler sort cost tor_56000 32 MB)
+                go = jnp.bool_(True)
             state, _ = lax.while_loop(
                 lambda c: c[1],
                 lambda c: (lambda s: (s, more(s)))(_phase(c[0])),
-                (state, more(state)))
+                (state, go))
             if AUDIT:
                 with jax.named_scope("engine.audit"):
                     state = _audit_round(state)
@@ -2385,6 +2454,7 @@ class DeviceEngine:
                      "occ_heap", "occ_ob", "occ_in", "occ_x",
                      "occ_trips", "occ_phases", "occ_iters",
                      "occ_burst") + \
+            (() if MERGE_GLOBAL else ("occ_arrivals",)) + \
             (AUD_KEYS if AUDIT else ()) + \
             (NIC_KEYS if MB else ()) + \
             (("path_cnt",) if CP else ())
@@ -2539,6 +2609,8 @@ class DeviceEngine:
         out["occ_phases"] = sds((S,), _np.int32)
         out["occ_iters"] = sds((S,), _np.int32)
         out["occ_burst"] = sds((S,), _np.int32)
+        if not self.program_facts["merge_global"]:
+            out["occ_arrivals"] = sds((S,), _np.int64)
         if self.config.audit:
             out["aud"] = sds((H,), _np.int32)
             out["aud_t"] = sds((H,), _np.int64)

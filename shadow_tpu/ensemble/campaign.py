@@ -212,8 +212,10 @@ class EnsembleRunner:
         overflow counters (any replica's loss fails the campaign)."""
         view = {}
         for k in ("occ_heap", "occ_ob", "occ_in", "occ_x",
-                  "occ_trips", "occ_phases", "occ_iters", "occ_burst"):
-            view[k] = np.asarray(jax.device_get(states[k])).max(0)
+                  "occ_trips", "occ_phases", "occ_iters", "occ_burst",
+                  "occ_arrivals"):
+            if k in states:
+                view[k] = np.asarray(jax.device_get(states[k])).max(0)
         for k in ("overflow", "x_overflow"):
             view[k] = np.asarray(jax.device_get(states[k])).sum(0)
         return view

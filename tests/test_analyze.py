@@ -92,7 +92,8 @@ def test_real_engine_programs_pass_clean():
             ("base", _small_engine()),
             ("two_phase", _small_engine(exchange="two_phase")),
             ("mb", _small_engine(model_bandwidth=True)),
-            # the window merge carrying its payload (tor_56000 on TPU)
+            # the window merge carrying its payload, its arrival
+            # windows filler-sorted (tor_56000 on TPU)
             ("window_merge_sort", _small_engine(
                 merge_global=False, merge_payload="sort")),
             # the compare-select lookups' run table is derived from

@@ -88,7 +88,7 @@ def _replay_windows(boots, packets, H, L, stop, msgload, H_loc, S,
     ip = 0
     occ_heap, occ_in, occ_ob = [0] * H, [0] * H, [0] * H
     occ_x = np.zeros((S, S), dtype=int)
-    trips_max, phases = 0, 0
+    trips_max, phases, arrivals = 0, 0, 0
     iters = np.zeros(S, dtype=int)
     while live:
         nxt = min(t for t, _, _ in live)
@@ -112,6 +112,7 @@ def _replay_windows(boots, packets, H, L, stop, msgload, H_loc, S,
             ip += 1
             assert send_t >= nxt, "arrival from a pre-window send"
             live.append((exec_t, dst, 1))
+            arrivals += 1
             if split_in and src // H_loc != dst // H_loc:
                 inn_far[dst] += 1
             else:
@@ -139,7 +140,8 @@ def _replay_windows(boots, packets, H, L, stop, msgload, H_loc, S,
     assert all(p[0] >= stop for p in pkts[ip:]), \
         "trace packets the replay never delivered"
     return dict(heap=occ_heap, inn=occ_in, ob=occ_ob, x=occ_x,
-                trips=trips_max, phases=phases, iters=iters)
+                trips=trips_max, phases=phases, iters=iters,
+                arrivals=arrivals)
 
 
 @pytest.mark.parametrize("merge", [
@@ -211,6 +213,15 @@ def test_occupancy_marks_match_trace_brute_force(merge):
     assert stats.occupancy is not None
     assert stats.occupancy["measured"]["heap_rows_max"] == \
         max(ref["heap"])
+    # the window merge counts the real rows it windowed (every arrival,
+    # since none overflowed); the global merge's state has no counter
+    if eng.program_facts["merge_global"]:
+        assert "arrivals_windowed" not in stats.occupancy["measured"]
+    else:
+        assert int(np.asarray(final["occ_arrivals"]).sum()) == \
+            ref["arrivals"] > 0
+        assert stats.occupancy["measured"]["arrivals_windowed"] == \
+            ref["arrivals"]
 
 
 # ---------------------------------------------------------------------

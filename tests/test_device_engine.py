@@ -595,6 +595,17 @@ def _payload_case(case):
         return PHOLD_YAML.format(policy="tpu", seed=3, loss=0.05, q=8,
                                  msgload=3).replace(
             "experimental:", "experimental:\n  exchange: all_to_all"), 8
+    if case == "phold_two_phase":
+        # relayed forwards reach the post-exchange block keyed to
+        # other shards' hosts
+        return PHOLD_YAML.format(policy="tpu", seed=5, loss=0.05, q=8,
+                                 msgload=3).replace(
+            "experimental:", "experimental:\n  exchange: two_phase"), 8
+    if case == "phold_all_gather":
+        # every shard's rows and keys replicated, then windowed
+        return PHOLD_YAML.format(policy="tpu", seed=11, loss=0.05, q=8,
+                                 msgload=3).replace(
+            "experimental:", "experimental:\n  exchange: all_gather"), 8
     if case == "tgen_burst":
         yaml, _ = _table_case("tgen_groups")
         return yaml, 8
@@ -620,11 +631,15 @@ def _payload_controller(monkeypatch, yaml, payload):
 
 
 @pytest.mark.parametrize("case", ["phold_lossy", "phold_all_to_all",
+                                  "phold_two_phase", "phold_all_gather",
                                   "tgen_burst", "tor"])
 def test_merge_payload_identical_traces(monkeypatch, case):
-    """The window merge carrying its payload through the row sort vs
-    recovering it with take_along_axis: identical stats and traces,
-    on one shard and over the 8-device mesh."""
+    """The window merge carrying its payload through its sorts (the
+    filler-sorted arrival windows, the row sort) vs recovering it with
+    gathers (seg_take, take_along_axis): identical stats, traces and
+    windowed arrival rows, on one shard and over the 8-device mesh
+    (the all_to_all self-shard bypass and post-exchange block, the
+    two-phase route's relayed forwards, the all_gather replicas)."""
     yaml, shards = _payload_case(case)
     outs = {}
     for payload in ("gather", "sort"):
@@ -635,10 +650,13 @@ def test_merge_payload_identical_traces(monkeypatch, case):
         assert eng.program_facts["merge_global"] is False
         assert eng.program_facts["merge_payload"] == payload
         assert eng.n_shards == shards
+        final = c.runner.final_state
         outs[payload] = (stats.events_executed, stats.packets_sent,
                          stats.packets_dropped,
-                         [h.trace_checksum for h in c.sim.hosts])
+                         [h.trace_checksum for h in c.sim.hosts],
+                         int(np.asarray(final["occ_arrivals"]).sum()))
     assert outs["gather"][2] > 0
+    assert outs["gather"][4] > 0
     assert outs["gather"] == outs["sort"]
 
 
@@ -687,6 +705,72 @@ def test_merge_payload_live_heap_slots_equal(monkeypatch, case):
     assert int(np.asarray(runs[1][1]["overflow"]).sum()) == 0
     assert rounds > 20 and trains > 0 and padded > 0
     assert top_bit > 0 or case == "tor"
+
+
+def _window_rows(rng, span, arrivals, top_host):
+    """Unsorted flat rows for the windowing unit test: `arrivals[g]`
+    rows keyed to global host g, each with a distinct okey (one row of
+    `top_host` with the largest, span - 1), plus empty (IMAX) rows;
+    returns (ukey, rows of the XF fields)."""
+    from shadow_tpu.device.engine import IMAX, INF, XF
+
+    n = int(sum(arrivals))
+    okey = rng.choice(span - 1, n, replace=False)
+    dst = np.repeat(np.arange(len(arrivals)), arrivals)
+    okey[np.flatnonzero(dst == top_host)[0]] = span - 1
+    ukey = np.concatenate([dst * span + okey,
+                           np.full(n // 2 + 3, IMAX)]).astype(np.int64)
+    order = rng.permutation(ukey.size)
+    ukey = ukey[order]
+    rows = {f: rng.integers(0, 1 << 62, ukey.size).astype(np.int64)
+            for f in XF}
+    rows["t"] = np.where(ukey < IMAX, rows["t"] >> 2, INF)
+    return ukey, rows
+
+
+@pytest.mark.parametrize("overflow", [False, True])
+def test_sorted_windows_match_seg_take(overflow):
+    """The windowing alone: sorted_windows against seg_take through
+    the (key, iota) sort, for shard 1 of 3 — empty hosts, a host with
+    exactly IN arrivals, rows keyed to the other shards' hosts, a real
+    okey of SPAN - 1 — bit-identical. With one host past IN the
+    overflow count is equal, and every host before it still is."""
+    from shadow_tpu._jax import jnp
+    from jax import lax
+
+    from shadow_tpu.device.engine import merge_cols, seg_take, \
+        sorted_windows
+
+    rng = np.random.default_rng(29)
+    S, H_loc, OB, IN = 3, 16, 8, 6
+    arrivals = rng.integers(0, IN, S * H_loc)
+    arrivals[H_loc + 2:H_loc + 5] = 0           # empty hosts
+    arrivals[H_loc + 7] = IN                    # exactly full
+    arrivals[H_loc + 9] = 2                     # takes okey SPAN - 1
+    if overflow:
+        arrivals[H_loc + 11] = IN + 3
+    span = S * H_loc * OB
+    ukey, rows = _window_rows(rng, span, arrivals, H_loc + 9)
+    ukey = jnp.asarray(ukey)
+    rows = {f: jnp.asarray(v) for f, v in rows.items()}
+    lo = jnp.int64(H_loc)
+    skey, perm = lax.sort((ukey, jnp.arange(ukey.shape[0],
+                                            dtype=jnp.int64)),
+                          num_keys=1)
+    edges = jnp.searchsorted(skey, (lo + jnp.arange(H_loc + 1)) * span)
+    starts, counts = edges[:-1], edges[1:] - edges[:-1]
+    assert (np.asarray(counts) == arrivals[H_loc:2 * H_loc]).all()
+    want = merge_cols(seg_take(perm, rows, starts, counts, IN))
+    got = sorted_windows(ukey, merge_cols(rows), counts, lo, span, IN)
+    over = np.maximum(0, np.asarray(counts) - IN)
+    assert over.sum() == (3 if overflow else 0)
+    ok = slice(0, 11) if overflow else slice(None)
+    for w, g in zip(want, got):
+        w, g = np.asarray(w), np.asarray(g)
+        assert w.shape == g.shape == (H_loc, IN)
+        assert (w[ok] == g[ok]).all()
+    filled = np.asarray(want[0]) < (1 << 62)
+    assert filled[7].all() and not filled[2:5].any()
 
 
 def test_outbox_compact_global_identical_traces():

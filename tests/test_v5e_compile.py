@@ -78,7 +78,8 @@ def _engine(topo, n):
         and facts["pop_onehot"] and facts["table_onehot"], facts
     assert facts["vertex_runs"] > 1, facts
     # the window merge (pinned where the global sort is too long to
-    # compile) carries its payload through the row sort on the TPU
+    # compile) carries its payload through the row sort on the TPU,
+    # and lays out its arrival windows with one filler sort
     assert facts["merge_payload"] == "sort", facts
     return engine
 
@@ -105,8 +106,9 @@ def test_window_merge_carries_its_payload_on_v5e(topo,
     """The window merge's TPU side, which the deployment above does
     not take (it resolves to the global merge): a small PHOLD engine
     with merge_global False compiles for one described chip, and its
-    row sort carrying the payload leaves fewer gathers in the program
-    than the take_along_axis recovery pinned on the same chip."""
+    filler-sorted arrival windows and row sort carrying the payload
+    leave fewer gathers in the program than seg_take's window gathers
+    and the take_along_axis recovery pinned on the same chip."""
     from jax.sharding import Mesh
 
     from shadow_tpu.device.apps import PholdDevice
